@@ -37,7 +37,16 @@ SERVER_NAME = "nornicdb-tpu"
 API_VERSION = "1.0"
 
 
-class ReuseportThreadingHTTPServer(ThreadingHTTPServer):
+class BacklogThreadingHTTPServer(ThreadingHTTPServer):
+    """ThreadingHTTPServer with a listen backlog sized for a burst of
+    clients: the stdlib's 5 resets connections when a few dozen connect
+    at once (seen on the v5e host with 33 — the accept loop shares the
+    GIL with a compile)."""
+
+    request_queue_size = 128
+
+
+class ReuseportThreadingHTTPServer(BacklogThreadingHTTPServer):
     """SO_REUSEPORT-bound ThreadingHTTPServer: the wire plane's
     parallel frontend workers (ISSUE 11) share one listening port and
     let the kernel balance accepted connections. Shared by HttpServer
@@ -603,7 +612,7 @@ class HttpServer:
                 self._dispatch("DELETE")
 
         server_cls = (ReuseportThreadingHTTPServer if self._reuse_port
-                      else ThreadingHTTPServer)
+                      else BacklogThreadingHTTPServer)
         self._server = server_cls((self.host, self.port), Handler)
         self.port = self._server.server_address[1]
         self._thread = threading.Thread(target=self._server.serve_forever,

@@ -888,7 +888,6 @@ def _worker_main(spec: Dict[str, Any]) -> None:
     """Process-mode entry (``python -m nornicdb_tpu.api.wire_plane
     --worker <json>``): build the worker, serve until the plane
     stops."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     worker = WireWorker(spec)
     try:
         worker.start()
@@ -1153,12 +1152,14 @@ class WirePlane:
             import sys
 
             import nornicdb_tpu as _pkg
+            from nornicdb_tpu.jaxenv import cpu_child_env
 
             # the worker interpreter must resolve this package no
             # matter the caller's cwd: prepend the package parent
             pkg_root = os.path.dirname(os.path.dirname(
                 os.path.abspath(_pkg.__file__)))
-            env = dict(os.environ)
+            # frontends never own the chip: the device plane holds it
+            env = cpu_child_env()
             env["PYTHONPATH"] = pkg_root + (
                 os.pathsep + env["PYTHONPATH"]
                 if env.get("PYTHONPATH") else "")

@@ -3,19 +3,22 @@
 Reference: pkg/embed — ``Embedder`` interface (embed.go:71), the local
 GGUF/llama.cpp provider (local_gguf.go:57) with crash recovery, and the
 cached decorator (cached_embedder.go). The TPU-native local provider is
-``JaxEncoderEmbedder``: the flax encoder jitted once per (batch, width)
-bucket, batched, padded to stable shapes so XLA never recompiles.
+``JaxEncoderEmbedder``: the flax encoder jitted once per power-of-two
+(batch, width) bucket, so the compile universe is log2-sized on both axes.
 """
 
 from __future__ import annotations
 
+import logging
 import threading
 from collections import OrderedDict
-from typing import List, Optional, Protocol, Sequence
+from typing import List, Optional, Protocol, Sequence, Set, Tuple
 
 import numpy as np
 
 from nornicdb_tpu.embed.tokenizer import CHUNK_OVERLAP, CHUNK_SIZE, HashTokenizer, chunk_tokens
+
+logger = logging.getLogger(__name__)
 
 
 class Embedder(Protocol):
@@ -53,7 +56,9 @@ class HashEmbedder:
 class JaxEncoderEmbedder:
     """Local TPU embedder over the flax encoder.
 
-    - pads token widths to power-of-two buckets (jit cache stays small);
+    - parameters are placed on the device once, at construction;
+    - pads token widths AND the batch dimension to power-of-two buckets
+      (jit cache stays small; pad rows are dropped);
     - batches up to ``max_batch`` texts per device call;
     - long texts are chunked 512/50 and mean-pooled (whole-doc vector);
       per-chunk vectors available via embed_chunks (reference
@@ -86,7 +91,9 @@ class JaxEncoderEmbedder:
             )["params"]
         self.cfg = cfg
         self.model = model
-        self.params = params
+        # checkpoint loaders hand back NumPy trees; left on the host,
+        # every call would ship every weight to the device again
+        self.params = jax.device_put(params)
         self.dims = cfg.hidden_size
         self.max_batch = max_batch
         self.tokenizer = HashTokenizer(cfg.vocab_size)
@@ -94,26 +101,41 @@ class JaxEncoderEmbedder:
             lambda p, ids: model.apply({"params": p}, ids)
         )
         self._lock = threading.Lock()
+        # every (batch, width) shape dispatched so far == the programs
+        # this embedder has made XLA compile
+        self.shapes: Set[Tuple[int, int]] = set()
 
     @staticmethod
     def _bucket_width(w: int) -> int:
-        b = 16
-        while b < w:
-            b *= 2
-        return b
+        from nornicdb_tpu.ops.similarity import pow2_bucket
+
+        return max(16, pow2_bucket(w))
 
     def _run(self, id_lists: List[List[int]]) -> np.ndarray:
         import jax.numpy as jnp
 
+        from nornicdb_tpu.ops.similarity import pow2_bucket
+
+        n = len(id_lists)
         width = self._bucket_width(max(len(x) for x in id_lists))
         width = min(width, self.cfg.max_len)
-        arr = np.zeros((len(id_lists), width), np.int32)
+        arr = np.zeros((pow2_bucket(n), width), np.int32)
         for i, ids in enumerate(id_lists):
             ids = ids[:width]
             arr[i, : len(ids)] = ids
-        with self._lock:
-            out = self._jit(self.params, jnp.asarray(arr))
-        return np.asarray(out, dtype=np.float32)
+        arr[n:] = arr[0]  # pad rows repeat row 0 (no all-masked rows)
+        try:
+            with self._lock:
+                self.shapes.add(arr.shape)
+                out = self._jit(self.params, jnp.asarray(arr))
+            out = np.asarray(out, dtype=np.float32)
+        except Exception:
+            # the einsum attention arm materialises [B, heads, S, S]; a
+            # batch too large for HBM must name its shape, not vanish
+            logger.error("encoder forward failed at (batch, width)=%s",
+                         arr.shape)
+            raise
+        return out[:n]
 
     def embed_batch(self, texts: Sequence[str]) -> List[List[float]]:
         out: List[List[float]] = []

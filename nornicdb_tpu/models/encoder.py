@@ -14,6 +14,7 @@ is a native JAX/flax module designed for TPU:
 
 from __future__ import annotations
 
+import logging
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -84,14 +85,23 @@ def _maybe_shard(x: jnp.ndarray, cfg: EncoderConfig, spec: P) -> jnp.ndarray:
 
 
 def flash_attention_enabled() -> bool:
-    """Opt-in fused Pallas attention (NORNICDB_PALLAS_ATTENTION=1). Off
-    by default for the same reason as the top-k kernel: interpret mode
-    is test-only and real-TPU validation gates enabling it broadly.
-    Consumed at encoder CONSTRUCTION by the inference embedder; the
-    training path never opts in (the kernel has no vjp)."""
+    """Opt-in fused Pallas attention (NORNICDB_PALLAS_ATTENTION=1),
+    compiled and matched against the XLA arm on a v5e (chip_smoke.py
+    phase 5); whether it becomes a default is a measured decision not
+    yet taken. Consumed at encoder CONSTRUCTION by the inference
+    embedder; the training path never opts in (the kernel has no vjp).
+    The kernel compiles for a TPU backend only: asked for anywhere else,
+    it says so and the XLA arm serves."""
     import os
 
-    return os.environ.get("NORNICDB_PALLAS_ATTENTION", "0") == "1"
+    if os.environ.get("NORNICDB_PALLAS_ATTENTION", "0") != "1":
+        return False
+    if jax.default_backend() != "tpu":
+        logging.getLogger(__name__).warning(
+            "NORNICDB_PALLAS_ATTENTION=1 but the backend is %r; the "
+            "encoder uses the XLA attention arm", jax.default_backend())
+        return False
+    return True
 
 
 class MultiHeadAttention(nn.Module):

@@ -269,7 +269,9 @@ class HFEncoderEmbedder:
 
         cfg, params = load_hf_model_dir(model_dir, pooling=pooling)
         self.cfg = cfg
-        self.params = params
+        # the importer returns a NumPy tree: place it on the device once
+        # instead of shipping every weight with every call
+        self.params = jax.device_put(params)
         self.model = HFEncoder(cfg)
         self.dims = cfg.hidden_size
         self.max_batch = max_batch

@@ -145,9 +145,7 @@ def make_sharded_train_step(
     def run(st, anchor_ids, positive_ids):
         # activation sharding constraints use raw PartitionSpecs, which
         # need the mesh in context at trace time
-        from nornicdb_tpu.parallel.mesh import mesh_context
-
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             return jitted(st, anchor_ids, positive_ids)
 
     return state, run

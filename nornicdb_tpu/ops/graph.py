@@ -31,8 +31,9 @@ def _pagerank_impl(
     safe_deg = jnp.maximum(out_deg, 1.0)
     # sort edges by destination ONCE; every iteration's scatter then
     # becomes a sorted segment-sum (sequential HBM traffic) instead of
-    # a per-iteration sort — on a real chip this took the 20-iteration
-    # LDBC-scale run from ~600ms to ~1ms
+    # a per-iteration sort. Its speed on the chip is not measured: the
+    # one recorded chip run (BENCH_r05_tpu_preview.json, older code) has
+    # device PageRank at 0.1x NumPy.
     order = jnp.argsort(dst)
     dst_s = dst[order]
     src_s = src[order]
@@ -89,10 +90,10 @@ def pagerank_arrays(
     if len(src) == 0:
         return np.full((n,), 1.0 / n, np.float32)
     if jax.default_backend() == "cpu":
-        # on the CPU fallback the jit scatter-add loses to host numpy
-        # (VERDICT r4 weak #3) — same host-path policy as
-        # search/vector_index.py; the device path stays the accelerator
-        # path
+        # on a CPU backend the jit scatter-add loses to host numpy — same
+        # host-path policy as search/vector_index.py; the device program
+        # is the accelerator path (run and matched against
+        # _pagerank_host on a v5e by chip_smoke.py phase 4)
         try:
             return _pagerank_host(np.asarray(src), np.asarray(dst), n,
                                   iters, damping)

@@ -25,10 +25,15 @@ union of block top-k's equals the full top-k whenever k <= KPAD.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from nornicdb_tpu.ops.similarity import EXACT, cosine_topk_auto
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 _KPAD = 128  # lane-aligned per-block winner count (k <= _KPAD)
@@ -48,6 +53,7 @@ def _block_topk_kernel(q_ref, m_ref, mask_ref, s_out_ref, i_out_ref, *, k: int):
     scores = jax.lax.dot_general(
         q_ref[:], m_ref[:],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=EXACT,
         preferred_element_type=jnp.float32,
     )
     # mask block is [BLOCK_C] float {0,1} (1-D: lane tiling only, no
@@ -142,32 +148,46 @@ def _fused_cosine_topk_impl(
     return top_s, top_i
 
 
+_said: set = set()
+
+
+def _xla_instead(reason: str, why: str, queries, matrix, valid, k):
+    """The kernel was asked for and cannot run: say so once per reason,
+    then serve the XLA program (same contract, same HBM routing)."""
+    if reason not in _said:
+        _said.add(reason)
+        logger.warning("fused Pallas top-k requested but %s; serving the "
+                       "XLA cosine_topk instead", why)
+    return cosine_topk_auto(queries, matrix, valid, k)
+
+
 def fused_cosine_topk(
     queries: jnp.ndarray,
     matrix: jnp.ndarray,
     valid: jnp.ndarray,
     k: int,
     *,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused exact cosine top-k (Pallas). Same contract as
     ops.similarity.cosine_topk: inputs L2-normalized, returns
     (scores [B,k], indices [B,k]).
 
-    Falls back to the XLA implementation (with the same dense/chunked
-    HBM routing as the vector index) when shapes don't meet the kernel's
-    tiling constraints (D % 128, C % block, k <= 128, B <= 256), or when
-    not running on a TPU backend — interpret-mode emulation is for tests
-    only and must be requested explicitly.
+    The kernel compiles for a TPU backend only, and only for shapes that
+    meet its tiling constraints (D % 128, C % block, k <= 128, B <= 256 —
+    compiled on a v5e up to B=256 at 8192 x 1024). Anything else is served
+    by the XLA program, logged once. ``interpret=True`` is the emulation
+    the tests and the CPU rehearsal of chip_smoke.py ask for by name;
+    nothing selects it on its own.
     """
-    from nornicdb_tpu.ops.similarity import cosine_topk_auto
-
     b, d = queries.shape
     c = matrix.shape[0]
     k_eff = min(k, c)
     block_c = min(_BLOCK_C, c)
-    if interpret is None and jax.default_backend() != "tpu":
-        return cosine_topk_auto(queries, matrix, valid, k)
+    if not interpret and jax.default_backend() != "tpu":
+        return _xla_instead(
+            "backend", f"the backend is {jax.default_backend()!r}",
+            queries, matrix, valid, k)
     if (
         d % 128 != 0
         or c % block_c != 0
@@ -175,9 +195,9 @@ def fused_cosine_topk(
         or k_eff < 1
         or b > 256  # VMEM bound: queries + score tile must fit
     ):
-        return cosine_topk_auto(queries, matrix, valid, k)
-    if interpret is None:
-        interpret = False
+        return _xla_instead(
+            "shape", f"shape B={b} C={c} D={d} k={k} is outside its tiling",
+            queries, matrix, valid, k)
 
     b_pad = max(8, -(-b // 8) * 8)
     if b_pad != b:

@@ -23,6 +23,20 @@ import jax.numpy as jnp
 
 NEG_INF = -1e30
 
+# What "exact" means for the float32 tiers. A TPU's default float32 matmul
+# rounds its operands to bfloat16. The exactness contracts — rank parity
+# with the host NumPy path, shadow parity 1.0, lower-slot-first tie order —
+# were written against float32 arithmetic, so every exact-tier matmul asks
+# for it. On a v5e against float64 (scripts/bringup_probe.py, PR 21;
+# PERF.md finding 1): DEFAULT is off by 2.6e-4 and gets 1 top-10 id in 640
+# wrong; HIGH (three passes) is off by 2.0e-6, inside a tie band of 1e-5,
+# and still got 1 id in 640 wrong; HIGHEST (six passes) stays at float32
+# rounding, 2.1e-8, recall 1.0, for 8-25% more time than DEFAULT once the
+# matrix is large enough to see it. HIGH is the candidate if a contract
+# with a stated tie band ever replaces rank parity. On CPU all three are
+# the same program.
+EXACT = jax.lax.Precision.HIGHEST
+
 
 def pad_dim(n: int, minimum: int = 256) -> int:
     """Round capacity up to the next power-of-two multiple of `minimum`
@@ -33,6 +47,16 @@ def pad_dim(n: int, minimum: int = 256) -> int:
     while capacity < n:
         capacity *= 2
     return capacity
+
+
+def pow2_bucket(n: int) -> int:
+    """Smallest power of two >= n. Shape bucketing for device dispatch:
+    every distinct (B, k) is its own XLA compile, so batch and k are
+    padded to buckets to cap the compile universe at log2 shapes."""
+    b = 1
+    while b < n:
+        b <<= 1
+    return b
 
 
 @jax.jit
@@ -50,7 +74,7 @@ def _cosine_topk_impl(
     valid: jnp.ndarray,  # [C] bool
     k: int,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    scores = queries @ matrix.T  # [B, C] — MXU
+    scores = jnp.matmul(queries, matrix.T, precision=EXACT)  # [B, C]
     scores = jnp.where(valid[None, :], scores, NEG_INF)
     return jax.lax.top_k(scores, k)
 
@@ -83,7 +107,7 @@ def _cosine_topk_chunked_impl(
         best_s, best_i = carry
         rows = jax.lax.dynamic_slice_in_dim(matrix, i * chunk, chunk, axis=0)
         vmask = jax.lax.dynamic_slice_in_dim(valid, i * chunk, chunk, axis=0)
-        s = queries @ rows.T  # [B, chunk]
+        s = jnp.matmul(queries, rows.T, precision=EXACT)  # [B, chunk]
         s = jnp.where(vmask[None, :], s, NEG_INF)
         idx = i * chunk + jnp.arange(chunk, dtype=jnp.int32)
         cat_s = jnp.concatenate([best_s, s], axis=1)

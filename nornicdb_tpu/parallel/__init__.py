@@ -9,8 +9,8 @@ plane onto XLA collectives over ICI/DCN (SURVEY.md §2.8, §5).
 from nornicdb_tpu.parallel.mesh import (  # noqa: F401
     MeshSpec,
     best_mesh,
-    compat_shard_map,
     data_mesh,
     make_mesh,
+    shard_map_unchecked,
     sharded_cosine_topk,
 )

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from nornicdb_tpu.ops.similarity import EXACT
+
 
 @dataclass(frozen=True)
 class MeshSpec:
@@ -72,28 +74,11 @@ def data_mesh(n: Optional[int] = None) -> Mesh:
     return Mesh(np.array(devices), axis_names=("data",))
 
 
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: ``jax.shard_map`` (with
-    ``check_vma``) on new releases, ``jax.experimental.shard_map`` (with
-    ``check_rep``) on 0.4.x — both replication checks disabled, since
-    the local top-k bodies intentionally mix replicated queries with
-    sharded rows."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def mesh_context(mesh: Mesh):
-    """Trace-time mesh scope across jax versions: ``jax.set_mesh`` on
-    new releases; on 0.4.x a Mesh is its own context manager (both make
-    raw-PartitionSpec sharding constraints resolvable inside jit)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def shard_map_unchecked(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication check off: the local top-k
+    bodies intentionally mix replicated queries with sharded rows."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "mesh_holder"))
@@ -107,7 +92,7 @@ def _sharded_topk_impl(queries, matrix, valid, k, mesh_holder):
 
     def local_topk(q, m, v):
         # q: [B, D] replicated; m: [rows/n, D]; v: [rows/n]
-        scores = q @ m.T
+        scores = jnp.matmul(q, m.T, precision=EXACT)
         scores = jnp.where(v[None, :], scores, -1e30)
         s, i = jax.lax.top_k(scores, local_k)
         # local indices -> global row ids
@@ -120,7 +105,7 @@ def _sharded_topk_impl(queries, matrix, valid, k, mesh_holder):
         top_i = jnp.take_along_axis(all_i, pos, axis=1)
         return top_s, top_i
 
-    return compat_shard_map(
+    return shard_map_unchecked(
         local_topk,
         mesh=mesh,
         in_specs=(P(), P("data", None), P("data")),

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from nornicdb_tpu.parallel.mesh import compat_shard_map
+from nornicdb_tpu.parallel.mesh import shard_map_unchecked
 
 
 # -- pipeline parallelism -------------------------------------------------
@@ -123,7 +123,7 @@ def pipeline_apply(
         return jax.lax.psum(outputs, "pp")
 
     data_spec = P(None, batch_axis) if batch_axis else P()
-    out = compat_shard_map(
+    out = shard_map_unchecked(
         staged,
         mesh=mesh,
         in_specs=(P("pp"), data_spec),
@@ -216,7 +216,7 @@ def moe_apply(
         out = jnp.einsum("bec,ecd->bd", dispatch, y) * gate[:, None]
         return out, jax.lax.pmean(aux, "ep")
 
-    return compat_shard_map(
+    return shard_map_unchecked(
         local,
         mesh=mesh,
         in_specs=(

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from nornicdb_tpu.parallel.mesh import compat_shard_map
+from nornicdb_tpu.parallel.mesh import shard_map_unchecked
 
 
 def _ring_attention_local(q, k, v, mask, axis_name: str):
@@ -104,7 +104,7 @@ def ring_attention(
         return _dense_attention(q, k, v, mask)
 
     qkv_spec = P(batch_axis, axis_name, head_axis, None)
-    fn = compat_shard_map(
+    fn = shard_map_unchecked(
         functools.partial(_ring_attention_local, axis_name=axis_name),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, P(batch_axis, axis_name)),

@@ -29,8 +29,7 @@ executors (optimized_executors.go:25-282):
   co-occurrence" family in O(nnz(C)) per query.
 
 Without these, both shapes re-run O(edges) array work per query, which
-is fine at 10^3 nodes and hopeless at 10^5 (the scale VERDICT r02
-demands).
+is fine at 10^3 nodes and hopeless at 10^5.
 """
 
 from __future__ import annotations
@@ -194,7 +193,7 @@ class _GramView:
     C in O(deg(mid)) with in-place (untearable) int64 stores.
 
     ``coo()`` is the pre-aggregated sparse decomposition the query path
-    consumes (VERDICT r4 #9: pre-aggregation, not per-query nonzero):
+    consumes (pre-aggregation, not per-query nonzero):
     recomputed only when ``gen`` moved, i.e. after a C mutation.
     """
 

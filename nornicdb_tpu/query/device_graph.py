@@ -124,11 +124,9 @@ def _cpu_backend() -> bool:
     paths win every rung (strip build 1.7 ms host vs 78 ms XLA-CPU at
     LDBC scale; coalesced chain dispatch roughly GIL-parity) — the same
     host-path policy as ops/graph.py PageRank and vector_index. ``on``
-    forces the device route regardless (tests, benches)."""
-    try:
-        return _jx().default_backend() == "cpu"
-    except Exception:  # noqa: BLE001 — no backend: host paths only
-        return True
+    forces the device route regardless (tests, benches). A backend that
+    fails to initialise raises: it is not a reason to serve from host."""
+    return _jx().default_backend() == "cpu"
 
 
 # -- jitted programs ---------------------------------------------------------

@@ -46,7 +46,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 def _replica_main(spec: Dict[str, Any]) -> None:
     """Subprocess entry: build the replica, attach, serve until the
     parent signals stop or disappears."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     work_dir = spec["work_dir"]
     name = spec["name"]
     stop_paths = (os.path.join(work_dir, "stop"),
@@ -139,6 +138,7 @@ class ReplicaProcess:
         import sys
 
         import nornicdb_tpu as _pkg
+        from nornicdb_tpu.jaxenv import cpu_child_env
 
         os.makedirs(self.work_dir, exist_ok=True)
         for stale in (f"ready-{self.name}", f"stop-{self.name}"):
@@ -151,7 +151,8 @@ class ReplicaProcess:
         # discipline)
         pkg_root = os.path.dirname(os.path.dirname(
             os.path.abspath(_pkg.__file__)))
-        env = dict(os.environ)
+        # a replica never owns the chip: the primary holds it
+        env = cpu_child_env()
         env["PYTHONPATH"] = pkg_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH")
             else "")

@@ -220,7 +220,7 @@ def _sharded_walk_impl(
 ):
     from jax.sharding import PartitionSpec as P
 
-    from nornicdb_tpu.parallel.mesh import compat_shard_map
+    from nornicdb_tpu.parallel.mesh import shard_map_unchecked
 
     mesh = mesh_holder.mesh
     n_shards = mesh.shape["data"]
@@ -237,7 +237,7 @@ def _sharded_walk_impl(
         top_s, pos = jax.lax.top_k(all_s, k)
         return top_s, jnp.take_along_axis(all_i, pos, axis=1)
 
-    return compat_shard_map(
+    return shard_map_unchecked(
         local_walk,
         mesh=mesh,
         in_specs=(P(), P("data", None), P("data", None), P("data")),

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from nornicdb_tpu.obs import REGISTRY, declare_kind, record_dispatch
-from nornicdb_tpu.ops.similarity import NEG_INF, concat_topk, pad_dim
+from nornicdb_tpu.ops.similarity import EXACT, NEG_INF, concat_topk, pad_dim
 from nornicdb_tpu.search.bm25 import B, K1, BM25Index, tokenize
 from nornicdb_tpu.search.microbatch import pow2_bucket
 
@@ -117,7 +117,9 @@ def bm25_dense_scores(
     # row, so they can never corrupt a real (term, doc) cell
     seg = urow * c + d
     m = jax.ops.segment_sum(tf_norm, seg, num_segments=(u + 1) * c)
-    dense = sel @ m.reshape(u + 1, c)[:u]
+    # idf weights and tf-norms are float32 scores the host path ranks
+    # on: the exact-tier matmul precision (ops/similarity.EXACT)
+    dense = jnp.matmul(sel, m.reshape(u + 1, c)[:u], precision=EXACT)
     return jnp.where((alive_f[None, :] > 0.0) & (dense > 0.0),
                      dense, NEG_INF)
 
@@ -147,7 +149,7 @@ def _sharded_bm25_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
                        alive_f, avgdl, k, mesh_holder):
     from jax.sharding import PartitionSpec as P
 
-    from nornicdb_tpu.parallel.mesh import compat_shard_map
+    from nornicdb_tpu.parallel.mesh import shard_map_unchecked
 
     mesh = mesh_holder.mesh
     n_shards = mesh.shape["data"]
@@ -165,7 +167,7 @@ def _sharded_bm25_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
         top_s, pos = jax.lax.top_k(all_s, k)
         return top_s, jnp.take_along_axis(all_i, pos, axis=1)
 
-    return compat_shard_map(
+    return shard_map_unchecked(
         local_fn,
         mesh=mesh,
         in_specs=(P("data"), P("data"), P(), P("data"), P("data"),

@@ -174,7 +174,7 @@ def _int8_sharded_impl(qn, codes_t, scale, valid, k, mesh_holder):
     fused pipeline."""
     from jax.sharding import PartitionSpec as P
 
-    from nornicdb_tpu.parallel.mesh import compat_shard_map
+    from nornicdb_tpu.parallel.mesh import shard_map_unchecked
 
     mesh = mesh_holder.mesh
     n_shards = mesh.shape["data"]
@@ -191,7 +191,7 @@ def _int8_sharded_impl(qn, codes_t, scale, valid, k, mesh_holder):
         top_s, pos = jax.lax.top_k(all_s, k)
         return top_s, jnp.take_along_axis(all_i, pos, axis=1)
 
-    return compat_shard_map(
+    return shard_map_unchecked(
         local_fn,
         mesh=mesh,
         in_specs=(P(), P(None, "data"), P("data"), P("data")),

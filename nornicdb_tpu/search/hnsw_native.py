@@ -1,17 +1,20 @@
 """ctypes loader for the native HNSW connect-phase kernel.
 
 See native/nornichnsw.cpp. Loading is lazy and failure-tolerant: when
-the toolchain or .so is unavailable the wave build silently uses its
-Python connect path (same semantics, pinned by
-tests/test_ann_stack.py::TestNativeConnect)."""
+the toolchain or .so is unavailable the wave build uses its Python
+connect path (same semantics, pinned by
+tests/test_ann_stack.py::TestNativeConnect) and says so once."""
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 from typing import Optional
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -66,6 +69,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
         lib.hnsw_wave_search.restype = None
         _lib = lib
     except Exception:
+        logger.warning("native HNSW library unavailable; the Python "
+                       "connect path serves", exc_info=True)
         _lib = None
     return _lib
 

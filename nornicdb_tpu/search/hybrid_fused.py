@@ -68,7 +68,7 @@ import numpy as np
 from nornicdb_tpu.obs import REGISTRY, declare_kind, record_dispatch
 from nornicdb_tpu.obs import audit as _audit
 from nornicdb_tpu.obs import cost as _cost
-from nornicdb_tpu.ops.similarity import NEG_INF, l2_normalize
+from nornicdb_tpu.ops.similarity import EXACT, NEG_INF, l2_normalize
 from nornicdb_tpu.search.bm25 import BM25Index
 from nornicdb_tpu.search.cagra import (
     CagraIndex,
@@ -207,7 +207,7 @@ def _local_parts_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
     ls, lid, lgrow = _lex_parts_impl(ptr, urow, sel, post_doc, post_tf,
                                      doc_len, alive_f, l2v, avgdl,
                                      lex_off, kq)
-    vsc = qn @ vmatrix.T
+    vsc = jnp.matmul(qn, vmatrix.T, precision=EXACT)
     vsc = jnp.where(vvalid[None, :], vsc, NEG_INF)
     vs, vi = jax.lax.top_k(vsc, min(kq, c_vec))
     return ls, lid, lgrow, vs, vi + vec_off
@@ -247,7 +247,7 @@ def _fused_sharded_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
                         n_cand, w_lex, w_vec, kq, rrf_k, mesh_holder):
     from jax.sharding import PartitionSpec as P
 
-    from nornicdb_tpu.parallel.mesh import compat_shard_map
+    from nornicdb_tpu.parallel.mesh import shard_map_unchecked
 
     mesh = mesh_holder.mesh
     s_n = mesh.shape["data"]
@@ -273,7 +273,7 @@ def _fused_sharded_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
                                    wl_r, wv_r, rrf_k, c_vec_total)
         return ls2, lgrow2, vs2, vi2, fs, fpos
 
-    return compat_shard_map(
+    return shard_map_unchecked(
         local_fn,
         mesh=mesh,
         in_specs=(P("data"), P("data"), P(), P("data"), P("data"),
@@ -424,7 +424,7 @@ def _walk_fused_sharded_impl(ptr, urow, sel, post_doc, post_tf,
     mesh path and ``cagra.sharded_cagra_walk``."""
     from jax.sharding import PartitionSpec as P
 
-    from nornicdb_tpu.parallel.mesh import compat_shard_map
+    from nornicdb_tpu.parallel.mesh import shard_map_unchecked
 
     mesh = mesh_holder.mesh
     s_n = mesh.shape["data"]
@@ -453,7 +453,7 @@ def _walk_fused_sharded_impl(ptr, urow, sel, post_doc, post_tf,
                                    wl_r, wv_r, rrf_k, c_g_total)
         return ls2, lgrow2, vs2, vi2, fs, fpos
 
-    return compat_shard_map(
+    return shard_map_unchecked(
         local_fn,
         mesh=mesh,
         in_specs=(P("data"), P("data"), P(), P("data"), P("data"),

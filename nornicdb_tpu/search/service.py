@@ -18,6 +18,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from nornicdb_tpu.embed.http_providers import EmbedHTTPError
 from nornicdb_tpu.obs import REGISTRY, attach_span
 from nornicdb_tpu.obs import audit as _audit
 from nornicdb_tpu.obs import tenant as _tenant
@@ -777,8 +778,13 @@ class SearchService:
             return None
         try:
             return np.asarray(self.embedder.embed(query), dtype=np.float32)
-        except Exception:
-            return None  # fail-open: hybrid degrades to text-only
+        except EmbedHTTPError:
+            # fail-open for a REMOTE provider's transport error only:
+            # hybrid degrades to text-only, counted. A local (JAX)
+            # embedder that raises is the caller's to see.
+            _audit.record_degrade("hybrid", "hybrid_brute_f32", "host",
+                                  "error", index=self.resource_name)
+            return None
 
     def similar(self, node_id: str, limit: int = 10) -> List[Dict[str, Any]]:
         """Nodes nearest to a stored node's embedding (reference: the REST

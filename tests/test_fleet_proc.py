@@ -50,8 +50,20 @@ def pfleet(tmp_path_factory):
     full interpreter + JAX import; the tests share the topology the
     way the in-process suites share a DB)."""
     base = str(tmp_path_factory.mktemp("pfleet"))
-    fleet = ProcessReadFleet(base, n_replicas=2,
-                             heartbeat_interval=0.1, auto_embed=True)
+    # the children are spawned beside a primary that owns the chip: what
+    # they inherit says "tpu", and they must come up on the CPU anyway
+    import jax  # noqa: F401 — this process reads the variable at import
+
+    inherited = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "tpu"
+    try:
+        fleet = ProcessReadFleet(base, n_replicas=2,
+                                 heartbeat_interval=0.1, auto_embed=True)
+    finally:
+        if inherited is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = inherited
     try:
         db = fleet.primary_db
         for i in range(30):
@@ -88,6 +100,9 @@ class TestTopology:
             # real socket transport and said so in its ready file
             assert proc.ready_doc["transport_addr"][1] > 0
             assert proc.ready_doc["http_port"] > 0
+            # pinned to the CPU by the spawner, whatever was inherited
+            with open(f"/proc/{proc.pid}/environ", "rb") as f:
+                assert b"JAX_PLATFORMS=cpu" in f.read().split(b"\0")
 
     def test_two_plane_stream_converges(self, pfleet):
         target = pfleet.primary_db._base.wal.last_seq

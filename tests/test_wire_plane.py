@@ -844,19 +844,27 @@ class TestSearchStream:
 
 class TestWirePlaneProcess:
     def test_process_workers_serve_rank_identical_and_survive_crash(
-            self):
+            self, monkeypatch):
         """2 real worker processes on one SO_REUSEPORT port: racing
         searches stay rank-identical to the direct path; killing one
         worker mid-serving leaves the survivor taking traffic (the
-        crash satellite's no-hang contract)."""
+        crash satellite's no-hang contract). The workers start beside a
+        device plane that owns the chip: they inherit JAX_PLATFORMS=tpu
+        and must come up pinned to the CPU."""
         import grpc
 
         from nornicdb_tpu.api.proto import qdrant_pb2 as q
         from nornicdb_tpu.api.wire_plane import WirePlane
 
+        import jax  # noqa: F401 — this process reads the variable at import
+
         db = _mk_db()
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
         plane = WirePlane(db, workers=2, mode="process").start()
         try:
+            for p in plane._procs:
+                with open(f"/proc/{p.pid}/environ", "rb") as f:
+                    assert b"JAX_PLATFORMS=cpu" in f.read().split(b"\0")
             _setup_collection(db, plane.grpc_address)
             target = db.storage.get_node("p4")
             want = [int(d["id"]) for d in db.qdrant_compat.search_points(
