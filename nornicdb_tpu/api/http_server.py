@@ -755,7 +755,8 @@ class HttpServer:
 
         # admin
         if segments[:1] == ["admin"]:
-            return self._admin_routes(method, segments, payload, username)
+            return self._admin_routes(method, segments, payload, username,
+                                      query)
 
         raise HTTPError(404, "Neo.ClientError.Request.Invalid",
                         f"no route for {method} {parsed.path}")
@@ -1510,7 +1511,9 @@ class HttpServer:
 
     def _admin_routes(self, method: str, segments: List[str],
                       payload: Dict[str, Any],
-                      username: Optional[str]) -> Tuple[int, Any]:
+                      username: Optional[str],
+                      query: Optional[Dict[str, str]] = None
+                      ) -> Tuple[int, Any]:
         self.authorize(username, "system", ADMIN)
         action = segments[1] if len(segments) > 1 else ""
 
@@ -1518,14 +1521,16 @@ class HttpServer:
             # slow-request ring buffer: full span trees of the most
             # recent requests over NORNICDB_OBS_SLOW_MS (default 0 =
             # every request, ring-bounded). /admin/traces/slowest ranks
-            # by duration instead of recency.
+            # by duration instead of recency; ?name= keeps the roots of
+            # one name or wire method (embed.batch: the embed worker's).
             if len(segments) > 2 and segments[2] == "slowest":
                 return 200, {"slow_ms": obs.TRACES.slow_ms,
                              "recorded": obs.TRACES.recorded,
                              "traces": obs.TRACES.slowest(limit=10)}
             return 200, {"slow_ms": obs.TRACES.slow_ms,
                          "recorded": obs.TRACES.recorded,
-                         "traces": obs.TRACES.snapshot(limit=50)}
+                         "traces": obs.TRACES.snapshot(
+                             limit=50, name=(query or {}).get("name"))}
 
         if action == "telemetry" and method == "GET":
             # include_empty: brand-new/idle histogram series report

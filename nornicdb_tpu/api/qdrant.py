@@ -28,7 +28,11 @@ from nornicdb_tpu.obs import annotate as _obs_annotate
 from nornicdb_tpu.obs import attach_span as _obs_attach_span
 from nornicdb_tpu.obs import audit as _audit
 from nornicdb_tpu.obs import cost as _cost
+from nornicdb_tpu.obs import declare_kind as _obs_declare_kind
+from nornicdb_tpu.obs import record_dispatch as _obs_record_dispatch
+from nornicdb_tpu.obs import span as _obs_span
 from nornicdb_tpu.obs import tenant as _tenant
+from nornicdb_tpu.ops.similarity import pow2_bucket
 from nornicdb_tpu.search.vector_index import BruteForceIndex
 from nornicdb_tpu.storage.types import Node, now_ms
 
@@ -50,6 +54,8 @@ def _point_node_id(collection: str, point_id: Any) -> str:
 
 # per-instance ordinal for the upsert-convoy resource registration
 _CONVOY_SEQ = itertools.count(1)
+# a search's own widening dispatch (b=1, outside the collection's batcher)
+_obs_declare_kind("vector_widen")
 
 
 class QdrantCompat:
@@ -785,6 +791,9 @@ class QdrantCompat:
         # MicroBatcher's coalesce-wait/dispatch spans land as siblings
         t_rank = time.time()
         out = []
+        # hydration as a running sum (two clock reads a hit, no span a
+        # node): storage.get_node, the filter and _point_dict
+        hydrate_s, hydrated = 0.0, 0
         for nid, score in ranked:
             if score_threshold is not None:
                 true_score = -score if distance == "Euclid" else score
@@ -793,16 +802,21 @@ class QdrantCompat:
                         continue
                 elif true_score < score_threshold:
                     continue
+            t_hit = time.perf_counter()
             try:
                 node = self.storage.get_node(nid)
             except (KeyError, NotFoundError):
                 continue
-            if query_filter is not None and not _match_filter(
+            hydrated += 1
+            d = None
+            if query_filter is None or _match_filter(
                 node.properties.get("payload") or {}, query_filter,
                 point_id=node.properties.get("_point_id"),
             ):
+                d = self._point_dict(node, with_payload, with_vector)
+            hydrate_s += time.perf_counter() - t_hit
+            if d is None:
                 continue
-            d = self._point_dict(node, with_payload, with_vector)
             d["score"] = float(-score if distance == "Euclid" else score)
             out.append(d)
             if len(out) >= limit:
@@ -815,7 +829,9 @@ class QdrantCompat:
             # already ordered, so this is a no-op for them.
             out.sort(key=lambda d: -d["score"])
         _obs_attach_span("qdrant.rank", t_rank, time.time(),
-                         collection=name, distance=distance)
+                         collection=name, distance=distance,
+                         hydrate_ms=round(hydrate_s * 1e3, 3),
+                         hydrated=hydrated)
         return self._search_cache.put_guarded(cache_key, out,
                                               gen_at_miss)
 
@@ -926,6 +942,7 @@ class QdrantCompat:
         total = len(idx)
         k = 40
         first = True
+        widen_round = 0
         # dedupe by id, not by list position: the batched round-1 call
         # (GEMM over a padded batch) and the direct widening calls can
         # order float near-ties differently, so positional continuation
@@ -945,7 +962,16 @@ class QdrantCompat:
                 # authoritative.
                 ann_round = True
             else:
-                hits = idx.search(q, k=k_req)
+                # the request's own b=1 dispatch, outside the coalescer:
+                # its own span and dispatch kind (never `device.dispatch`
+                # or `coalesce.wait`, which mean the coalesced round). No
+                # yield inside the span: it is live on this context.
+                widen_round += 1
+                t_widen = time.perf_counter()
+                with _obs_span("qdrant.widen", k=k_req, round=widen_round):
+                    hits = idx.search(q, k=k_req)
+                _obs_record_dispatch("vector_widen", 1, pow2_bucket(k_req),
+                                     time.perf_counter() - t_widen)
                 ann_round = False
             for nid, score in hits:
                 if nid in yielded:

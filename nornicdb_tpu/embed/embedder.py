@@ -11,14 +11,26 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from collections import OrderedDict
 from typing import List, Optional, Protocol, Sequence, Set, Tuple
 
 import numpy as np
 
 from nornicdb_tpu.embed.tokenizer import CHUNK_OVERLAP, CHUNK_SIZE, HashTokenizer, chunk_tokens
+from nornicdb_tpu.obs import REGISTRY, declare_kind, record_dispatch
+from nornicdb_tpu.obs import span as _span
 
 logger = logging.getLogger(__name__)
+
+# the encoder forward as a dispatch kind: b = rows, k = width of the
+# padded id array the device is given
+declare_kind("encoder")
+_TOKENS_C = REGISTRY.counter(
+    "nornicdb_embed_tokens_total",
+    "Tokens handed to the encoder forward: real (the id lists' lengths) "
+    "and padded (rows x width of the array the device is given)",
+    labels=("kind",))
 
 
 class Embedder(Protocol):
@@ -119,16 +131,28 @@ class JaxEncoderEmbedder:
         n = len(id_lists)
         width = self._bucket_width(max(len(x) for x in id_lists))
         width = min(width, self.cfg.max_len)
-        arr = np.zeros((pow2_bucket(n), width), np.int32)
+        rows = pow2_bucket(n)
+        arr = np.zeros((rows, width), np.int32)
+        real = 0
         for i, ids in enumerate(id_lists):
             ids = ids[:width]
             arr[i, : len(ids)] = ids
+            real += len(ids)
         arr[n:] = arr[0]  # pad rows repeat row 0 (no all-masked rows)
         try:
-            with self._lock:
-                self.shapes.add(arr.shape)
-                out = self._jit(self.params, jnp.asarray(arr))
-            out = np.asarray(out, dtype=np.float32)
+            # one timing for the span and the dispatch record: from
+            # asking for this embedder's lock to the vectors on the host
+            # (the jitted call returns at enqueue; np.asarray waits)
+            t0 = time.perf_counter()
+            with _span("encoder.forward", rows=rows, width=width):
+                with self._lock:
+                    self.shapes.add(arr.shape)
+                    out = self._jit(self.params, jnp.asarray(arr))
+                out = np.asarray(out, dtype=np.float32)
+            record_dispatch("encoder", rows, width,
+                            time.perf_counter() - t0)
+            _TOKENS_C.labels("real").inc(real)
+            _TOKENS_C.labels("padded").inc(rows * width)
         except Exception:
             # the einsum attention arm materialises [B, heads, S, S]; a
             # batch too large for HBM must name its shape, not vanish

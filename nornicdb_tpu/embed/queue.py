@@ -15,9 +15,37 @@ import threading
 import time
 from typing import Callable, List, Optional
 
+from nornicdb_tpu.obs import REGISTRY
+from nornicdb_tpu.obs.tracing import Span, span as _span, trace as _trace
 from nornicdb_tpu.storage.types import Engine, MutationListener, Node
 
 logger = logging.getLogger(__name__)
+
+# where the worker's wall time goes: the phases of a batch (each the
+# summed duration of that batch's `embed.<phase>` spans, `other` what
+# the batch's root holds beside them) and `starved`, blocked on an
+# empty queue. All phases together are the worker's wall time.
+_WORKER_S = REGISTRY.counter(
+    "nornicdb_embed_worker_seconds_total",
+    "Embed worker wall time by phase (load, encode, chunks, store, "
+    "publish, other, starved)", labels=("phase",))
+_BATCHES_C = REGISTRY.counter(
+    "nornicdb_embed_batches_total", "Batches the embed worker processed")
+
+
+def _account_batch(root) -> None:
+    """Feed the phase counter from a finished ``embed.batch`` root: each
+    direct child ``embed.<phase>`` adds its own duration, so the span and
+    the counter share one timing. Nothing while telemetry is off."""
+    if not isinstance(root, Span) or root.t1 is None:
+        return
+    covered = 0.0
+    for child in root.children:
+        seconds = child.t1 - child.t0
+        covered += seconds
+        _WORKER_S.labels(child.name.partition(".")[2]).inc(seconds)
+    _WORKER_S.labels("other").inc(max(root.t1 - root.t0 - covered, 0.0))
+    _BATCHES_C.inc()
 
 CHUNK_THRESHOLD_CHARS = 2000  # texts longer than this get chunk embeddings
 
@@ -66,6 +94,8 @@ class EmbedQueue(MutationListener):
         self._cluster_timer: Optional[threading.Timer] = None
         self.embedded_count = 0
         self.failed_count = 0
+        # seconds the worker was blocked on the queue since its last batch
+        self._starved_s = 0.0
 
     # -- MutationListener ------------------------------------------------
 
@@ -129,10 +159,15 @@ class EmbedQueue(MutationListener):
         _adm.lane_scope(_adm.LANE_BACKGROUND).__enter__()
         while not self._stop.is_set():
             batch: List[str] = []
+            t_wait = time.perf_counter()
             try:
                 item = self._q.get(timeout=0.25)
             except queue.Empty:
                 continue
+            finally:
+                waited = time.perf_counter() - t_wait
+                self._starved_s += waited
+                _WORKER_S.labels("starved").inc(waited)
             if item is None:
                 break
             batch.append(item)
@@ -151,23 +186,38 @@ class EmbedQueue(MutationListener):
                 logger.exception("embed batch failed")
 
     def _process_batch(self, node_ids: List[str]) -> None:
+        """One batch is one root span ``embed.batch`` (``/admin/traces``,
+        and ``nornic:embed.batch`` in a profiler trace) whose children
+        are its phases; the phase counter is fed from the same spans."""
+        starved, self._starved_s = self._starved_s, 0.0
+        root = None
+        try:
+            with _trace("embed.batch", rows=len(node_ids),
+                        starved_ms=round(starved * 1e3, 3)) as root:
+                self._embed_and_store(node_ids)
+        finally:
+            _account_batch(root)
+
+    def _embed_and_store(self, node_ids: List[str]) -> None:
         nodes = []
-        for nid in node_ids:
-            try:
-                node = self.storage.get_node(nid)
-            except KeyError:
-                with self._lock:
-                    self._pending.discard(nid)
-                continue
-            if node.embedding is not None:
-                with self._lock:
-                    self._pending.discard(nid)
-                continue
-            nodes.append(node)
+        with _span("embed.load"):
+            for nid in node_ids:
+                try:
+                    node = self.storage.get_node(nid)
+                except KeyError:
+                    with self._lock:
+                        self._pending.discard(nid)
+                    continue
+                if node.embedding is not None:
+                    with self._lock:
+                        self._pending.discard(nid)
+                    continue
+                nodes.append(node)
+            texts = [build_embedding_text(n) for n in nodes]
         if not nodes:
             return
-        texts = [build_embedding_text(n) for n in nodes]
-        vectors = self._embed_with_retry(texts)
+        with _span("embed.encode"):
+            vectors = self._embed_with_retry(texts)
         if vectors is None:
             self.failed_count += len(nodes)
             for n in nodes:
@@ -178,28 +228,35 @@ class EmbedQueue(MutationListener):
             # per-node isolation: one failing write must not wedge the rest
             # of the batch in _pending (they'd never re-enqueue)
             try:
-                try:
-                    fresh = self.storage.get_node(node.id)
-                except KeyError:
-                    continue
-                fresh.embedding = list(vec)
+                chunk_vectors = None
                 if len(text) > CHUNK_THRESHOLD_CHARS and hasattr(
                     self.embedder, "embed_chunks"
                 ):
+                    with _span("embed.chunks"):
+                        try:
+                            chunk_vectors = self.embedder.embed_chunks(text)
+                        except Exception:
+                            logger.exception(
+                                "chunk embed failed for %s", node.id)
+                with _span("embed.store"):
                     try:
-                        fresh.chunk_embeddings = self.embedder.embed_chunks(text)
-                    except Exception:
-                        logger.exception("chunk embed failed for %s", node.id)
-                try:
-                    self.storage.update_node(fresh)
-                except KeyError:
-                    continue  # deleted concurrently
+                        fresh = self.storage.get_node(node.id)
+                    except KeyError:
+                        continue
+                    fresh.embedding = list(vec)
+                    if chunk_vectors is not None:
+                        fresh.chunk_embeddings = chunk_vectors
+                    try:
+                        self.storage.update_node(fresh)
+                    except KeyError:
+                        continue  # deleted concurrently
                 self.embedded_count += 1
                 if self.on_embedded is not None:
-                    try:
-                        self.on_embedded(fresh)
-                    except Exception:
-                        logger.exception("on_embedded callback failed")
+                    with _span("embed.publish"):
+                        try:
+                            self.on_embedded(fresh)
+                        except Exception:
+                            logger.exception("on_embedded callback failed")
             except Exception:
                 logger.exception("embed write failed for %s", node.id)
                 self.failed_count += 1

@@ -63,6 +63,13 @@ IMPORT_TIME_MODULES = (
     # ISSUE 20: device-truth calibration plane — compile split,
     # roofline gauges, recompile counter, memory-ledger families
     "nornicdb_tpu.obs.device",
+    # ISSUE 26: the hot-path spans' counters — embed worker phases and
+    # token fill, the encoder / vector_widen dispatch kinds, the
+    # in-memory engine's lock wait
+    "nornicdb_tpu.embed.queue",
+    "nornicdb_tpu.embed.embedder",
+    "nornicdb_tpu.api.qdrant",
+    "nornicdb_tpu.storage.memory",
 )
 
 _PREFIX = "nornicdb_"

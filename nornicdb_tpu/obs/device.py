@@ -191,6 +191,11 @@ class _DispatchScope:
 
     def __exit__(self, *exc) -> None:
         _tls.scope = self._prev
+        if self._prev is None:
+            # a rider count nobody priced inside the scope (telemetry
+            # off, an empty index) must not pad-correct the next,
+            # unrelated cost on this thread
+            _tls.real_rows = None
 
 
 def dispatch_scope(kind: str) -> _DispatchScope:

@@ -44,11 +44,6 @@ _LATENCY_H = REGISTRY.histogram(
     "nornicdb_device_dispatch_seconds",
     "Device dispatch wall time (first call includes compile)",
     labels=("kind",))
-_FIRST_G = REGISTRY.gauge(
-    "nornicdb_device_first_call_seconds",
-    "Wall time of the first call per bucket: compile AND execute "
-    "conflated (the calibrated split is nornicdb_device_compile_seconds)",
-    labels=("kind", "b", "k"))
 
 
 def set_observer(
@@ -88,7 +83,6 @@ def record_dispatch(kind: str, b: int, k: int, seconds: float) -> None:
     _LATENCY_H.labels(kind).observe(seconds)
     if first:
         _COMPILE_C.labels(kind).inc()
-        _FIRST_G.labels(kind, b, k).set(seconds)
     obs_fn = _observer
     if obs_fn is not None:
         obs_fn(kind, int(b), int(k), seconds, first)

@@ -28,6 +28,18 @@ span tree (:func:`export_span`) in the response and the originating
 side grafts it (:func:`attach_span_tree`) into the live root, so
 ``/admin/traces`` on the ingress worker shows the full
 wire -> ring -> coalesce -> device.dispatch -> merge chain.
+
+The profiler's clock: every LIVE span (``trace``/``span``/
+``propagated_trace``, roots included) also enters a
+``jax.profiler.TraceAnnotation("nornic:" + name)``, so a profiler trace
+of the process (``.xplane.pb``) holds the program's own spans on the
+same clock as the device's operations and an idle gap on the device can
+be given to what the host was doing in it. Intervals grafted with
+``attach_span`` (``coalesce.wait``, ``device.dispatch``, ``qdrant.rank``)
+were timed by another thread or after the fact and stay host-clock only.
+JAX is never imported from here: the annotation binds once ``jax`` is
+already in ``sys.modules`` (a process that never imports JAX, such as
+``bench.py``'s parent, pays one dict probe a span).
 """
 
 from __future__ import annotations
@@ -36,6 +48,7 @@ import contextvars
 import itertools
 import os
 import re
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -171,6 +184,24 @@ def current_span() -> Optional[Span]:
     return _current.get()
 
 
+PROFILER_PREFIX = "nornic:"
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once bound
+
+
+def _profiler_annotation(name: str):
+    """A ``TraceAnnotation`` for a live span, or None while this process
+    has not imported JAX. With no profiler session open an annotation
+    costs well under a microsecond (one flag read in the tracer)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:  # no JAX here, or still being imported
+            return None
+        _trace_annotation = profiler.TraceAnnotation
+    return _trace_annotation(PROFILER_PREFIX + name)
+
+
 class _ActiveSpan:
     """Context manager binding a span as the contextvar current.
 
@@ -178,7 +209,8 @@ class _ActiveSpan:
     root span instead of minting a fresh one — the cross-process
     propagation path (:func:`propagated_trace`)."""
 
-    __slots__ = ("span", "_token", "_root", "_tid_token", "_tid")
+    __slots__ = ("span", "_token", "_root", "_tid_token", "_tid",
+                 "_annotation")
 
     def __init__(self, span: Span, root: bool,
                  tid: Optional[str] = None) -> None:
@@ -187,15 +219,21 @@ class _ActiveSpan:
         self._token = None
         self._tid_token = None
         self._tid = tid
+        self._annotation = None
 
     def __enter__(self) -> Span:
         self._token = _current.set(self.span)
         if self._root:
             self.span.trace_id = self._tid or _new_trace_id()
             self._tid_token = _current_tid.set(self.span.trace_id)
+        self._annotation = _profiler_annotation(self.span.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         self.span.finish()
         if exc_type is not None:
             self.span.attrs.setdefault("error", f"{exc_type.__name__}")

@@ -11,12 +11,14 @@ capacity so jit never sees a new shape per insert.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
 from nornicdb_tpu.obs import cost as _cost
+from nornicdb_tpu.obs.tracing import span as _span
 from nornicdb_tpu.ops.similarity import (
     CHUNKED_THRESHOLD,
     cosine_topk,
@@ -609,45 +611,69 @@ class BruteForceIndex:
         # is the exact float32 brute tier (the quant plane notes its
         # own tier before returning above)
         _audit.note_batch_tier("vector_brute_f32")
-        with self._lock:
-            if self._n_alive == 0:
-                return [[] for _ in range(len(queries))]
-            k_eff = min(k, self._n_alive)
-            # per-query cost accounting: the brute scan's price is its
-            # known shapes — B queries against the capacity-padded
-            # [C, D] matrix (host or device, the arithmetic is the same)
-            if _cost.pricing_enabled():
-                flops, byts = _cost.price_brute(
-                    len(queries), self._capacity, self.dims or 1)
-                _cost.record_query_cost("brute", _cost.cost_name(self),
-                                        len(queries), flops, byts)
-            if self._capacity * (self.dims or 1) <= self._SMALL_HOST:
-                # no defensive copies: the whole host search runs under
-                # the lock and only reads the matrix/valid/ext_ids
-                return self._search_host(
-                    np.asarray(queries, np.float32), self._matrix,
-                    self._valid, self._ext_ids, k_eff)
-            m, valid = self._device_arrays_locked()
-            ext_ids = list(self._ext_ids)
-        q = l2_normalize(jnp.asarray(queries, dtype=jnp.float32))
-        if _use_pallas():
-            from nornicdb_tpu.ops.pallas_topk import fused_cosine_topk
+        # three child spans of whoever searches (the batch leader's
+        # root, or ``qdrant.widen``): the lock and the copies made under
+        # it, the scan until its result is on the host, the result loop.
+        # ``path`` on index.scan is the tier label that tells host NumPy
+        # from the chip, which ``vector_brute_f32`` does not.
+        with _span("index.snapshot") as snap:
+            t_ask = time.perf_counter()
+            with self._lock:
+                snap.annotate(lock_wait_ms=round(
+                    (time.perf_counter() - t_ask) * 1e3, 3))
+                if self._n_alive == 0:
+                    return [[] for _ in range(len(queries))]
+                k_eff = min(k, self._n_alive)
+                # per-query cost accounting: the brute scan's price is
+                # its known shapes — B queries against the
+                # capacity-padded [C, D] matrix (host or device, the
+                # arithmetic is the same)
+                if _cost.pricing_enabled():
+                    flops, byts = _cost.price_brute(
+                        len(queries), self._capacity, self.dims or 1)
+                    _cost.record_query_cost(
+                        "brute", _cost.cost_name(self), len(queries),
+                        flops, byts)
+                if self._capacity * (self.dims or 1) <= self._SMALL_HOST:
+                    # no defensive copies: the whole host search runs
+                    # under the lock and only reads the matrix/valid/
+                    # ext_ids, so its scan nests inside the snapshot
+                    with _span("index.scan", path="host",
+                               b=len(queries), k=k_eff):
+                        return self._search_host(
+                            np.asarray(queries, np.float32), self._matrix,
+                            self._valid, self._ext_ids, k_eff)
+                m, valid = self._device_arrays_locked()
+                ext_ids = list(self._ext_ids)
+        pallas = _use_pallas()
+        # from the call into the jitted scan to its result on the host:
+        # the wait behind other callers' scans, the execution, D2H
+        with _span("index.scan", path="pallas" if pallas else "xla",
+                   b=len(queries), k=k_eff):
+            q = l2_normalize(jnp.asarray(queries, dtype=jnp.float32))
+            if pallas:
+                from nornicdb_tpu.ops.pallas_topk import fused_cosine_topk
 
-            s, i = fused_cosine_topk(q, m, valid, k_eff)
-        else:
-            s, i = cosine_topk_auto(q, m, valid, k_eff)
-        s = np.asarray(s)
-        i = np.asarray(i)
+                s, i = fused_cosine_topk(q, m, valid, k_eff)
+            else:
+                s, i = cosine_topk_auto(q, m, valid, k_eff)
+            s = np.asarray(s)
+            i = np.asarray(i)
         out: List[List[Tuple[str, float]]] = []
-        for row in range(s.shape[0]):
-            hits = []
-            for col in range(s.shape[1]):
-                if s[row, col] < -1e29:
-                    break
-                eid = ext_ids[int(i[row, col])]
-                if eid is not None:
-                    hits.append((eid, float(s[row, col])))
-            out.append(hits)
+        with _span("index.collect"):
+            for row in range(s.shape[0]):
+                hits = []
+                for col in range(s.shape[1]):
+                    if s[row, col] < -1e29:
+                        break
+                    eid = ext_ids[int(i[row, col])]
+                    if eid is not None:
+                        hits.append((eid, float(s[row, col])))
+                out.append(hits)
+            # freeing the id-list copy costs as much as making it (one
+            # reference dropped an entry); dropped here so that it is
+            # timed inside a span and not at the return, between spans
+            del ext_ids
         return out
 
     # -- bulk access (for HNSW/kmeans builds) ------------------------------

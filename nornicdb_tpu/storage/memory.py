@@ -7,9 +7,12 @@ edge-type secondary indexes plus per-node adjacency for O(1) degree queries.
 from __future__ import annotations
 
 import threading
+import time
+import weakref
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from nornicdb_tpu.errors import AlreadyExistsError, NotFoundError
+from nornicdb_tpu.obs.metrics import REGISTRY
 from nornicdb_tpu.storage.types import (
     Direction,
     Edge,
@@ -21,9 +24,44 @@ from nornicdb_tpu.storage.types import (
 )
 
 
+_LOCK_WAIT_C = REGISTRY.counter(
+    "nornicdb_storage_lock_wait_seconds_total",
+    "Seconds callers waited for the in-memory engine's one lock, by "
+    "operation (are readers convoying?)", labels=("op",))
+_LOCK_ACQUIRES_C = REGISTRY.counter(
+    "nornicdb_storage_lock_acquires_total",
+    "Acquisitions of the in-memory engine's lock, by operation",
+    labels=("op",))
+_engines: "weakref.WeakSet[MemoryEngine]" = weakref.WeakSet()
+_engines_lock = threading.Lock()
+
+
+def _export_lock_counters() -> None:
+    """Scrape-time collector: move what each live engine's get_node has
+    added to its two plain fields since the last scrape into the
+    counters, so the hot read pays two clock reads and two adds."""
+    with _engines_lock:
+        engines = list(_engines)
+    for engine in engines:
+        with engine._lock:
+            wait, engine._get_wait_s = engine._get_wait_s, 0.0
+            acquires, engine._get_acquires = engine._get_acquires, 0
+        _LOCK_WAIT_C.labels("get_node").inc(wait)
+        _LOCK_ACQUIRES_C.labels("get_node").inc(acquires)
+
+
+REGISTRY.add_collector(_export_lock_counters)
+
+
 class MemoryEngine(Engine):
     def __init__(self):
         self._lock = threading.RLock()
+        # get_node's lock wait and acquires since the last scrape,
+        # written under the lock (_export_lock_counters drains them)
+        self._get_wait_s = 0.0
+        self._get_acquires = 0
+        with _engines_lock:
+            _engines.add(self)
         self._nodes: Dict[NodeID, Node] = {}
         self._edges: Dict[EdgeID, Edge] = {}
         self._by_label: Dict[str, Set[NodeID]] = {}
@@ -47,7 +85,10 @@ class MemoryEngine(Engine):
                 self._by_label.setdefault(label, set()).add(n.id)
 
     def get_node(self, node_id: NodeID) -> Node:
+        t_ask = time.perf_counter()
         with self._lock:
+            self._get_wait_s += time.perf_counter() - t_ask
+            self._get_acquires += 1
             n = self._nodes.get(node_id)
             if n is None:
                 raise NotFoundError(f"node {node_id} not found")

@@ -119,16 +119,23 @@ class TestCalibrationMath:
             0.120 + 20 * 0.010 - 0.110, rel=0.01)
         assert doc["compile_shapes_split"] == 1
 
-    def test_first_call_series_keeps_conflated_meaning(self):
-        _feed("legacy_kind", 4, 8, 0.2, 0.01, 10)
-        # PR 3's series is byte-compatible: the first-call gauge still
-        # carries the CONFLATED wall time; the calibrated split lives
-        # in its own family
-        dsp.record_dispatch("legacy_kind", 4, 8, 0.0)  # ensure family
-        fam = obs.REGISTRY.get("nornicdb_device_first_call_seconds")
-        assert fam is not None
-        assert "conflated" in fam.help or "compile AND execute" \
-            in fam.help
+    def test_compile_series_supersedes_first_call_gauge(self):
+        dsp.reset()
+        dsp.record_dispatch("legacy_kind", 4, 8, 0.2)
+        for _ in range(10):
+            dsp.record_dispatch("legacy_kind", 4, 8, 0.01)
+        # PR 3's conflated first-call gauge is gone (nothing read it);
+        # the calibrated split is the series, and the conflated wall
+        # time stays in the admin view of the compile universe
+        obs.REGISTRY.run_collectors()
+        assert obs.REGISTRY.get(
+            "nornicdb_device_first_call_seconds") is None
+        fam = obs.REGISTRY.get("nornicdb_device_compile_seconds")
+        assert fam.labels("legacy_kind", 4, 8).value == pytest.approx(
+            0.19, rel=0.01)
+        shape = next(e for e in dsp.compile_universe()
+                     if e["kind"] == "legacy_kind")
+        assert shape["first_call_ms"] == pytest.approx(200.0)
 
     def test_roofline_join_and_padding_efficiency(self):
         _feed("fake_kind", 8, 16, 0.020, 0.010, 20)

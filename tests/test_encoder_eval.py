@@ -121,13 +121,16 @@ def test_trained_beats_random_init(trained):
     assert trained_res.recall > random_res.recall
 
 
-def test_db_default_embedder_is_trained_encoder():
+def test_db_default_embedder_is_trained_encoder(monkeypatch):
     """db.open() without an explicit embedder uses the committed
     checkpoint (reference default: local embeddings always on,
     embed.go; here the committed mini encoder plays bge-m3's role)."""
     import nornicdb_tpu
     from nornicdb_tpu.embed.embedder import CachedEmbedder, JaxEncoderEmbedder
 
+    # other test files of the same worker force the hash embedder for
+    # the whole process (os.environ.setdefault)
+    monkeypatch.delenv("NORNICDB_TPU_EMBEDDER", raising=False)
     db = nornicdb_tpu.open(auto_embed=False)
     try:
         emb = db._embedder
