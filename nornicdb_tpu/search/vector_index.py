@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from nornicdb_tpu.obs import cost as _cost
+from nornicdb_tpu.obs.metrics import REGISTRY
 from nornicdb_tpu.obs.tracing import span as _span
 from nornicdb_tpu.ops.similarity import (
     CHUNKED_THRESHOLD,
@@ -27,6 +28,15 @@ from nornicdb_tpu.ops.similarity import (
     l2_normalize,
     pad_dim,
 )
+
+
+# how often a reader of the slot-to-id table was handed the generation's
+# shared snapshot (reused) against a fresh copy after a write (copied)
+_IDS_SNAPSHOT_C = REGISTRY.counter(
+    "nornicdb_index_ids_snapshot_total",
+    "Slot-to-id snapshots handed to index readers, by whether the "
+    "mutation generation's snapshot was shared or rebuilt",
+    labels=("result",))
 
 
 def _use_pallas() -> bool:
@@ -81,7 +91,8 @@ class BruteForceIndex:
         self._dev_matrix = None
         self._dev_valid = None
         self._dirty = True
-        # (mutations, ext_ids copy) memo for device_view consumers
+        # (mutations, tuple(_ext_ids)): the slot-to-id snapshot every
+        # reader of that generation shares (_ids_snapshot_locked)
         self._view_ids_cache = None
         # quantized serving plane (search/device_quant.py), created
         # lazily when NORNICDB_VECTOR_QUANT != off and the corpus
@@ -376,6 +387,25 @@ class BruteForceIndex:
             self._dirty = False
         return self._dev_matrix, self._dev_valid
 
+    def _ids_snapshot_locked(self):
+        """(slot-to-id snapshot, ``"reused"`` | ``"copied"``) for the
+        current ``mutations`` generation; call with the index lock held.
+        The snapshot is rebuilt only when a write has moved the
+        generation (every writer of ``_ext_ids`` bumps ``mutations``
+        under the lock), is shared by every reader of that generation
+        and is a tuple because nobody may write it: a reader that
+        captured it keeps resolving slots to the ids they held then,
+        whatever is freed and reused afterwards."""
+        cached = self._view_ids_cache
+        if cached is not None and cached[0] == self.mutations:
+            result = "reused"
+        else:
+            cached = (self.mutations, tuple(self._ext_ids))
+            self._view_ids_cache = cached
+            result = "copied"
+        _IDS_SNAPSHOT_C.labels(result).inc()
+        return cached[1], result
+
     def view_meta(self):
         """(mutations, compactions) — or None while the index is empty
         — WITHOUT forcing the device arrays current. The walk tier
@@ -389,39 +419,35 @@ class BruteForceIndex:
             return self.mutations, self.compactions
 
     def ids_meta(self):
-        """(ext_ids copy, mutations, compactions) — or None while
+        """(ext_ids, mutations, compactions) — or None while
         empty — WITHOUT forcing the device arrays current. The
         quantized fused tier joins/decodes against slot ids and must
         not pay the float32 matrix re-ship that :meth:`device_view`
-        implies after a write burst. Shares device_view's per-
-        generation ids memo."""
+        implies after a write burst. ``ext_ids`` is the generation's
+        id snapshot: one per mutation generation, shared with
+        :meth:`device_view` and :meth:`search_batch`, read-only."""
         with self._lock:
             if self._n_alive == 0 or self._matrix is None:
                 return None
-            cached = self._view_ids_cache
-            if cached is None or cached[0] != self.mutations:
-                cached = (self.mutations, list(self._ext_ids))
-                self._view_ids_cache = cached
-            return cached[1], self.mutations, self.compactions
+            ext_ids, _ = self._ids_snapshot_locked()
+            return ext_ids, self.mutations, self.compactions
 
     def device_view(self):
         """Consistent device-side view for external batched kernels (the
         fused hybrid pipeline): (matrix[C,D], valid[C], ext_ids,
         mutations, compactions) captured atomically, or None while the
         index is empty. The matrix/valid arrays are the same lazily
-        synced device cache ``search_batch`` dispatches against; the
-        ext_ids copy is memoized per mutation generation so a steady
-        read stream doesn't re-copy a capacity-sized list per batch."""
+        synced device cache ``search_batch`` dispatches against;
+        ``ext_ids`` is the generation's id snapshot: one per mutation
+        generation, shared with :meth:`ids_meta` and
+        :meth:`search_batch`, read-only, so a steady read stream
+        doesn't re-copy a capacity-sized list per batch."""
         with self._lock:
             if self._n_alive == 0 or self._matrix is None:
                 return None
             m, valid = self._device_arrays_locked()
-            cached = self._view_ids_cache
-            if cached is None or cached[0] != self.mutations:
-                cached = (self.mutations, list(self._ext_ids))
-                self._view_ids_cache = cached
-            return m, valid, cached[1], self.mutations, \
-                self.compactions
+            ext_ids, _ = self._ids_snapshot_locked()
+            return m, valid, ext_ids, self.mutations, self.compactions
 
     def search(
         self, query: Sequence[float], k: int = 10
@@ -594,7 +620,12 @@ class BruteForceIndex:
         With ``NORNICDB_VECTOR_QUANT`` set, large corpora serve through
         the quantized coarse+exact-rerank plane instead (answers remain
         exact-rescored float32; ``exact=True`` bypasses the plane for
-        callers whose contract is exhaustive recall)."""
+        callers whose contract is exhaustive recall). Slots resolve
+        through the id snapshot of the mutation generation the scan
+        ran against: one per generation, shared with
+        :meth:`device_view` and :meth:`ids_meta`, read-only, rebuilt
+        by the first read after a write under the lock this call
+        already takes."""
         from nornicdb_tpu.obs import audit as _audit
 
         if not exact:
@@ -612,7 +643,7 @@ class BruteForceIndex:
         # own tier before returning above)
         _audit.note_batch_tier("vector_brute_f32")
         # three child spans of whoever searches (the batch leader's
-        # root, or ``qdrant.widen``): the lock and the copies made under
+        # root, or ``qdrant.widen``): the lock and what is captured under
         # it, the scan until its result is on the host, the result loop.
         # ``path`` on index.scan is the tier label that tells host NumPy
         # from the chip, which ``vector_brute_f32`` does not.
@@ -643,8 +674,10 @@ class BruteForceIndex:
                         return self._search_host(
                             np.asarray(queries, np.float32), self._matrix,
                             self._valid, self._ext_ids, k_eff)
+                # one lock hold: (m, valid, ext_ids) are one generation
                 m, valid = self._device_arrays_locked()
-                ext_ids = list(self._ext_ids)
+                ext_ids, ids = self._ids_snapshot_locked()
+                snap.annotate(ids=ids)
         pallas = _use_pallas()
         # from the call into the jitted scan to its result on the host:
         # the wait behind other callers' scans, the execution, D2H
@@ -670,10 +703,6 @@ class BruteForceIndex:
                     if eid is not None:
                         hits.append((eid, float(s[row, col])))
                 out.append(hits)
-            # freeing the id-list copy costs as much as making it (one
-            # reference dropped an entry); dropped here so that it is
-            # timed inside a span and not at the return, between spans
-            del ext_ids
         return out
 
     # -- bulk access (for HNSW/kmeans builds) ------------------------------

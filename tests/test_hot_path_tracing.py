@@ -2,7 +2,8 @@
 
 What is pinned here, on the CPU:
 
-- a search that widens yields ``qdrant.widen`` > ``index.snapshot``,
+- a search that widens yields ``qdrant.widen`` > ``index.snapshot``
+  (with ``ids``: the id snapshot ``reused`` or ``copied``, ISSUE 27),
   ``index.scan`` (with its ``path``), ``index.collect``, inside the
   interval ``qdrant.rank`` covers, and ``qdrant.rank`` carries the
   hydration's running sum;
@@ -131,6 +132,32 @@ class TestVectorReadPath:
                  if e["kind"] == "vector_widen"}
         assert after[(1, 256)] == before.get((1, 256), 0) + 1
         assert "vector_widen" in obs.dispatch.bucket_counts()
+
+    def test_snapshot_span_says_whether_the_ids_were_copied(
+            self, device_collection):
+        def counts():
+            fam = obs.REGISTRY.get("nornicdb_index_ids_snapshot_total")
+            return {r: fam.labels(r).value for r in ("reused", "copied")}
+
+        compat, vectors = device_collection
+        # the same vector over the same point: a write, so a generation
+        compat.upsert_points("c", [
+            {"id": 0, "vector": vectors[0].tolist(), "payload": {"n": 0}}])
+        before = counts()
+        root, _ = _search(compat, vectors[9])
+        snapshots = _named(root, "index.snapshot")
+        # one count and one ``ids`` a search_batch call: the coalesced
+        # round rebuilds after the write, the widening round shares it
+        assert [s.attrs["ids"] for s in snapshots] == ["copied", "reused"]
+        assert counts() == {"copied": before["copied"] + 1,
+                            "reused": before["reused"] + 1}
+        root, _ = _search(compat, vectors[10])
+        assert [s.attrs["ids"] for s in _named(root, "index.snapshot")] \
+            == ["reused", "reused"]
+        assert counts() == {"copied": before["copied"] + 1,
+                            "reused": before["reused"] + 3}
+        text = obs.REGISTRY.render()
+        assert 'nornicdb_index_ids_snapshot_total{result="reused"}' in text
 
     def test_disabled_telemetry_leaves_no_span(self, device_collection):
         compat, vectors = device_collection
