@@ -362,6 +362,9 @@ class DB:
                 def on_edge_delete(self, edge_id):
                     ex.on_external_mutation()
 
+                def on_bulk_change(self):
+                    ex.on_external_mutation()
+
             self._listenable.add_listener(_CacheInvalidator())
         return self._executor
 
@@ -447,7 +450,9 @@ class DB:
         from nornicdb_tpu.embed.queue import EmbedQueue
 
         self._embed_queue = EmbedQueue(
-            self.storage, self._embedder, on_embedded=self._on_embedded
+            self.storage, self._embedder, on_embedded=self._on_embedded,
+            has_vector=lambda nid: (self._search is not None
+                                    and nid in self._search.vectors),
         )
         self._listenable.add_listener(self._embed_queue)
         self._embed_queue.start()
@@ -494,6 +499,55 @@ class DB:
         if auto_link and embedding is not None:
             self.inference.on_store(node)
         return self.storage.get_node(nid)
+
+    def store_batch(
+        self,
+        contents: Sequence[str],
+        embeddings: Any,
+        node_ids: Optional[Sequence[str]] = None,
+        labels: Optional[Sequence[str]] = None,
+    ) -> List[str]:
+        """Bulk ``store`` with the caller's embeddings: one node per
+        content (``properties={"content": ...}``, the same ``labels``
+        for all), created under one hold of the engine's lock
+        (``Engine.create_nodes``) and indexed in one call
+        (``SearchService.index_batch``), with no per-node listener
+        event, so nothing is embedded or indexed a second time.
+        ``embeddings`` is a float32 ``[n, dims]`` matrix; the vectors go
+        to the index and its snapshot, and the nodes carry no
+        ``embedding`` list (33 KB a node as Python floats at 1,024
+        dims). Returns the ids.
+
+        Part of the contract: the load ends with ``gc.freeze()``
+        (``search.service.gc_paused``), which takes what it built, and
+        whatever else is alive in the process at that moment, out of the
+        cyclic collector's later passes for good. A server calls this
+        to fill a store that stays; a process that loads and drops
+        stores over and over should ``gc.unfreeze()`` between them."""
+        import numpy as np
+
+        from nornicdb_tpu.search.service import extract_text, gc_paused
+
+        labels = list(labels or ["Memory"])
+        if any(lbl.startswith("_") for lbl in labels):
+            raise ValueError("store_batch is not for system-owned labels")
+        contents = list(contents)
+        ids = ([str(uuid.uuid4()) for _ in contents]
+               if node_ids is None else list(node_ids))
+        embeddings = np.asarray(embeddings, dtype=np.float32)
+        if not (len(ids) == len(contents) == len(embeddings)):
+            raise ValueError(
+                f"store_batch: {len(ids)} ids, {len(contents)} contents, "
+                f"embeddings {embeddings.shape}")
+        search = self.search     # built, and back-filled, before the load
+        with gc_paused(freeze=True):
+            nodes = [Node(id=nid, labels=list(labels),
+                          properties={"content": content})
+                     for nid, content in zip(ids, contents)]
+            self.storage.create_nodes(nodes)
+            search.index_batch(ids, [extract_text(n) for n in nodes],
+                               embeddings)
+        return ids
 
     def recall(self, query: str, limit: int = 10, **kw) -> List[Dict[str, Any]]:
         """Hybrid search over stored memories (reference: db.go:2107 Recall)."""

@@ -76,10 +76,15 @@ class EmbedQueue(MutationListener):
         rescan_interval_s: float = 900.0,
         cluster_debounce_s: float = 30.0,
         on_cluster: Optional[Callable[[], None]] = None,
+        has_vector: Optional[Callable[[str], bool]] = None,
     ):
         self.storage = storage
         self.embedder = embedder
         self.on_embedded = on_embedded
+        # a node whose vector lives in the search index alone (a bulk
+        # load with the caller's embeddings, ``DB.store_batch``) has no
+        # ``embedding`` list: the rescan must not take it for a miss
+        self.has_vector = has_vector
         self.batch_size = batch_size
         self.max_retries = max_retries
         self.rescan_interval_s = rescan_interval_s
@@ -311,6 +316,8 @@ class EmbedQueue(MutationListener):
                         node.embedding is None
                         and not embed_exempt(node)
                         and build_embedding_text(node)
+                        and not (self.has_vector is not None
+                                 and self.has_vector(node.id))
                     ):
                         self.enqueue(node.id)
             except Exception:

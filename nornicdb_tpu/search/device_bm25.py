@@ -13,14 +13,16 @@ closed it for graph ANN:
   space. Terms are sorted so host and device accumulate per-doc scores
   in the same order.
 - **Scoring** (one jitted program per pow2 bucket): the host plans a
-  query batch by flattening each query's term posting ranges into
-  ``(posting_ptr, query_row, idf)`` entry columns (idf comes from the
-  index's *incremental live-df counters*, so deletes correct df without
-  touching the snapshot); the device gathers postings, applies the
-  vectorized Okapi tf normalization, segment-sums into a dense
-  ``[B, C]`` score matrix and takes one top-k. Batch, entry count and k
-  pad to power-of-two buckets (``microbatch.pow2_bucket``) so the XLA
-  compile universe stays bounded.
+  query batch as each unique term's posting RANGE and an idf-weighted
+  ``[B, U]`` selection matrix (idf comes from the index's *incremental
+  live-df counters*, so deletes correct df without touching the
+  snapshot); the device walks the ranges' postings in fixed chunks,
+  applies the vectorized Okapi tf normalization, scatters them into a
+  ``[U, C]`` matrix, multiplies into a dense ``[B, C]`` score matrix
+  and takes one top-k. Batch and k pad to power-of-two buckets
+  (``microbatch.pow2_bucket``), U follows the batch bucket
+  (``LEX_TERMS_PER_QUERY``) and the number of postings is no shape at
+  all, so the XLA compile universe is (B, k).
 - **Sharding** (``shard_map``): postings, doc vectors and the planned
   entry columns row-shard over the ``data`` mesh axis; each shard
   scores its local rows, then one all-gather + top-k merges shard-local
@@ -65,9 +67,9 @@ declare_kind("bm25_score")
 
 
 class PlanOverflow(Exception):
-    """The (U+1)*C segment-id space of a planned batch would exceed
-    int32 (jax's default index width; segment_sum silently DROPS
-    out-of-range ids). Callers serve the batch host-exact instead."""
+    """The (U+1)*C cell space of a planned batch would exceed int32
+    (jax's default index width). Callers serve the batch host-exact
+    instead."""
 
 
 class SnapshotStale(Exception):
@@ -81,9 +83,29 @@ class SnapshotStale(Exception):
 # ---------------------------------------------------------------------------
 
 
+# postings scored per step of the device loop (below): the entries of a
+# batch are walked in chunks of this many, so what a dispatch costs
+# follows the postings its terms have, and no program's shape does
+LEX_CHUNK = 65536
+
+# unique-term rows a program has for each row of its batch bucket: the
+# [B, U] selection matrix and the [U+1, C] scatter are compiled at
+# U = LEX_TERMS_PER_QUERY x B (or the next power of two that holds the
+# batch's terms), so a batch bucket has ONE program as long as its
+# queries average no more than this many distinct scoring terms.
+# 16 holds every batch of queries of up to 16 terms in that one program;
+# it is not the fastest: what a dispatch costs grows with U (the
+# scatter's target, its layout conversion, the product), and at a
+# million passages U = 8 x B ran a batch of 12.8 six-term riders in 105 ms
+# where 16 x B takes 141 (v5e, PR 28; 76 queries/s against 61). The
+# smaller U needs a second warmed program a bucket for the batches that
+# overflow it: ROADMAP S12
+LEX_TERMS_PER_QUERY = 16
+
+
 def bm25_dense_scores(
-    ptr: jnp.ndarray,  # [P] int32 indices into post_doc/post_tf
-    urow: jnp.ndarray,  # [P] int32 unique-term row per entry
+    tstart: jnp.ndarray,  # [U] int32 first posting of each unique term
+    tlen: jnp.ndarray,  # [U] int32 postings of each unique term (0 = pad)
     sel: jnp.ndarray,  # [B, U] f32 idf-weighted term-selection matrix
     post_doc: jnp.ndarray,  # [Pcap] int32 doc row per posting
     post_tf: jnp.ndarray,  # [Pcap] f32 OR uint16 term freq per posting
@@ -92,7 +114,8 @@ def bm25_dense_scores(
     avgdl: jnp.ndarray,  # scalar f32
 ) -> jnp.ndarray:
     """Dense BM25 scores [B, C]; rows with no matching live term (and
-    padding entries, whose sel columns are all-zero) come out NEG_INF.
+    padding terms, whose ranges are empty and whose sel columns are
+    all-zero) come out NEG_INF.
 
     The aggregation is term-deduplicated across the batch: postings
     scatter ONCE per unique query term into a [U, C] tf-norm matrix
@@ -102,42 +125,82 @@ def bm25_dense_scores(
     zipfian traffic — thus pays the scatter once per term, not once per
     (query, term): the device dispatch gets CHEAPER per query as the
     MicroBatcher coalesces harder. Okapi contributions are strictly
-    positive, so `score > 0` IS the touched-by-a-query-term mask."""
+    positive, so `score > 0` IS the touched-by-a-query-term mask.
+
+    The host hands over each unique term's posting RANGE, not its
+    postings: the flat entry space (term 0's postings, then term 1's,
+    ...) is walked here in chunks of ``LEX_CHUNK`` by a loop whose trip
+    count is the batch's own, so neither the host nor the program's
+    shape sees the number of entries (at a million documents a batch of
+    32 queries has millions, and a power-of-two bucket of them was one
+    more compiled program per bucket)."""
     u = sel.shape[1]
     c = doc_len.shape[0]
-    # cast AFTER the gather: tf and doc-len are integer counts, so the
-    # quantized (uint16) CSR columns are exactly lossless below 65536 —
-    # HBM holds 2-byte columns, the Okapi arithmetic stays float32
-    # bit-identical (PR 8 headroom; f32 columns pass through unchanged)
-    d = post_doc[ptr]
-    tf = post_tf[ptr].astype(jnp.float32)
-    dl = doc_len[d].astype(jnp.float32)
-    tf_norm = tf * (K1 + 1.0) / (tf + K1 * (1.0 - B + B * dl / avgdl))
-    # padding entries carry urow == U and land in a discarded overflow
-    # row, so they can never corrupt a real (term, doc) cell
-    seg = urow * c + d
-    m = jax.ops.segment_sum(tf_norm, seg, num_segments=(u + 1) * c)
+    ch = min(LEX_CHUNK, c)
+    ends = jnp.cumsum(tlen)
+    total = ends[-1]
+    # entry j of term t is posting tstart[t] + (j - begin[t]): the shift
+    # tstart - begin steps at every term's end, and so does t, so both
+    # come from ONE [chunk, U] comparison of j with the ends, summed
+    # along U. No table is gathered from: a 65,536-wide gather out of a
+    # 256-entry table cost the chip as much as the scatter itself
+    # (0.77 s of a 3.0 s fused second, v5e, PR 28)
+    shift = tstart - (ends - tlen)
+    shift_step = jnp.concatenate([shift[:1], shift[1:] - shift[:-1]])
+    lane = jnp.arange(ch, dtype=jnp.int32)
+
+    def chunk(i, m):
+        j = i * ch + lane
+        live = j < total
+        past = j[:, None] >= ends[None, :]            # [chunk, U]
+        t = jnp.sum(past, axis=1, dtype=jnp.int32)    # ends at or below j
+        p = j + shift_step[0] + jnp.sum(
+            jnp.where(past[:, :-1], shift_step[None, 1:], 0), axis=1,
+            dtype=jnp.int32)
+        p = jnp.where(live, p, 0)
+        # cast AFTER the gather: tf and doc-len are integer counts, so
+        # the quantized (uint16) CSR columns are exactly lossless below
+        # 65536 — HBM holds 2-byte columns, the Okapi arithmetic stays
+        # float32 bit-identical (PR 8 headroom; f32 columns pass
+        # through unchanged)
+        d = post_doc[p]
+        tf = post_tf[p].astype(jnp.float32)
+        dl = doc_len[d].astype(jnp.float32)
+        tf_norm = tf * (K1 + 1.0) / (tf + K1 * (1.0 - B + B * dl / avgdl))
+        # the tf-norm matrix is kept FLAT, cell (t, d) at t * C + d: the
+        # chip scatters into one dimension, and a two-dimensional target
+        # was converted there and back row by row on every step (0.35 s a
+        # batch of 16 against 0.13 s, v5e, PR 28). Entries past the end
+        # point past the last cell, each at an index of its own, and are
+        # dropped: every index of a chunk is distinct and ascends, and
+        # the scatter is told both
+        return m.at[jnp.where(live, t * c + d, u * c + lane)].add(
+            tf_norm, mode="drop",
+            indices_are_sorted=True, unique_indices=True)
+
+    m = jax.lax.fori_loop(0, (total + ch - 1) // ch, chunk,
+                          jnp.zeros((u * c,), jnp.float32))
     # idf weights and tf-norms are float32 scores the host path ranks
     # on: the exact-tier matmul precision (ops/similarity.EXACT)
-    dense = jnp.matmul(sel, m.reshape(u + 1, c)[:u], precision=EXACT)
+    dense = jnp.matmul(sel, m.reshape(u, c), precision=EXACT)
     return jnp.where((alive_f[None, :] > 0.0) & (dense > 0.0),
                      dense, NEG_INF)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
-def _bm25_topk(ptr, urow, sel, post_doc, post_tf, doc_len, alive_f,
+def _bm25_topk(tstart, tlen, sel, post_doc, post_tf, doc_len, alive_f,
                avgdl, k):
-    dense = bm25_dense_scores(ptr, urow, sel, post_doc, post_tf,
+    dense = bm25_dense_scores(tstart, tlen, sel, post_doc, post_tf,
                               doc_len, alive_f, avgdl)
     return jax.lax.top_k(dense, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k_local",))
-def _bm25_local_topk(ptr, urow, sel, post_doc, post_tf, doc_len,
+def _bm25_local_topk(tstart, tlen, sel, post_doc, post_tf, doc_len,
                      alive_f, avgdl, row_offset, k_local):
     """One shard's local top-k with globalized row ids — the building
     block of the single-device reference merge."""
-    dense = bm25_dense_scores(ptr, urow, sel, post_doc, post_tf,
+    dense = bm25_dense_scores(tstart, tlen, sel, post_doc, post_tf,
                               doc_len, alive_f, avgdl)
     s, i = jax.lax.top_k(dense, k_local)
     return s, i + row_offset
@@ -145,7 +208,7 @@ def _bm25_local_topk(ptr, urow, sel, post_doc, post_tf, doc_len,
 
 @functools.partial(
     jax.jit, static_argnames=("k", "mesh_holder"))
-def _sharded_bm25_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
+def _sharded_bm25_impl(tstart, tlen, sel, post_doc, post_tf, doc_len,
                        alive_f, avgdl, k, mesh_holder):
     from jax.sharding import PartitionSpec as P
 
@@ -156,8 +219,8 @@ def _sharded_bm25_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
     c_local = doc_len.shape[0] // n_shards
     k_local = min(k, c_local)
 
-    def local_fn(ptr_s, urow_s, sel_r, pd_s, pt_s, dl_s, al_s, avg_r):
-        dense = bm25_dense_scores(ptr_s, urow_s, sel_r, pd_s, pt_s,
+    def local_fn(tstart_s, tlen_s, sel_r, pd_s, pt_s, dl_s, al_s, avg_r):
+        dense = bm25_dense_scores(tstart_s, tlen_s, sel_r, pd_s, pt_s,
                                   dl_s, al_s, avg_r)
         s, i = jax.lax.top_k(dense, k_local)
         shard = jax.lax.axis_index("data")
@@ -173,7 +236,7 @@ def _sharded_bm25_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
         in_specs=(P("data"), P("data"), P(), P("data"), P("data"),
                   P("data"), P("data"), P()),
         out_specs=(P(), P()),
-    )(ptr, urow, sel, post_doc, post_tf, doc_len, alive_f, avgdl)
+    )(tstart, tlen, sel, post_doc, post_tf, doc_len, alive_f, avgdl)
 
 
 # ---------------------------------------------------------------------------
@@ -567,14 +630,18 @@ class DeviceBM25:
         token_rows: Sequence[Sequence[str]],
         b_bucket: int,
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.float32]:
-        """Flatten a tokenized query batch into pow2-padded entry
-        columns (ptr, unique-term row) sharded like the snapshot, plus
-        the [B, U] idf-weighted selection matrix and current avgdl.
+        """Plan a tokenized query batch: for each unique scoring term its
+        posting range in every shard of the snapshot (first posting,
+        count; both [shards * U], sharded like the snapshot), plus the
+        [B, U] idf-weighted selection matrix and current avgdl.
 
         Terms are DEDUPED across the whole batch (each unique term's
-        postings flatten once, however many coalesced queries share it)
-        and idf comes from the incremental live-df counters, so deletes
-        correct df without a rebuild."""
+        postings are walked once, however many coalesced queries share
+        it) and idf comes from the incremental live-df counters, so
+        deletes correct df without a rebuild. U is
+        ``LEX_TERMS_PER_QUERY`` x the batch bucket, or the next power of
+        two that holds the terms, and nothing the size of the postings
+        is built here: the device expands the ranges."""
         vocab = snap["vocab"]
         off_sh = snap["off_sh"]
         s_n = snap["shards"]
@@ -595,10 +662,11 @@ class DeviceBM25:
                 terms.append(t)
                 idfs.append(np.float32(
                     math.log(1.0 + (n - df + 0.5) / (df + 0.5))))
-        u_b = pow2_bucket(max(len(terms), 1))
-        # the device segment id is urow * C + doc in int32 (jax default
-        # index width; segment_sum silently drops out-of-range ids) —
-        # refuse to plan a batch whose id space would wrap
+        u_b = pow2_bucket(max(len(terms),
+                              LEX_TERMS_PER_QUERY * max(b_bucket, 1)))
+        # the device's [U+1, C] tf-norm matrix is addressed in int32
+        # (jax's default index width) — refuse to plan a batch whose
+        # cell space would wrap
         if (u_b + 1) * snap["c_local"] > 2**31 - 1:
             raise PlanOverflow
         sel = np.zeros((b_bucket, u_b), dtype=np.float32)
@@ -607,31 +675,15 @@ class DeviceBM25:
                 ui = u_of.get(t)
                 if ui is not None:
                     sel[qi, ui] = idfs[ui]
-        ptr_lists: List[List[np.ndarray]] = [[] for _ in range(s_n)]
-        urow_lists: List[List[int]] = [[] for _ in range(s_n)]
-        cnt_lists: List[List[int]] = [[] for _ in range(s_n)]
-        for ui, t in enumerate(terms):
-            ti = vocab[t]
-            for sh in range(s_n):
-                a, bnd = int(off_sh[sh, ti]), int(off_sh[sh, ti + 1])
-                if bnd > a:
-                    ptr_lists[sh].append(
-                        np.arange(a, bnd, dtype=np.int32))
-                    urow_lists[sh].append(ui)
-                    cnt_lists[sh].append(bnd - a)
-        totals = [sum(c) for c in cnt_lists]
-        p_b = pow2_bucket(max(max(totals), 1) if totals else 1)
-        ptr = np.zeros((s_n, p_b), dtype=np.int32)
-        # pad entries target the overflow row U (discarded on device)
-        urow = np.full((s_n, p_b), u_b, dtype=np.int32)
-        for sh in range(s_n):
-            if not cnt_lists[sh]:
-                continue
-            ptr[sh, : totals[sh]] = np.concatenate(ptr_lists[sh])
-            urow[sh, : totals[sh]] = np.repeat(
-                np.asarray(urow_lists[sh], dtype=np.int32),
-                np.asarray(cnt_lists[sh]))
-        return (ptr.reshape(-1), urow.reshape(-1), sel,
+        tstart = np.zeros((s_n, u_b), dtype=np.int32)
+        tlen = np.zeros((s_n, u_b), dtype=np.int32)   # pad terms: empty
+        if terms:
+            ti = np.fromiter((vocab[t] for t in terms), np.int64,
+                             len(terms))
+            tstart[:, : len(terms)] = off_sh[:, ti]
+            tlen[:, : len(terms)] = off_sh[:, ti + 1] - off_sh[:, ti]
+        self._plan_cost.shape = (int(tlen.sum()), len(terms), u_b)
+        return (tstart.reshape(-1), tlen.reshape(-1), sel,
                 np.float32(avgdl))
 
     # -- dispatch ---------------------------------------------------------
@@ -645,8 +697,8 @@ class DeviceBM25:
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Scores + global row ids [b_bucket, k] for a tokenized batch
         (rows beyond len(token_rows) are planning no-ops)."""
-        ptr, urow, sel, avgdl = self.plan(snap, token_rows, b_bucket)
-        args = (jnp.asarray(ptr), jnp.asarray(urow), jnp.asarray(sel),
+        tstart, tlen, sel, avgdl = self.plan(snap, token_rows, b_bucket)
+        args = (jnp.asarray(tstart), jnp.asarray(tlen), jnp.asarray(sel),
                 snap["post_doc"], snap["post_tf"], snap["doc_len"],
                 snap["alive"], jnp.float32(avgdl))
         s_n = snap["shards"]
@@ -666,17 +718,17 @@ class DeviceBM25:
         each shard's local rows, concatenate shard-local winners in
         shard order (exactly the all-gather layout) and take one global
         top-k. The mesh path must be bit-identical to this."""
-        ptr, urow, sel, pd, pt, dl, al, avgdl = args
+        tstart, tlen, sel, pd, pt, dl, al, avgdl = args
         s_n = snap["shards"]
         c_local = snap["c_local"]
-        p_b = ptr.shape[0] // s_n
+        p_b = tstart.shape[0] // s_n
         p_cap = pd.shape[0] // s_n
         k_local = min(k, c_local)
         parts_s, parts_i = [], []
         for sh in range(s_n):
             s, i = _bm25_local_topk(
-                ptr[sh * p_b:(sh + 1) * p_b],
-                urow[sh * p_b:(sh + 1) * p_b],
+                tstart[sh * p_b:(sh + 1) * p_b],
+                tlen[sh * p_b:(sh + 1) * p_b],
                 sel,
                 pd[sh * p_cap:(sh + 1) * p_cap],
                 pt[sh * p_cap:(sh + 1) * p_cap],

@@ -9,7 +9,10 @@ entries and emits the RRF-fused top-k, with the per-source candidate
 lists along for the ride (the service's min_score gates and result
 payloads need the raw scores).
 
-Pipeline (single compile per pow2 ``(B, k)`` bucket):
+Pipeline (single compile per pow2 ``(B, k)`` bucket: the lexical
+half's unique-term rows follow B, ``device_bm25.LEX_TERMS_PER_QUERY``,
+and the number of postings a batch walks is no shape of the program;
+``SearchService.warm_hybrid`` compiles every bucket before traffic):
 
 1. **lexical** — ``device_bm25.bm25_dense_scores`` over the CSR
    snapshot -> top-k rows;
@@ -66,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from nornicdb_tpu.obs import REGISTRY, declare_kind, record_dispatch
+from nornicdb_tpu.obs.tracing import span as _span
 from nornicdb_tpu.obs import audit as _audit
 from nornicdb_tpu.obs import cost as _cost
 from nornicdb_tpu.ops.similarity import EXACT, NEG_INF, l2_normalize
@@ -165,12 +169,12 @@ def rrf_fuse_device(
 
 
 @functools.partial(jax.jit, static_argnames=("kq", "rrf_k"))
-def _fused_single(ptr, urow, sel, post_doc, post_tf, doc_len, alive_f,
+def _fused_single(tstart, tlen, sel, post_doc, post_tf, doc_len, alive_f,
                   l2v, avgdl, qn, vmatrix, vvalid, n_cand, w_lex, w_vec,
                   kq, rrf_k):
     c_vec = vmatrix.shape[0]
     ls, lid, lgrow, vs, vi = _local_parts_impl(
-        ptr, urow, sel, post_doc, post_tf, doc_len, alive_f, l2v,
+        tstart, tlen, sel, post_doc, post_tf, doc_len, alive_f, l2v,
         avgdl, qn, vmatrix, vvalid, jnp.int32(0), jnp.int32(0), kq=kq)
     ls = _pad_cols(ls, kq, NEG_INF)
     lid = _pad_cols(lid, kq, 0)
@@ -182,13 +186,13 @@ def _fused_single(ptr, urow, sel, post_doc, post_tf, doc_len, alive_f,
     return ls, lgrow, vs, vi, fs, fpos
 
 
-def _lex_parts_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
+def _lex_parts_impl(tstart, tlen, sel, post_doc, post_tf, doc_len,
                     alive_f, l2map, avgdl, lex_off, kq):
     """One shard's lexical top-k with globalized row ids plus the
     joined foreign-row column (brute slot for the matmul tier, graph
     row for the walk tier) — the lexical half of every shard path."""
     c_lex = doc_len.shape[0]
-    dense = bm25_dense_scores(ptr, urow, sel, post_doc, post_tf,
+    dense = bm25_dense_scores(tstart, tlen, sel, post_doc, post_tf,
                               doc_len, alive_f, avgdl)
     ls, li = jax.lax.top_k(dense, min(kq, c_lex))
     return ls, l2map[li], li + lex_off
@@ -198,13 +202,13 @@ _lex_parts = functools.partial(
     jax.jit, static_argnames=("kq",))(_lex_parts_impl)
 
 
-def _local_parts_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
+def _local_parts_impl(tstart, tlen, sel, post_doc, post_tf, doc_len,
                       alive_f, l2v, avgdl, qn, vmatrix, vvalid, lex_off,
                       vec_off, kq):
     """One shard's per-source top-k with globalized ids — the building
     block of both the single-device reference loop and the mesh path."""
     c_vec = vmatrix.shape[0]
-    ls, lid, lgrow = _lex_parts_impl(ptr, urow, sel, post_doc, post_tf,
+    ls, lid, lgrow = _lex_parts_impl(tstart, tlen, sel, post_doc, post_tf,
                                      doc_len, alive_f, l2v, avgdl,
                                      lex_off, kq)
     vsc = jnp.matmul(qn, vmatrix.T, precision=EXACT)
@@ -242,7 +246,7 @@ def _fuse_merged(ls, lid, lgrow, vs, vi, n_cand, w_lex, w_vec, kq,
 
 @functools.partial(
     jax.jit, static_argnames=("kq", "rrf_k", "mesh_holder"))
-def _fused_sharded_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
+def _fused_sharded_impl(tstart, tlen, sel, post_doc, post_tf, doc_len,
                         alive_f, l2v, avgdl, qn, vmatrix, vvalid,
                         n_cand, w_lex, w_vec, kq, rrf_k, mesh_holder):
     from jax.sharding import PartitionSpec as P
@@ -255,11 +259,11 @@ def _fused_sharded_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
     c_vec_local = vmatrix.shape[0] // s_n
     c_vec_total = vmatrix.shape[0]
 
-    def local_fn(ptr_s, urow_s, sel_r, pd_s, pt_s, dl_s, al_s, l2v_s,
+    def local_fn(tstart_s, tlen_s, sel_r, pd_s, pt_s, dl_s, al_s, l2v_s,
                  avg_r, qn_r, vm_s, vv_s, nc_r, wl_r, wv_r):
         sh = jax.lax.axis_index("data")
         ls, lid, lgrow, vs, gvi = _local_parts_impl(
-            ptr_s, urow_s, sel_r, pd_s, pt_s, dl_s, al_s, l2v_s, avg_r,
+            tstart_s, tlen_s, sel_r, pd_s, pt_s, dl_s, al_s, l2v_s, avg_r,
             qn_r, vm_s, vv_s, sh * c_lex_local, sh * c_vec_local,
             kq=kq)
 
@@ -280,7 +284,7 @@ def _fused_sharded_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
                   P("data"), P("data"), P("data"), P(), P(),
                   P("data", None), P("data"), P(), P(), P()),
         out_specs=(P(), P(), P(), P(), P(), P()),
-    )(ptr, urow, sel, post_doc, post_tf, doc_len, alive_f, l2v,
+    )(tstart, tlen, sel, post_doc, post_tf, doc_len, alive_f, l2v,
       avgdl, qn, vmatrix, vvalid, n_cand, w_lex, w_vec)
 
 
@@ -294,7 +298,7 @@ def _fused_sharded_impl(ptr, urow, sel, post_doc, post_tf, doc_len,
 
 
 @functools.partial(jax.jit, static_argnames=("kq", "pool", "mode"))
-def _fused_single_quant(ptr, urow, sel, post_doc, post_tf, doc_len,
+def _fused_single_quant(tstart, tlen, sel, post_doc, post_tf, doc_len,
                         alive_f, l2v, avgdl, qn, codes_t, aux,
                         vvalid, kq, pool, mode):
     """Lexical CSR scoring + quantized coarse vector top-``pool`` in
@@ -311,7 +315,7 @@ def _fused_single_quant(ptr, urow, sel, post_doc, post_tf, doc_len,
     )
 
     c_vec = codes_t.shape[1]
-    ls, _lid, lgrow = _lex_parts_impl(ptr, urow, sel, post_doc,
+    ls, _lid, lgrow = _lex_parts_impl(tstart, tlen, sel, post_doc,
                                       post_tf, doc_len, alive_f, l2v,
                                       avgdl, jnp.int32(0), kq=kq)
     if mode == "int8":
@@ -329,7 +333,7 @@ def _fused_single_quant(ptr, urow, sel, post_doc, post_tf, doc_len,
 
 @functools.partial(jax.jit, static_argnames=(
     "kq", "iters", "width", "itopk", "hash_bits", "n_seeds", "keep"))
-def _walk_fused_single_q(ptr, urow, sel, post_doc, post_tf, doc_len,
+def _walk_fused_single_q(tstart, tlen, sel, post_doc, post_tf, doc_len,
                          alive_f, l2g, avgdl, qp, codes, codes_head,
                          scale, gadj, gvalidf, kq, iters, width, itopk,
                          hash_bits, n_seeds, keep):
@@ -341,7 +345,7 @@ def _walk_fused_single_q(ptr, urow, sel, post_doc, post_tf, doc_len,
     re-fuse replaces the device fuse (see _fused_single_quant)."""
     from nornicdb_tpu.search.device_quant import _walk_body_quant
 
-    ls, _lid, lgrow = _lex_parts_impl(ptr, urow, sel, post_doc,
+    ls, _lid, lgrow = _lex_parts_impl(tstart, tlen, sel, post_doc,
                                       post_tf, doc_len, alive_f, l2g,
                                       avgdl, jnp.int32(0), kq=kq)
     vs, vi = _walk_body_quant(qp, codes, codes_head, scale, gadj,
@@ -354,7 +358,7 @@ def _walk_fused_single_q(ptr, urow, sel, post_doc, post_tf, doc_len,
 
 @functools.partial(jax.jit, static_argnames=(
     "kq", "iters", "width", "itopk", "hash_bits", "n_seeds"))
-def _walk_fused_single_pq(ptr, urow, sel, post_doc, post_tf, doc_len,
+def _walk_fused_single_pq(tstart, tlen, sel, post_doc, post_tf, doc_len,
                           alive_f, l2g, avgdl, qn, codes, codebooks,
                           gadj, gvalidf, kq, iters, width, itopk,
                           hash_bits, n_seeds):
@@ -365,7 +369,7 @@ def _walk_fused_single_pq(ptr, urow, sel, post_doc, post_tf, doc_len,
     the device fuse, exactly as in :func:`_walk_fused_single_q`."""
     from nornicdb_tpu.search.device_quant import _walk_body_pq
 
-    ls, _lid, lgrow = _lex_parts_impl(ptr, urow, sel, post_doc,
+    ls, _lid, lgrow = _lex_parts_impl(tstart, tlen, sel, post_doc,
                                       post_tf, doc_len, alive_f, l2g,
                                       avgdl, jnp.int32(0), kq=kq)
     vs, vi = _walk_body_pq(qn, codes, codebooks, gadj, gvalidf,
@@ -383,7 +387,7 @@ def _walk_fused_single_pq(ptr, urow, sel, post_doc, post_tf, doc_len,
 
 @functools.partial(jax.jit, static_argnames=(
     "kq", "rrf_k", "iters", "width", "itopk", "hash_bits", "n_seeds"))
-def _walk_fused_single(ptr, urow, sel, post_doc, post_tf, doc_len,
+def _walk_fused_single(tstart, tlen, sel, post_doc, post_tf, doc_len,
                        alive_f, l2g, avgdl, qn, gmatrix, gadj, gvalidf,
                        n_cand, w_lex, w_vec, kq, rrf_k, iters, width,
                        itopk, hash_bits, n_seeds):
@@ -394,7 +398,7 @@ def _walk_fused_single(ptr, urow, sel, post_doc, post_tf, doc_len,
     the walk's own statics (iters/width/itopk) are per-graph-build
     constants, not per-request knobs."""
     c_g = gmatrix.shape[0]
-    ls, lid, lgrow = _lex_parts_impl(ptr, urow, sel, post_doc, post_tf,
+    ls, lid, lgrow = _lex_parts_impl(tstart, tlen, sel, post_doc, post_tf,
                                      doc_len, alive_f, l2g, avgdl,
                                      jnp.int32(0), kq=kq)
     vs, vi = _walk_body(qn, gmatrix, gadj, gvalidf, min(kq, itopk),
@@ -412,7 +416,7 @@ def _walk_fused_single(ptr, urow, sel, post_doc, post_tf, doc_len,
 @functools.partial(jax.jit, static_argnames=(
     "kq", "rrf_k", "iters", "width", "itopk", "hash_bits", "n_seeds",
     "mesh_holder"))
-def _walk_fused_sharded_impl(ptr, urow, sel, post_doc, post_tf,
+def _walk_fused_sharded_impl(tstart, tlen, sel, post_doc, post_tf,
                              doc_len, alive_f, l2g, avgdl, qn, gmatrix,
                              gadj, gvalidf, n_cand, w_lex, w_vec, kq,
                              rrf_k, iters, width, itopk, hash_bits,
@@ -433,11 +437,11 @@ def _walk_fused_sharded_impl(ptr, urow, sel, post_doc, post_tf,
     c_g_total = gmatrix.shape[0]
     kw = min(kq, itopk)
 
-    def local_fn(ptr_s, urow_s, sel_r, pd_s, pt_s, dl_s, al_s, l2g_s,
+    def local_fn(tstart_s, tlen_s, sel_r, pd_s, pt_s, dl_s, al_s, l2g_s,
                  avg_r, qn_r, gm_s, ga_s, gv_s, nc_r, wl_r, wv_r):
         sh = jax.lax.axis_index("data")
         ls, lid, lgrow = _lex_parts_impl(
-            ptr_s, urow_s, sel_r, pd_s, pt_s, dl_s, al_s, l2g_s,
+            tstart_s, tlen_s, sel_r, pd_s, pt_s, dl_s, al_s, l2g_s,
             avg_r, sh * c_lex_local, kq=kq)
         ws, wi = _walk_body(qn_r, gm_s, ga_s, gv_s, kw, iters, width,
                             itopk, hash_bits, n_seeds)
@@ -461,7 +465,7 @@ def _walk_fused_sharded_impl(ptr, urow, sel, post_doc, post_tf,
                   P("data", None), P("data", None), P("data"), P(),
                   P(), P()),
         out_specs=(P(), P(), P(), P(), P(), P()),
-    )(ptr, urow, sel, post_doc, post_tf, doc_len, alive_f, l2g,
+    )(tstart, tlen, sel, post_doc, post_tf, doc_len, alive_f, l2g,
       avgdl, qn, gmatrix, gadj, gvalidf, n_cand, w_lex, w_vec)
 
 
@@ -665,10 +669,14 @@ class FusedHybrid:
         if self.brute.view_meta() is None:
             return none_rows  # vector index empty
         t_plan0 = time.time()
+        token_rows = [e["tokens"] for e in extras]
         try:
-            self.lex.refresh_alive(snap)
-            token_rows = [e["tokens"] for e in extras]
-            ptr, urow, sel, avgdl = self.lex.plan(snap, token_rows, b)
+            with _span("hybrid.plan", b=b) as sp:
+                self.lex.refresh_alive(snap)
+                tstart, tlen, sel, avgdl = self.lex.plan(
+                    snap, token_rows, b)
+                entries, n_terms, u_b = self.lex._plan_cost.shape
+                sp.annotate(entries=entries, terms=n_terms, u=u_b)
         except SnapshotStale:
             _HYB_C.labels("host_fallback_compaction").inc()
             self._ledger(TIER_BRUTE_F32, "host", "compaction", snap)
@@ -683,7 +691,7 @@ class FusedHybrid:
         w_lex = np.asarray([e["w"][0] for e in extras], dtype=np.float32)
         w_vec = np.asarray([e["w"][1] for e in extras], dtype=np.float32)
         qn = l2_normalize(jnp.asarray(queries_emb, dtype=jnp.float32))
-        lex_base = (jnp.asarray(ptr), jnp.asarray(urow),
+        lex_base = (jnp.asarray(tstart), jnp.asarray(tlen),
                     jnp.asarray(sel), snap["post_doc"],
                     snap["post_tf"], snap["doc_len"], snap["alive"])
         tail = (jnp.asarray(n_cand), jnp.asarray(w_lex),
@@ -737,27 +745,31 @@ class FusedHybrid:
             return none_rows
         args = (*lex_base, l2v, jnp.float32(avgdl), qn)
         t0 = time.time()
-        if snap["shards"] == 1:
-            ls, li, vs, vi, fs, fpos = _fused_single(
-                *args, jnp.asarray(m), jnp.asarray(valid), *tail,
-                kq=kq, rrf_k=self.rrf_k)
-            lgrow = li
-        elif "mesh" in snap and len(jax.devices()) >= snap["shards"]:
-            mp, vp = self._vec_arrays(m, valid, snap)
-            if mp is None:
-                _HYB_C.labels("host_fallback_unshardable").inc()
-                self._ledger(TIER_BRUTE_F32, "host", "unshardable", snap)
-                return none_rows
-            ls, lgrow, vs, vi, fs, fpos = _fused_sharded_impl(
-                *args, mp, vp, *tail, kq=kq, rrf_k=self.rrf_k,
-                mesh_holder=_holder(snap["mesh"]))
-        else:
-            ls, lgrow, vs, vi, fs, fpos = self._shard_loop(
-                snap, args, m, valid, tail, kq)
-        # force to host inside the timed window (async dispatch)
-        ls, lgrow = np.asarray(ls), np.asarray(lgrow)
-        vs, vi = np.asarray(vs), np.asarray(vi)
-        fs, fpos = np.asarray(fs), np.asarray(fpos)
+        # from the call into the fused program to its arrays on the host
+        with _span("hybrid.dispatch", b=b, k=kq, entries=entries,
+                   terms=n_terms, u=u_b, tier=TIER_BRUTE_F32):
+            if snap["shards"] == 1:
+                ls, li, vs, vi, fs, fpos = _fused_single(
+                    *args, jnp.asarray(m), jnp.asarray(valid), *tail,
+                    kq=kq, rrf_k=self.rrf_k)
+                lgrow = li
+            elif "mesh" in snap and len(jax.devices()) >= snap["shards"]:
+                mp, vp = self._vec_arrays(m, valid, snap)
+                if mp is None:
+                    _HYB_C.labels("host_fallback_unshardable").inc()
+                    self._ledger(TIER_BRUTE_F32, "host", "unshardable",
+                                 snap)
+                    return none_rows
+                ls, lgrow, vs, vi, fs, fpos = _fused_sharded_impl(
+                    *args, mp, vp, *tail, kq=kq, rrf_k=self.rrf_k,
+                    mesh_holder=_holder(snap["mesh"]))
+            else:
+                ls, lgrow, vs, vi, fs, fpos = self._shard_loop(
+                    snap, args, m, valid, tail, kq)
+            # force to host inside the timed window (async dispatch)
+            ls, lgrow = np.asarray(ls), np.asarray(lgrow)
+            vs, vi = np.asarray(vs), np.asarray(vi)
+            fs, fpos = np.asarray(fs), np.asarray(fpos)
         t1 = time.time()
         record_dispatch("hybrid_fused", pow2_bucket(b), kq, t1 - t0)
         _HYB_C.labels("dispatch").inc()
@@ -765,9 +777,10 @@ class FusedHybrid:
                           vec_flops_bytes=_cost.price_brute(
                               pow2_bucket(b), int(m.shape[0]),
                               int(m.shape[1])))
-        out = self._decode(snap, vec_ext, delta, token_rows, extras,
-                           ls, lgrow, vs, vi, fs, fpos, kq,
-                           tier=TIER_BRUTE_F32)
+        with _span("hybrid.decode", b=b):
+            out = self._decode(snap, vec_ext, delta, token_rows, extras,
+                               ls, lgrow, vs, vi, fs, fpos, kq,
+                               tier=TIER_BRUTE_F32)
         if delta:
             _HYB_C.labels("delta_merge").inc(len(extras))
         times = {"plan_s": t0 - t_plan0, "device_t0": t0,
@@ -1181,11 +1194,11 @@ class FusedHybrid:
         shard's lexical parts + local-subgraph walk, merged in shard
         order (the all-gather layout), fused once. The mesh path must
         match this bit-for-bit."""
-        ptr, urow, sel, pd, pt, dl, al = lex_base
+        tstart, tlen, sel, pd, pt, dl, al = lex_base
         n_cand, w_lex, w_vec = tail
         s_n = snap["shards"]
         c_local = snap["c_local"]
-        p_b = ptr.shape[0] // s_n
+        p_b = tstart.shape[0] // s_n
         p_cap = pd.shape[0] // s_n
         r = g["rows_per_shard"]
         kw = min(kq, wctx["itopk"])
@@ -1193,8 +1206,8 @@ class FusedHybrid:
         lex_parts, vec_parts = [], []
         for sh in range(s_n):
             ls, lid, lgrow = _lex_parts(
-                ptr[sh * p_b:(sh + 1) * p_b],
-                urow[sh * p_b:(sh + 1) * p_b],
+                tstart[sh * p_b:(sh + 1) * p_b],
+                tlen[sh * p_b:(sh + 1) * p_b],
                 sel,
                 pd[sh * p_cap:(sh + 1) * p_cap],
                 pt[sh * p_cap:(sh + 1) * p_cap],
@@ -1220,19 +1233,19 @@ class FusedHybrid:
         """Single-device reference for the sharded layout: run every
         shard's local parts, merge in shard order (the all-gather
         layout), fuse once. The mesh path must match this bit-for-bit."""
-        ptr, urow, sel, pd, pt, dl, al, l2v, avgdl, qn = args
+        tstart, tlen, sel, pd, pt, dl, al, l2v, avgdl, qn = args
         n_cand, w_lex, w_vec = tail
         s_n = snap["shards"]
         c_local = snap["c_local"]
-        p_b = ptr.shape[0] // s_n
+        p_b = tstart.shape[0] // s_n
         p_cap = pd.shape[0] // s_n
         mj, vj = jnp.asarray(m), jnp.asarray(valid)
         c_vec_local = mj.shape[0] // s_n
         lex_parts, vec_parts = [], []
         for sh in range(s_n):
             ls, lid, lgrow, vvs, gvi = _local_parts(
-                ptr[sh * p_b:(sh + 1) * p_b],
-                urow[sh * p_b:(sh + 1) * p_b],
+                tstart[sh * p_b:(sh + 1) * p_b],
+                tlen[sh * p_b:(sh + 1) * p_b],
                 sel,
                 pd[sh * p_cap:(sh + 1) * p_cap],
                 pt[sh * p_cap:(sh + 1) * p_cap],

@@ -350,6 +350,11 @@ class MicroBatcher:
         self.batches = 0
         self.batched_queries = 0
 
+    @property
+    def max_batch(self) -> int:
+        """The most riders one sealed batch takes."""
+        return self._max_batch
+
     def queue_depth(self) -> int:
         """Live pending requests (not yet claimed by a batch leader) —
         the saturation signal /readyz and the resource gauges read

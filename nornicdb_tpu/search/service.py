@@ -4,12 +4,21 @@ Reference: pkg/search/search.go ``Service`` (:417-524), ``Search`` (:2841),
 ``BuildIndexes`` (:2246), ``IndexNode`` (:1785), strategy state machine
 bruteCPU <-> bruteGPU <-> HNSW (:528-535). TPU design: the "GPU" strategy
 is simply the device-backed BruteForceIndex (ops dispatch to whatever
-backend JAX has); HNSW kicks in above ``hnsw_threshold`` with a
-BM25-seeded build.
+backend JAX has). What happens above ``hnsw_threshold`` vectors depends on
+the backend (``_maybe_switch_strategy``): on the CPU backend a host HNSW
+is built, BM25-seeded, and fed on the write path from then on; on an
+accelerator the exact device tier stays for as long as the float32 matrix
+takes no more than half the chip's memory, and no host graph is built
+(a scan of a million rows is milliseconds there, and a Python HNSW on the
+write path never finishes loading them). The ``cagra`` profile builds
+its device graph above the threshold on either.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
+import gc
 import os
 import threading
 import time
@@ -20,6 +29,7 @@ import numpy as np
 
 from nornicdb_tpu.embed.http_providers import EmbedHTTPError
 from nornicdb_tpu.obs import REGISTRY, attach_span
+from nornicdb_tpu.obs.tracing import span as _span
 from nornicdb_tpu.obs import audit as _audit
 from nornicdb_tpu.obs import tenant as _tenant
 from nornicdb_tpu.search.bm25 import BM25Index, tokenize
@@ -39,6 +49,56 @@ _STRATEGY_C = REGISTRY.counter(
 # tier-mix truth for result-cache hits (ISSUE 10): cached child — the
 # hit path must not pay a labels() probe per request
 _HYBRID_CACHED_SERVED = _audit.served_counter("hybrid", "cached")
+
+
+# the share of one chip's memory the exact tier's float32 matrix may take
+# before the strategy machine looks for an approximate index: the rest is
+# for the encoder's parameters, the lexical snapshot and the programs'
+# temporaries (a fused batch of 32 keeps ~2.5 GB beside a 4.3 GB matrix)
+DEVICE_EXACT_SHARE = 0.5
+
+
+@functools.lru_cache(maxsize=1)
+def _accelerator_memory_limit() -> Optional[int]:
+    """Bytes of device memory one chip offers, as the backend reports
+    them; None on the CPU backend, which has no device tier to keep.
+    Asked once: ``index_node`` runs the strategy machine once a node."""
+    import jax
+
+    if jax.default_backend() == "cpu":
+        return None
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", 0)) or None
+
+
+@contextlib.contextmanager
+def gc_paused(freeze: bool = False):
+    """The cyclic collector off for the length of a bulk load: millions
+    of new containers trigger full collections that find nothing (a
+    20,000-document ``BM25Index.index_batch`` takes 0.64 s without them
+    and 1.14 s with, CPU).
+
+    ``freeze`` is for the caller that OWNS a load of lasting objects
+    (``DB.store_batch``): garbage is collected first, and on the way out
+    ``gc.freeze()`` moves everything then alive into the collector's
+    permanent generation, so that later full collections do not walk a
+    million nodes and their postings again (0.05-0.07 s a collection
+    with them frozen, v5e host, PR 28). That is a lasting effect on the
+    whole process, not on the load alone: reference counting frees a
+    frozen object as before, but a reference CYCLE alive at that moment,
+    anywhere in the process, is never collected once it is dropped
+    (``gc.unfreeze()`` undoes it)."""
+    was_on = gc.isenabled()
+    if was_on and freeze:
+        gc.collect()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was_on:
+            if freeze:
+                gc.freeze()
+            gc.enable()
 
 
 def _copy_tree(v):
@@ -113,7 +173,12 @@ class SearchStats:
 
 class SearchService:
     """One search service per logical database
-    (reference: per-DB instances, pkg/nornicdb/search_services.go:68)."""
+    (reference: per-DB instances, pkg/nornicdb/search_services.go:68).
+
+    ``hnsw_threshold`` is where the vector strategy leaves the plain
+    brute tier: for a host HNSW on the CPU backend, for nothing while
+    the matrix fits the chip on an accelerator (the module's header
+    says why), for the device graph under the ``cagra`` profile."""
 
     def __init__(
         self,
@@ -329,6 +394,45 @@ class SearchService:
                     f.cagra)
         return f
 
+    def warm_hybrid(self, limit: int = 10,
+                    max_batch: Optional[int] = None) -> List[int]:
+        """Compile, before traffic needs them, every program a hybrid
+        search of ``limit`` hits can dispatch: the embedder's
+        single-query shape, and one fused batch for each power-of-two
+        bucket up to ``max_batch`` riders (default: the most the hybrid
+        batcher seals). The lexical snapshot is built first, inline, and
+        the first batch ships the vector matrix to the device. Returns
+        the buckets warmed: none while the fused tier is not eligible
+        (``_ensure_fused``). A server calls this once after a bulk load;
+        nothing else changes (no result is cached, no counter of served
+        searches moves)."""
+        from nornicdb_tpu.config import env_bool, env_int
+        from nornicdb_tpu.search.microbatch import pow2_bucket
+
+        with self._lock:
+            fused = self._ensure_fused_locked(env_bool, env_int)
+        if fused is None or not fused.build():
+            return []
+        dims = int(self.vectors.dims or 0)
+        qv = None
+        if self.embedder is not None:
+            qv = self._query_embedding("warm up")
+        if qv is None:
+            qv = np.ones((dims,), np.float32)
+        overfetch = max(limit * 3, 30)
+        extra = {"tokens": tuple(tokenize("warm up")),
+                 "n_cand": overfetch, "w": (1.0, 1.0)}
+        top = self._hybrid_batch.max_batch if max_batch is None \
+            else max_batch
+        warmed: List[int] = []
+        b = 1
+        while b <= pow2_bucket(max(top, 1)):
+            fused.search_batch(np.tile(qv, (b, 1)), pow2_bucket(overfetch),
+                               [extra] * b)
+            warmed.append(b)
+            b *= 2
+        return warmed
+
     def _fused_hybrid_trio(self, query, qv, overfetch, weights):
         """One coalesced fused-hybrid ride: (lex, vec, fused) candidate
         lists for this query, or None when the host path must serve.
@@ -468,6 +572,42 @@ class SearchService:
                 self.vectors.remove(node.id)
                 if self.hnsw is not None:
                     self.hnsw.remove(node.id)
+            self.stats.indexed_docs = len(self.bm25)
+            self.stats.indexed_vectors = len(self.vectors)
+            self._maybe_switch_strategy()
+        self._clear_result_cache()
+        self._schedule_save()
+
+    def index_batch(self, ids: Sequence[str], texts: Sequence[str],
+                    vectors: np.ndarray) -> None:
+        """Index many nodes in one call: ``ids[i]`` with the searchable
+        text ``texts[i]`` (what ``extract_text`` gives for the node) and
+        the embedding ``vectors[i]`` (a float32 ``[n, dims]`` matrix).
+        The text and vector indexes end in exactly the state
+        ``index_node`` called once a node, in order, would leave them
+        in, and fresh ids (a bulk load) get there without a Python call
+        a row (``BM25Index.index_batch``, ``BruteForceIndex.add_matrix``).
+        The strategy machine, the result cache's generation and the
+        debounced save run once for the call, not once a node. The
+        caller keeps system-owned nodes (a label starting with ``_``)
+        out, as ``index_node`` does."""
+        ids, texts = list(ids), list(texts)
+        vectors = np.asarray(vectors, dtype=np.float32)
+        if not (len(ids) == len(texts) == len(vectors)) \
+                or vectors.ndim != 2:
+            raise ValueError(
+                f"index_batch: {len(ids)} ids, {len(texts)} texts, "
+                f"vectors {vectors.shape}")
+        with gc_paused(), self._lock:
+            for node_id, text in zip(ids, texts):
+                if not text:
+                    self.bm25.remove(node_id)
+            self.bm25.index_batch(
+                [(i, t) for i, t in zip(ids, texts) if t])
+            self.vectors.add_matrix(ids, vectors)
+            if self.hnsw is not None:
+                for node_id, vec in zip(ids, vectors):
+                    self.hnsw.add(node_id, vec)
             self.stats.indexed_docs = len(self.bm25)
             self.stats.indexed_vectors = len(self.vectors)
             self._maybe_switch_strategy()
@@ -690,6 +830,19 @@ class SearchService:
 
     # -- strategy state machine -------------------------------------------
 
+    def _device_keeps_exact_tier(self) -> bool:
+        """On an accelerator, while the index's float32 matrix (at the
+        capacity it is padded to) takes no more than
+        ``DEVICE_EXACT_SHARE`` of one chip's memory. Never on the CPU
+        backend."""
+        limit = _accelerator_memory_limit()
+        if limit is None:
+            return False
+        from nornicdb_tpu.ops.similarity import pad_dim
+
+        need = pad_dim(len(self.vectors)) * int(self.vectors.dims or 0) * 4
+        return need <= DEVICE_EXACT_SHARE * limit
+
     def _maybe_switch_strategy(self) -> None:
         if len(self.vectors) < self.hnsw_threshold:
             return
@@ -701,7 +854,7 @@ class SearchService:
             if self.cagra is None:
                 self._rebuild_cagra_locked()
             return
-        if self.hnsw is None:
+        if self.hnsw is None and not self._device_keeps_exact_tier():
             self._rebuild_hnsw_locked()
 
     def _rebuild_cagra_locked(self) -> None:
@@ -972,11 +1125,11 @@ class SearchService:
         vec_hits: List[Tuple[str, float]] = []
         qv = None
         if mode in ("hybrid", "vector"):
-            qv = (
-                np.asarray(query_embedding, dtype=np.float32)
-                if query_embedding is not None
-                else (self._query_embedding(query) if query.strip() else None)
-            )
+            if query_embedding is not None:
+                qv = np.asarray(query_embedding, dtype=np.float32)
+            elif query.strip():
+                with _span("hybrid.embed_query"):
+                    qv = self._query_embedding(query)
             if diag:
                 timings["embed_ms"] = (time.perf_counter() - t0) * 1e3
                 t0 = time.perf_counter()
@@ -1047,34 +1200,35 @@ class SearchService:
         bm = dict(bm25_hits)
         vs = dict(vec_hits)
         out: List[Dict[str, Any]] = []
-        for node_id, score in fused:
-            # min_score filters on the raw similarity scores (cosine and/or
-            # BM25), NOT the fused RRF value — fused magnitudes depend on
-            # which lists fired and are not comparable across modes. A hit
-            # survives if ANY of its raw scores clears the threshold (a
-            # strong text match must not be vetoed by a negative cosine).
-            v_sc, b_sc = vs.get(node_id), bm.get(node_id)
-            gates = [g for g in (v_sc, b_sc) if g is not None]
-            if gates and max(gates) < min_score:
-                continue
-            res = SearchResult(
-                node_id=node_id,
-                score=score,
-                bm25_score=b_sc,
-                vector_score=v_sc,
-            )
-            if (enrich or labels) and self.storage is not None:
-                try:
-                    node = self.storage.get_node(node_id)
-                except KeyError:
-                    continue  # deleted since indexing; drop stale hit
-                if labels and not set(labels) & set(node.labels):
+        with _span("hybrid.hydrate"):
+            for node_id, score in fused:
+                # min_score filters on the raw similarity scores (cosine and/or
+                # BM25), NOT the fused RRF value — fused magnitudes depend on
+                # which lists fired and are not comparable across modes. A hit
+                # survives if ANY of its raw scores clears the threshold (a
+                # strong text match must not be vetoed by a negative cosine).
+                v_sc, b_sc = vs.get(node_id), bm.get(node_id)
+                gates = [g for g in (v_sc, b_sc) if g is not None]
+                if gates and max(gates) < min_score:
                     continue
-                if enrich:
-                    res.node = node
-            out.append(res.to_dict())
-            if len(out) >= limit and self.reranker is None:
-                break
+                res = SearchResult(
+                    node_id=node_id,
+                    score=score,
+                    bm25_score=b_sc,
+                    vector_score=v_sc,
+                )
+                if (enrich or labels) and self.storage is not None:
+                    try:
+                        node = self.storage.get_node(node_id)
+                    except KeyError:
+                        continue  # deleted since indexing; drop stale hit
+                    if labels and not set(labels) & set(node.labels):
+                        continue
+                    if enrich:
+                        res.node = node
+                out.append(res.to_dict())
+                if len(out) >= limit and self.reranker is None:
+                    break
         if self.reranker is not None and out:
             # stage-2 rerank over the full fused overfetch, then cut
             # (reference: rerank.go after RRF). Pass the query embedding

@@ -84,6 +84,25 @@ class MemoryEngine(Engine):
             for label in n.labels:
                 self._by_label.setdefault(label, set()).add(n.id)
 
+    def create_nodes(self, nodes: Sequence[Node]) -> None:
+        """One lock hold for the batch; all or nothing on a taken id."""
+        with self._lock:
+            ids = [n.id for n in nodes]
+            if len(set(ids)) != len(ids) \
+                    or not self._nodes.keys().isdisjoint(ids):
+                raise AlreadyExistsError(
+                    "a node of the batch already exists")
+            now = now_ms()
+            for node in nodes:
+                n = node.copy()
+                if not n.created_at:
+                    n.created_at = now
+                if not n.updated_at:
+                    n.updated_at = n.created_at
+                self._nodes[n.id] = n
+                for label in n.labels:
+                    self._by_label.setdefault(label, set()).add(n.id)
+
     def get_node(self, node_id: NodeID) -> Node:
         t_ask = time.perf_counter()
         with self._lock:

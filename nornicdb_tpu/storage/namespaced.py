@@ -72,6 +72,9 @@ class NamespacedEngine(EngineDecorator):
     def create_node(self, node: Node) -> None:
         self.inner.create_node(self._node_in(node))
 
+    def create_nodes(self, nodes: Sequence[Node]) -> None:
+        self.inner.create_nodes([self._node_in(n) for n in nodes])
+
     def get_node(self, node_id: NodeID) -> Node:
         try:
             return self._node_out(self.inner.get_node(self._q(node_id)))

@@ -145,6 +145,22 @@ class Engine(ABC):
     @abstractmethod
     def create_node(self, node: Node) -> None: ...
 
+    def create_nodes(self, nodes: Sequence[Node]) -> None:
+        """``create_node`` for each, in order. An engine that can take a
+        batch under one lock hold overrides this; a decorator that does
+        not inherits the loop over its OWN ``create_node``, so what it
+        adds to a write (a log, a buffer, a namespace) is never
+        skipped.
+
+        THE CALLER INDEXES WHAT IT LOADS. Behind ``ListenableEngine``
+        (every ``DB``) a batch sends listeners one ``on_bulk_change``
+        and no ``on_node_upsert``: the embed queue embeds none of these
+        nodes and the search service indexes none. ``DB.store_batch``
+        is the caller that does both itself; anything else that wants
+        its nodes embedded and searchable calls ``create_node``."""
+        for node in nodes:
+            self.create_node(node)
+
     @abstractmethod
     def get_node(self, node_id: NodeID) -> Node: ...
 
@@ -413,6 +429,15 @@ class ListenableEngine(EngineDecorator):
         self.inner.create_node(node)
         for l in self._each():
             l.on_node_upsert(node)
+
+    def create_nodes(self, nodes: Sequence[Node]) -> None:
+        """A bulk load carries no per-node events (its caller indexes
+        what it loads, ``Engine.create_nodes`` says so, and nothing is
+        to be re-embedded or re-indexed): listeners get the one coarse
+        ``on_bulk_change``."""
+        self.inner.create_nodes(nodes)
+        for l in self._each():
+            l.on_bulk_change()
 
     def update_node(self, node: Node) -> None:
         self.inner.update_node(node)
