@@ -40,6 +40,13 @@ _META_PREFIX = "qdrant-meta/"
 _POINT_PREFIX = "qdrant/"
 _COLLECTION_LABEL = "_QdrantCollection"
 _ALIAS_META_ID = "qdrant-meta-aliases"
+# the first, coalesced round of a Cosine search asks for the request's
+# `limit` between these two (`_ranked_cosine` says why the bound exists).
+# The floor keeps `limit` <= 40 on the k=64 programs it always used; the
+# bound is the k bucket the old k=160 widening round fell in, and PERF.md
+# section 6 (PR 29) has what a b=32 round costs the chip at k=128 and 256.
+_FIRST_K_MIN = 40
+_FIRST_K_MAX = 256
 
 
 class QdrantError(ValueError):
@@ -783,7 +790,7 @@ class QdrantCompat:
                 f"size {want}")
         distance = meta.properties.get("config", {}).get("distance", "Cosine")
         if distance == "Cosine":
-            ranked = self._ranked_cosine(name, vector)
+            ranked = self._ranked_cosine(name, vector, limit)
         else:
             ranked = self._ranked_raw(name, vector, distance)
         # the rank generator runs lazily inside the loop below, so this
@@ -928,19 +935,30 @@ class QdrantCompat:
         except Exception:  # noqa: BLE001
             pass
 
-    def _ranked_cosine(self, name: str, vector: Sequence[float]):
+    def _ranked_cosine(self, name: str, vector: Sequence[float],
+                       limit: int):
         """Yield (node_id, cosine) best-first, progressively widening the
         kNN so selective filters still fill `limit` (a fixed 4x
         oversample starves on rare payloads).
 
-        The first (and almost always only) round routes through the
-        collection's MicroBatcher: concurrent single-vector searches
-        from any surface coalesce into one power-of-two-bucketed batch
-        dispatch. Widening rounds (selective filters) are rare and go
-        direct — their k varies too much to bucket usefully."""
+        The first round routes through the collection's MicroBatcher:
+        concurrent single-vector searches from any surface coalesce into
+        one power-of-two-bucketed batch dispatch. It asks for what the
+        request already said it wants: ``max(_FIRST_K_MIN, limit)`` hits,
+        at most ``_FIRST_K_MAX``. So an unfiltered search up to that
+        bound is one shared scan and no more. The bound exists because a
+        batch runs at the largest k among its riders: an unbounded first
+        k would let one caller's huge `limit` make every rider of its
+        batch pay that merge, and compile a new k bucket in the serving
+        path.
+
+        Widening rounds (a selective filter, a hit whose node is gone,
+        an ANN first round that under-filled, a `limit` over the bound)
+        go direct, `k *= 4` — their k varies too much to bucket
+        usefully."""
         idx = self._index(name)
         total = len(idx)
-        k = 40
+        k = min(max(_FIRST_K_MIN, limit), _FIRST_K_MAX)
         first = True
         widen_round = 0
         # dedupe by id, not by list position: the batched round-1 call

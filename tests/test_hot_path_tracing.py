@@ -2,11 +2,13 @@
 
 What is pinned here, on the CPU:
 
-- a search that widens yields ``qdrant.widen`` > ``index.snapshot``
-  (with ``ids``: the id snapshot ``reused`` or ``copied``, ISSUE 27),
-  ``index.scan`` (with its ``path``), ``index.collect``, inside the
-  interval ``qdrant.rank`` covers, and ``qdrant.rank`` carries the
-  hydration's running sum;
+- a search that widens (since ISSUE 29 a selective filter or a ``limit``
+  over the bound: the coalesced round asks for the request's ``limit``, so
+  ``limit`` 100 alone is one shared scan and no ``qdrant.widen``) yields
+  ``qdrant.widen`` > ``index.snapshot`` (with ``ids``: the id snapshot
+  ``reused`` or ``copied``, ISSUE 27), ``index.scan`` (with its ``path``),
+  ``index.collect``, inside the interval ``qdrant.rank`` covers, and
+  ``qdrant.rank`` carries the hydration's running sum;
 - the widening search and the encoder forward are dispatch kinds of the
   compile universe, with their shapes;
 - the embed worker opens one ``embed.batch`` root a batch whose phase
@@ -24,6 +26,7 @@ import glob
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -32,6 +35,7 @@ import pytest
 from benchmark.lib import loader
 from benchmark.lib.observed import Observed
 from nornicdb_tpu import obs
+from nornicdb_tpu.api import qdrant
 from nornicdb_tpu.api.qdrant import QdrantCompat
 from nornicdb_tpu.embed.embedder import JaxEncoderEmbedder
 from nornicdb_tpu.embed.queue import CHUNK_THRESHOLD_CHARS, EmbedQueue
@@ -70,28 +74,128 @@ def device_collection():
     return _collection(4200, 64)
 
 
-def _search(compat, vector, limit=100):
+# half the fixture's points: a limit-100 search's first round (k=100)
+# yields ~50 that pass, so it widens once, to k=400
+HALF = {"must": [{"key": "n", "range": {"lt": 2100}}]}
+
+
+def _search(compat, vector, limit=100, query_filter=None):
     with obs.trace("wire", method=SEARCH, transport="http") as root:
-        hits = compat.search_points("c", vector.tolist(), limit=limit)
+        hits = compat.search_points("c", vector.tolist(), limit=limit,
+                                    query_filter=query_filter)
     return root, hits
+
+
+def _node_ids(hits):
+    return [qdrant._point_node_id("c", h["id"]) for h in hits]
+
+
+def _widen_dispatches():
+    return {(e["b"], e["k"]): e["dispatches"]
+            for e in obs.compile_universe() if e["kind"] == "vector_widen"}
 
 
 class TestVectorReadPath:
     def test_widening_search_tree(self, device_collection):
         compat, vectors = device_collection
-        root, hits = _search(compat, vectors[3])
+        root, hits = _search(compat, vectors[3], query_filter=HALF)
         assert len(hits) == 100
+        assert all(h["payload"]["n"] < 2100 for h in hits)
         (widen,) = _named(root, "qdrant.widen")
         assert widen in root.children
-        assert widen.attrs["k"] == 160 and widen.attrs["round"] == 1
+        assert widen.attrs["k"] == 400 and widen.attrs["round"] == 1
         names = [c.name for c in widen.children]
         assert names == ["index.snapshot", "index.scan", "index.collect"]
         snapshot, scan, _ = widen.children
         assert snapshot.attrs["lock_wait_ms"] >= 0.0
         assert scan.attrs["path"] == "xla"
-        assert scan.attrs["b"] == 1 and scan.attrs["k"] == 160
+        assert scan.attrs["b"] == 1 and scan.attrs["k"] == 400
         # the coalesced round's scan hangs from the batch leader's root
         assert len(_named(root, "index.scan")) == 2
+
+    def test_limit_100_without_a_filter_is_one_shared_scan(
+            self, device_collection):
+        compat, vectors = device_collection
+        before = _widen_dispatches()
+        root, hits = _search(compat, vectors[11])
+        assert not _named(root, "qdrant.widen")
+        (scan,) = _named(root, "index.scan")
+        assert scan.attrs["b"] == 1 and scan.attrs["k"] == 128
+        assert _widen_dispatches() == before
+        # the same hundred, in the same order, as the k=160 scan the
+        # request used to make for itself
+        old = compat._index("c").search(vectors[11], k=160)[:100]
+        assert _node_ids(hits) == [nid for nid, _ in old]
+        assert [h["score"] for h in hits] == pytest.approx(
+            [score for _, score in old], abs=1e-6)
+
+    @pytest.mark.parametrize("limit, first_k", [(10, 64), (40, 64),
+                                                (41, 64), (65, 128),
+                                                (256, 256)])
+    def test_first_round_asks_for_the_limit(self, device_collection,
+                                            limit, first_k):
+        # the batcher pads k to its pow2 bucket: limit <= 40 keeps the
+        # k=40 -> 64 programs it always had
+        compat, vectors = device_collection
+        root, hits = _search(compat, vectors[12], limit=limit)
+        assert len(hits) == limit
+        (scan,) = _named(root, "index.scan")
+        assert scan.attrs["k"] == first_k
+        assert not _named(root, "qdrant.widen")
+
+    def test_limit_over_the_bound_starts_at_the_bound_then_widens(
+            self, device_collection):
+        compat, vectors = device_collection
+        limit = qdrant._FIRST_K_MAX + 44
+        root, hits = _search(compat, vectors[13], limit=limit)
+        assert len(hits) == limit
+        assert len({h["id"] for h in hits}) == limit
+        scores = [h["score"] for h in hits]
+        assert scores == sorted(scores, reverse=True)
+        first, second = _named(root, "index.scan")
+        assert first.attrs["k"] == qdrant._FIRST_K_MAX
+        (widen,) = _named(root, "qdrant.widen")
+        assert widen.attrs["k"] == 4 * qdrant._FIRST_K_MAX
+        assert second in widen.children
+
+    def test_mixed_limits_sealed_together_get_their_own_counts(
+            self, device_collection):
+        compat, vectors = device_collection
+        batcher = compat._collection_microbatch("c")
+        shipped = batcher._gather_window_s
+        # hold the gather window open for a burst of two, as the
+        # benchmark's warm-up does
+        batcher._gather_window_s, batcher._last_batch = 5.0, 2
+        gate = threading.Barrier(2)
+        got = {}
+
+        def one(limit, row):
+            gate.wait(timeout=30)
+            got[limit] = _search(compat, vectors[row], limit=limit)
+
+        threads = [threading.Thread(target=one, args=a)
+                   for a in ((10, 14), (100, 15))]
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            batcher._gather_window_s = shipped
+        assert not any(t.is_alive() for t in threads)
+        assert {k: len(hits) for k, (_, hits) in got.items()} \
+            == {10: 10, 100: 100}
+        # one batch, run at the larger rider's k, under the leader's root
+        scans = [s for root, _ in got.values()
+                 for s in _named(root, "index.scan")]
+        assert [(s.attrs["b"], s.attrs["k"]) for s in scans] == [(2, 128)]
+        for root, _ in got.values():
+            (dispatch,) = _named(root, "device.dispatch")
+            assert dispatch.attrs["batch"] == 2
+            assert not _named(root, "qdrant.widen")
+        for limit, row in ((10, 14), (100, 15)):
+            alone = compat._index("c").search(vectors[row], k=limit)
+            assert _node_ids(got[limit][1]) == [nid for nid, _ in alone]
 
     def test_rank_span_carries_hydration(self, device_collection):
         compat, vectors = device_collection
@@ -104,8 +208,9 @@ class TestVectorReadPath:
         # wire_self_ms.search is the root less its children's union: a new
         # direct child outside qdrant.rank's interval would shift it
         compat, vectors = device_collection
-        root, _ = _search(compat, vectors[5])
+        root, _ = _search(compat, vectors[5], query_filter=HALF)
         (rank,) = _named(root, "qdrant.rank")
+        assert _named(root, "qdrant.widen")
         for child in root.children:
             if child.name in ("qdrant.widen", "index.snapshot",
                               "index.scan", "index.collect"):
@@ -123,14 +228,10 @@ class TestVectorReadPath:
 
     def test_widen_is_a_dispatch_kind_with_its_shape(self, device_collection):
         compat, vectors = device_collection
-        before = {(e["b"], e["k"]): e["dispatches"]
-                  for e in obs.compile_universe()
-                  if e["kind"] == "vector_widen"}
-        _search(compat, vectors[6])
-        after = {(e["b"], e["k"]): e["dispatches"]
-                 for e in obs.compile_universe()
-                 if e["kind"] == "vector_widen"}
-        assert after[(1, 256)] == before.get((1, 256), 0) + 1
+        before = _widen_dispatches()
+        _search(compat, vectors[6], query_filter=HALF)
+        after = _widen_dispatches()
+        assert after[(1, 512)] == before.get((1, 512), 0) + 1
         assert "vector_widen" in obs.dispatch.bucket_counts()
 
     def test_snapshot_span_says_whether_the_ids_were_copied(
@@ -144,14 +245,14 @@ class TestVectorReadPath:
         compat.upsert_points("c", [
             {"id": 0, "vector": vectors[0].tolist(), "payload": {"n": 0}}])
         before = counts()
-        root, _ = _search(compat, vectors[9])
+        root, _ = _search(compat, vectors[9], query_filter=HALF)
         snapshots = _named(root, "index.snapshot")
         # one count and one ``ids`` a search_batch call: the coalesced
         # round rebuilds after the write, the widening round shares it
         assert [s.attrs["ids"] for s in snapshots] == ["copied", "reused"]
         assert counts() == {"copied": before["copied"] + 1,
                             "reused": before["reused"] + 1}
-        root, _ = _search(compat, vectors[10])
+        root, _ = _search(compat, vectors[10], query_filter=HALF)
         assert [s.attrs["ids"] for s in _named(root, "index.snapshot")] \
             == ["reused", "reused"]
         assert counts() == {"copied": before["copied"] + 1,
