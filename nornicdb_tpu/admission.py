@@ -4,7 +4,7 @@ load shedding (ISSUE 15 / ROADMAP item 2).
 The observability stack can *see* overload perfectly — burn rates
 (obs/slo.py), per-request queue-delay stage attribution (obs/stages.py),
 per-query tier attribution (obs/audit.py) — but until now nothing
-*acted* on it: BENCH_r07 showed the gRPC surface past its open-loop
+*acted* on it: a pre-chip CPU run showed the gRPC surface past its open-loop
 knee collapsing from p99 7.6 ms to 565 ms while achieved QPS fell below
 offered, because every arrival was admitted into an unbounded queue.
 This module is the actuator, in three parts:
@@ -207,7 +207,7 @@ def _load_cfg() -> Dict[str, Any]:
         not in ("0", "false", "no", "off"),
         # estimated-wait bound for the interactive lane: the queueing
         # delay the scheduler refuses to let build up (the p99-at-load
-        # bound the overload bench gates ≈ this + one dispatch)
+        # bound under overload ≈ this + one dispatch)
         "max_wait_s": env_float("ADMIT_MAX_WAIT_MS", 50.0) / 1e3,
         # absolute in-flight cap per lane when no drain estimate exists
         "max_queue": env_int("ADMIT_MAX_QUEUE", 512),

@@ -1,7 +1,7 @@
 """Multi-worker wire plane: parallel frontends over ONE device plane.
 
-ISSUE 11 / ROADMAP item 3. BENCH_r07 showed the serving stack
-collapsing at the wire, not the device: the qdrant gRPC surface knees
+ISSUE 11 / ROADMAP item 3. A pre-chip CPU run showed the serving stack
+collapsing at the wire, not the device: the qdrant gRPC surface kneed
 at 724 qps open-loop while the Go reference does ~29k ops/s on the
 same contract, and PR 1's framework-floor calibration (vs_floor 1.31)
 says one Python event loop is the ceiling. This module is the

@@ -1,7 +1,7 @@
 """Process-edge JAX set-up: the compile cache and the children's backend.
 
 One chip belongs to one process. The process that may own it (``open()``,
-a bench stage child, ``chip_smoke.py``) calls :func:`ensure_compile_cache`
+``benchmark/run.py``, ``chip_smoke.py``) calls :func:`ensure_compile_cache`
 once; every child it spawns that must NOT own it (replica subprocesses,
 wire-plane workers) is launched with :func:`cpu_child_env`.
 """

@@ -112,7 +112,7 @@ ALL_TIERS: Tuple[str, ...] = tuple(sorted(
 # parity contracts per tier (host is the reference; never audited).
 # Exact tiers must reproduce the host ranking bit-for-bit (rank-parity
 # floor 1.0); statistical tiers carry the documented recall floors the
-# sentinel already gates (walk parity / quant recall >= 0.95).
+# tests already hold them to (walk parity / quant recall >= 0.95).
 STATISTICAL_FLOORS: Dict[str, float] = {
     "vector_walk_quant": 0.95,
     "vector_walk_f32": 0.95,

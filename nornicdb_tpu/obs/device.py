@@ -398,9 +398,9 @@ def _calibrated(doc: Dict[str, Any]) -> bool:
 
 
 def calibration_summary() -> Dict[str, Any]:
-    """Per-kind roofline view + the coverage verdict the sentinel
-    gates: every top-level served dispatch kind must carry effective
-    FLOPs/s and padding efficiency."""
+    """Per-kind roofline view + the coverage verdict: every top-level
+    served dispatch kind must carry effective FLOPs/s and padding
+    efficiency."""
     min_n = cfg()["min_samples"]
     with _lock:
         kinds = {k: _kind_doc_locked(k, min_n) for k in sorted(_kinds)}

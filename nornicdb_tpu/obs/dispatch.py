@@ -106,7 +106,7 @@ def bucket_counts() -> Dict[str, int]:
     """Distinct compiled (B, k) buckets per dispatch kind — the size of
     each compile cache. The resource accounting layer exposes this as
     ``nornicdb_compile_cache_entries{kind=...}``; growth at serve time
-    is the bucket-churn signal the sentinel gates on."""
+    is the bucket-churn signal."""
     with _lock:
         out: Dict[str, int] = {kind: 0 for kind in sorted(_declared)}
         for (kind, _b, _k) in _shapes:

@@ -738,8 +738,8 @@ def latency_summary(registry: Optional[Registry] = None,
                     include_empty: bool = False,
                     ) -> Dict[str, Dict[str, float]]:
     """p50/p95/p99 (ms) + count for every ``*_seconds`` histogram
-    series — one flat dict keyed ``name{label=value,...}``. Shared by
-    the /admin/telemetry endpoint and bench.py's percentile stage.
+    series — one flat dict keyed ``name{label=value,...}``. Read by
+    the /admin/telemetry endpoint.
 
     ``include_empty=True`` also lists series with zero observations
     (count 0, null percentiles) — brand-new histograms must read as

@@ -149,7 +149,7 @@ def _live_objects() -> List[Tuple[str, str, Any]]:
 
 def snapshot() -> List[Dict[str, Any]]:
     """Per-object resource/freshness stats for every live registered
-    structure — the JSON the admin surface, /readyz and bench.py read.
+    structure — the JSON the admin surface and /readyz read.
     A failing stats call yields an ``error`` entry, never a raise."""
     out: List[Dict[str, Any]] = []
     for family, name, obj in _live_objects():

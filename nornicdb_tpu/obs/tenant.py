@@ -680,9 +680,9 @@ def _fam_children(state: Dict[str, Dict], name: str) -> Dict:
 def attribution_completeness(
         state: Optional[Dict[str, Dict]] = None) -> Optional[float]:
     """Share of attributed requests carrying a REAL tenant (not
-    ``__unattributed__``) — the truth metric the multi-tenant bench
-    sentinel gates ABSOLUTELY at 1.0. None when no requests were
-    recorded at all."""
+    ``__unattributed__``) — the truth metric of the multi-tenant
+    plane, which has to read 1.0. None when no requests were recorded
+    at all."""
     if state is None:
         state = {f["name"]: f for f in _m.dump_state()}
     total = attributed = 0.0

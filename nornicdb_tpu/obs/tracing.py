@@ -38,8 +38,8 @@ be given to what the host was doing in it. Intervals grafted with
 ``attach_span`` (``coalesce.wait``, ``device.dispatch``, ``qdrant.rank``)
 were timed by another thread or after the fact and stay host-clock only.
 JAX is never imported from here: the annotation binds once ``jax`` is
-already in ``sys.modules`` (a process that never imports JAX, such as
-``bench.py``'s parent, pays one dict probe a span).
+already in ``sys.modules`` (a process that never imports JAX pays one
+dict probe a span).
 """
 
 from __future__ import annotations

@@ -32,8 +32,8 @@ def _pagerank_impl(
     # sort edges by destination ONCE; every iteration's scatter then
     # becomes a sorted segment-sum (sequential HBM traffic) instead of
     # a per-iteration sort. Its speed on the chip is not measured: the
-    # one recorded chip run (BENCH_r05_tpu_preview.json, older code) has
-    # device PageRank at 0.1x NumPy.
+    # one chip run on record (an older chip, older code) had device
+    # PageRank at 0.1x NumPy.
     order = jnp.argsort(dst)
     dst_s = dst[order]
     src_s = src[order]

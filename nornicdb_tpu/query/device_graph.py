@@ -69,7 +69,7 @@ _EVENTS_C = REGISTRY.counter(
     "Device graph plane lifecycle/degrade events", labels=("event",))
 
 # dispatch kinds pre-registered so the compile-cache accounting carries
-# their series (and the sentinel's growth gate sees them) from start
+# their series from start
 KIND_CHAIN = "graph_chain_topk"
 KIND_AGG = "graph_strip_agg"
 KIND_GRAM = "graph_cooc_gram"

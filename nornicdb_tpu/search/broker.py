@@ -2,8 +2,8 @@
 shared-memory ring (ISSUE 11).
 
 One Python event loop cannot parse and serialize wire traffic fast
-enough to feed the device plane (BENCH_r07: the qdrant gRPC surface
-knees at 724 qps open-loop while the Go reference does ~29k ops/s on
+enough to feed the device plane (a pre-chip CPU run: the qdrant gRPC
+surface kneed at 724 qps open-loop while the Go reference does ~29k ops/s on
 the same contract, and PR 1's framework-floor calibration says we sit
 at the ceiling of one loop). The architectural fix is N frontend
 workers — separate processes parsing/serializing in parallel — funneled

@@ -563,7 +563,7 @@ class CagraIndex:
                 # keep 3/4 of each expansion past the head prefilter:
                 # measured (8k x 64d clustered, CPU) recall@10 0.93 at
                 # 1/2, 0.98 at 3/4, 1.00 unpruned — 3/4 clears the
-                # 0.95 sentinel floor with margin while still dropping
+                # 0.95 recall floor with margin while still dropping
                 # a quarter of the full-row gathers
                 quant["keep"] = max(8, env_int(
                     "QUANT_WALK_KEEP",
@@ -783,8 +783,8 @@ class CagraIndex:
         Batch and k are padded to pow2 buckets so every arrival-rate
         batch from the MicroBatcher reuses one of log2(max_batch)
         compiled programs. ``itopk``/``iters``/``width`` overrides exist
-        for recall/qps sweeps (bench.py); production callers leave them
-        to the index config."""
+        for recall/qps sweeps; production callers leave them to the
+        index config."""
         queries = np.asarray(queries, dtype=np.float32)
         if queries.ndim != 2:
             raise ValueError(f"queries must be [B, D], got {queries.shape}")

@@ -39,7 +39,7 @@ Parity contract per tier: the brute tier is **rank-identical** to the
 host hybrid path (the PR 4 parity corpus). The walk tier is
 approximate by construction, so its contract is **walk-parity**: the
 fused top-k must stay within recall@k tolerance of the host hybrid
-ranking (bench + sentinel gate recall@10 >= 0.95 absolute), and every
+ranking (tests/test_hybrid_walk.py: recall@10 >= 0.95 absolute), and every
 freshness gap degrades DOWN the ladder — walk-fused -> brute-fused ->
 host — never to a wrong answer.
 

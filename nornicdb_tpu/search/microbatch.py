@@ -557,7 +557,7 @@ class MicroBatcher:
             # pad the batch dim to a power-of-two bucket: every distinct
             # B is a fresh XLA compile on an accelerator backend, and
             # arrival-rate batches take nearly every size — observed on
-            # an older chip run as 24 q/s (BENCH_r05_tpu_preview.json).
+            # an older chip run as 24 q/s.
             # Buckets cap the compile universe at log2(max_batch)
             # shapes; the pad rows repeat row 0 (no NaN paths) and their
             # results are dropped.

@@ -10,7 +10,7 @@ tool, so a PR introducing a violation fails tier-1.
 
 Usage:
     python scripts/nornic_lint.py                    # human output, exit 1 on fresh findings
-    python scripts/nornic_lint.py --json             # one sentinel-style verdict line
+    python scripts/nornic_lint.py --json             # one JSON verdict line
     python scripts/nornic_lint.py --list-passes      # pass catalog
     python scripts/nornic_lint.py --passes lock-discipline,jit-hygiene
     python scripts/nornic_lint.py --update-baseline  # regenerate the baseline
@@ -43,7 +43,7 @@ def main(argv=None) -> int:
                     help="baseline path (default: "
                          "scripts/nornic_lint_baseline.json)")
     ap.add_argument("--json", action="store_true",
-                    help="one sentinel-style JSON verdict line")
+                    help="one JSON verdict line")
     ap.add_argument("--list-passes", action="store_true",
                     help="print the pass catalog and exit")
     ap.add_argument("--update-baseline", action="store_true",

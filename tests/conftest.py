@@ -50,3 +50,20 @@ def _deadlock_watchdog():
         yield
     finally:
         faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.fixture
+def benchmark_state_put_back():
+    """A rehearsed run of the benchmark (``benchmark/run.py --rehearse``,
+    in process) sets the deployment's environment and the trace buffer's
+    capacity; the tests that follow on this worker must not inherit
+    them."""
+    from nornicdb_tpu.obs import tracing
+
+    env = dict(os.environ)
+    capacity = tracing.TRACES.capacity
+    yield
+    tracing.TRACES.capacity = capacity
+    for key in set(os.environ) - set(env):
+        del os.environ[key]
+    os.environ.update(env)

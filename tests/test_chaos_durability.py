@@ -294,7 +294,9 @@ class TestConcurrencyRaces:
         tw.start()
         tw.join()
         [t.join() for t in ts]
-        assert not errors
+        assert not errors, [f"{type(e).__name__}: {e}" for e in errors[:3]]
+        # short here and no exception above: ROADMAP D10, a reader's
+        # result put into the query cache after the last write cleared it
         assert ex.execute("MATCH (n:R) RETURN count(n)").rows == [[150]]
 
     def test_concurrent_search_index_and_query(self):

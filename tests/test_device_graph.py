@@ -604,42 +604,3 @@ class TestObsWiring:
         for kind in ("graph_chain_topk", "graph_strip_agg",
                      "graph_cooc_gram", "graph_traverse_rank"):
             assert kind in counts
-
-
-class TestSentinelGraphGates:
-    def test_parity_floor_and_extraction(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_sentinel",
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "scripts",
-                "bench_sentinel.py"))
-        bs = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bs)
-        full = {
-            "metric": "ldbc_snb_cypher_geomean", "value": 9000.0,
-            "cypher": {"device_graph": {
-                "parity": 1.0,
-                "recent_messages_friends": {
-                    "concurrent_device_qps": 3000.0},
-                "traverse_rank": {"device_qps_b16": 12000.0},
-                "compile_buckets": 7,
-            }},
-        }
-        m = bs.extract_metrics(full)
-        assert m["ldbc_device_parity"] == 1.0
-        assert m["graph_chain_conc_qps"] == 3000.0
-        assert m["graph_traverse_rank_qps"] == 12000.0
-        assert m["graph_compile_buckets"] == 7
-        # parity gates ABSOLUTELY (no baseline needed); 0.9 must flag
-        broken = dict(m, ldbc_device_parity=0.9)
-        verdict = bs.compare(broken, {})
-        flagged = {f["metric"] for f in verdict["flagged"]}
-        assert "ldbc_device_parity" in flagged
-        assert bs.compare(m, {})["verdict"] == "pass"
-        # compile-bucket growth past baseline + 2 flags
-        grown = dict(m, graph_compile_buckets=10)
-        verdict2 = bs.compare(grown, {"graph_compile_buckets": 7})
-        assert {f["metric"] for f in verdict2["flagged"]} == {
-            "graph_compile_buckets"}

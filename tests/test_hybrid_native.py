@@ -366,17 +366,14 @@ def test_warm_hybrid_warms_nothing_below_the_fused_tiers_floor():
 # -- the served path against the plain reference -----------------------------
 
 
-def test_nornicdb_search_agrees_with_the_plain_reference(monkeypatch):
+def test_nornicdb_search_agrees_with_the_plain_reference(
+        benchmark_state_put_back):
     """A whole rehearsed run of the benchmark's cell at its CPU sizes
     (6,000 passages, over ``HYBRID_MIN_N``, a 2-layer random encoder):
     text in over HTTP, every score of a sample of the served hits against
     the reference's, per source, and membership by ``fused_gap``."""
     from benchmark import run as bench_run
-    from nornicdb_tpu.obs import tracing
 
-    for key in ("NORNICDB_ADMIT_MAX_WAIT_MS", "NORNICDB_HYBRID_WALK"):
-        monkeypatch.setenv(key, os.environ.get(key, ""))  # restored after
-    monkeypatch.setattr(tracing.TRACES, "capacity", tracing.TRACES.capacity)
     out = io.StringIO()
     with redirect_stdout(out):
         rc = bench_run.main(["--workload", "hybrid1m-c32", "--seed",

@@ -28,7 +28,7 @@ from nornicdb_tpu.search.vector_index import BruteForceIndex
 
 VOCAB = [f"term{i}" for i in range(64)]
 D = 32
-RECALL_FLOOR = 0.95  # the sentinel's absolute walk-parity floor
+RECALL_FLOOR = 0.95  # the absolute walk-parity floor
 
 QUERIES = [
     "term1 term2 term3",

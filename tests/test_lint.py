@@ -516,7 +516,7 @@ class TestCli:
         assert set(verdict["passes"]) == {
             "jit-hygiene", "lock-discipline", "degrade-contract",
             "env-knob-catalog", "metrics-catalog"}
-        # the sentinel-style shape bench tooling consumes
+        # the one-line shape tooling consumes
         for key in ("files", "baseline", "total", "fresh_total"):
             assert key in verdict
 

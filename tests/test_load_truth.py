@@ -1,7 +1,6 @@
 """Load-truth observability (ISSUE 7): queue-delay stage attribution,
 per-query device cost accounting, histogram exemplars with OpenMetrics
-content negotiation, the open-loop knee estimator, and the
-metric-catalog drift lint.
+content negotiation, and the metric-catalog drift lint.
 
 The acceptance contract pinned here: every MicroBatcher/BatchCoalescer
 rider records its coalesce-wait/dispatch/merge (or apply) split into
@@ -10,10 +9,9 @@ queueing fraction answers "queued or compute?"; device dispatches are
 priced in FLOPs/bytes per (kind, index) and aggregate per real query;
 ``/metrics`` serves OpenMetrics exemplars under content negotiation
 while the classic exposition stays byte-identical with tagging on or
-off; SLO flight-recorder dumps carry the stage summary; the knee
-estimator flags queueing collapse a closed-loop bench cannot see; and
-an import-time metric family missing from docs/observability.md fails
-the catalog lint.
+off; SLO flight-recorder dumps carry the stage summary; and an
+import-time metric family missing from docs/observability.md fails the
+catalog lint.
 """
 
 import json
@@ -34,7 +32,6 @@ from nornicdb_tpu.search.vector_index import BruteForceIndex
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "scripts"))
-sys.path.insert(0, REPO)
 
 
 def _stage_child(surface, stage):
@@ -453,60 +450,6 @@ class TestFlightRecorderStages:
 
 
 # ---------------------------------------------------------------------------
-# open-loop knee estimator
-# ---------------------------------------------------------------------------
-
-
-def _pt(offered_qps, achieved_qps, p99, offered=100, completed=100,
-        errors=0, timed_out=0):
-    return {"offered_qps": offered_qps, "achieved_qps": achieved_qps,
-            "offered": offered, "completed": completed,
-            "errors": errors, "timed_out": timed_out, "p99_ms": p99}
-
-
-class TestKneeEstimator:
-    def test_stable_sweep_knee_is_best_achieved(self):
-        import bench
-
-        points = [_pt(100, 99, 2.0), _pt(200, 198, 2.5),
-                  _pt(400, 390, 4.0)]
-        est = bench._estimate_knee(points)
-        assert est["knee_qps"] == 390
-        assert est["p99_at_load_ms"] == 4.0
-        assert est["queue_collapse_detected"] is False
-        assert not any(p["collapsed"] for p in points)
-
-    def test_p99_slope_blowup_flags_collapse(self):
-        import bench
-
-        points = [_pt(100, 99, 2.0), _pt(200, 198, 2.5),
-                  _pt(400, 395, 300.0)]  # 120x the previous p99
-        est = bench._estimate_knee(points)
-        assert points[-1]["collapsed"] is True
-        assert est["queue_collapse_detected"] is True
-        assert est["knee_qps"] == 198  # last stable point
-
-    def test_achieved_shortfall_and_timeouts_flag_collapse(self):
-        import bench
-
-        points = [_pt(100, 99, 2.0),
-                  _pt(400, 300, 5.0, offered=400, completed=300),
-                  _pt(800, 500, 6.0, timed_out=10)]
-        bench._estimate_knee(points)
-        assert points[1]["collapsed"] and points[2]["collapsed"]
-
-    def test_fully_collapsed_sweep_still_emits_gate_metric(self):
-        import bench
-
-        points = [_pt(100, 50, 900.0, offered=100, completed=50)]
-        est = bench._estimate_knee(points)
-        # gate metric exists even when no point was stable
-        assert est["knee_qps"] == 50
-        assert est["p99_at_load_ms"] == 900.0
-        assert est["queue_collapse_detected"] is True
-
-
-# ---------------------------------------------------------------------------
 # metric-catalog drift lint
 # ---------------------------------------------------------------------------
 
@@ -579,48 +522,3 @@ class TestMetricsCatalogLint:
         assert ok.returncode == 0, ok.stdout + ok.stderr
         verdict = json.loads(ok.stdout)
         assert verdict["verdict"] == "pass"
-
-
-# ---------------------------------------------------------------------------
-# open-loop harness plumbing (no servers: the async point machinery)
-# ---------------------------------------------------------------------------
-
-
-class TestOpenLoopPoint:
-    def test_poisson_point_offered_vs_achieved(self):
-        import asyncio
-
-        import bench
-
-        async def run():
-            async def send():
-                await asyncio.sleep(0.001)
-
-            return await bench._open_loop_point(
-                send, rate_qps=200.0, duration_s=0.25, seed=7)
-
-        point = asyncio.run(run())
-        assert point["offered"] > 10
-        assert point["completed"] == point["offered"]
-        assert point["errors"] == 0 and point["timed_out"] == 0
-        assert point["p99_ms"] is not None and point["p99_ms"] >= 1.0
-        # arrivals are open-loop: offered rate tracks the request, not
-        # the 1ms service time (allow generous sleep-resolution slack)
-        assert point["offered_qps"] > 100
-
-    def test_errors_counted_not_raised(self):
-        import asyncio
-
-        import bench
-
-        async def run():
-            async def send():
-                raise RuntimeError("down")
-
-            return await bench._open_loop_point(
-                send, rate_qps=100.0, duration_s=0.1, seed=7)
-
-        point = asyncio.run(run())
-        assert point["errors"] == point["offered"] > 0
-        assert point["completed"] == 0
-        assert point["p99_ms"] is None

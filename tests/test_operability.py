@@ -1,22 +1,17 @@
 """Operability layer (ISSUE 5): resource & freshness accounting, SLO
-burn-rate health with /readyz, label-cardinality caps, percentile null
-safety, and the bench sentinel.
+burn-rate health with /readyz, label-cardinality caps and percentile null
+safety.
 
 The acceptance contract pinned here: /metrics exposes device-memory and
 freshness-lag gauges for all three device-resident index families;
 /readyz flips to degraded during cagra/device-bm25 background rebuilds
 and under injected MicroBatcher queue saturation, then recovers; the
 SLO engine computes multi-window burn rates from the existing latency
-histograms and writes a flight-recorder dump on breach; and the
-sentinel passes the real BENCH_r0*.json trajectory while flagging an
-injected regression.
+histograms and writes a flight-recorder dump on breach.
 """
 
 import gc
-import glob
 import json
-import os
-import sys
 import threading
 import time
 import urllib.error
@@ -33,11 +28,6 @@ from nornicdb_tpu.search.cagra import CagraIndex
 from nornicdb_tpu.search.device_bm25 import DeviceBM25
 from nornicdb_tpu.search.microbatch import MicroBatcher
 from nornicdb_tpu.search.vector_index import BruteForceIndex
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(_REPO, "scripts"))
-
-import bench_sentinel  # noqa: E402
 
 
 # ---------------------------------------------------------------------------
@@ -488,104 +478,3 @@ class TestSloEngine:
         assert 0 < http_obj["target"] < 1
         assert len(http_obj["windows"]) >= 2
         assert "dump_dir" in doc
-
-
-# ---------------------------------------------------------------------------
-# bench sentinel (ISSUE 5 tentpole pillar 3)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchSentinel:
-    SUMMARY = {
-        "summary": True, "value": 19000.0,
-        "knn": {"b1_qps": 140.0, "b1_concurrent_qps": 1100.0,
-                "b64_qps": 1900.0},
-        "cagra": {"qps_at_recall95": 5300.0, "recall_at_10": 0.994},
-        "hybrid": {"fused_qps_b16": 1250.0, "rank_parity": 1.0},
-        "surfaces": {"bolt": [5700.0, 2.3],
-                     "qdrant_grpc": [2800.0, 0.1]},
-        "pagerank_speedup_vs_numpy": 1.7,
-    }
-
-    def test_extracts_both_artifact_shapes(self):
-        m = bench_sentinel.extract_metrics(self.SUMMARY)
-        assert m["cypher_geomean"] == 19000.0
-        assert m["knn_b1_qps"] == 140.0
-        assert m["cagra_recall10"] == 0.994
-        assert m["surface_bolt_qps"] == 5700.0
-        full = {
-            "value": 18000.0,
-            "knn": {"value": 150.0, "b64_qps": 2000.0},
-            "ann": {"cagra": {"qps_at_recall95": 5000.0,
-                              "recall_at_10": 0.99}},
-            "hybrid": {"fused_qps": {"16": 1200.0}, "rank_parity": 1.0,
-                       "compile_buckets": 4},
-            "northstar": {"pagerank_device": {"speedup_vs_numpy": 1.5}},
-            "surfaces": {"bolt": {"ops_per_s": 5000.0}},
-        }
-        m = bench_sentinel.extract_metrics(full)
-        assert m["cypher_geomean"] == 18000.0
-        assert m["knn_b1_qps"] == 150.0
-        assert m["hybrid_fused_qps_b16"] == 1200.0
-        assert m["hybrid_compile_buckets"] == 4
-        assert m["pagerank_speedup"] == 1.5
-        assert m["surface_bolt_qps"] == 5000.0
-
-    def test_flags_2x_qps_regression(self):
-        fresh = bench_sentinel.extract_metrics(self.SUMMARY)
-        baseline = {k: v * 2 for k, v in fresh.items()
-                    if k.endswith("_qps") or k == "cypher_geomean"}
-        verdict = bench_sentinel.compare(fresh, baseline)
-        assert verdict["verdict"] == "regression"
-        flagged = {f["metric"] for f in verdict["flagged"]}
-        assert "cypher_geomean" in flagged
-        assert "knn_b1_qps" in flagged
-
-    def test_passes_self_comparison(self):
-        fresh = bench_sentinel.extract_metrics(self.SUMMARY)
-        verdict = bench_sentinel.compare(fresh, dict(fresh))
-        assert verdict["verdict"] == "pass"
-        assert verdict["flagged"] == []
-        assert verdict["checked"] > 5
-
-    def test_quality_floor_catches_parity_drop(self):
-        fresh = bench_sentinel.extract_metrics(self.SUMMARY)
-        baseline = dict(fresh)
-        fresh["hybrid_rank_parity"] = 0.90  # qps fine, ranking broken
-        verdict = bench_sentinel.compare(fresh, baseline)
-        assert verdict["verdict"] == "regression"
-        assert any(f["metric"] == "hybrid_rank_parity"
-                   and f["kind"] == "quality_floor"
-                   for f in verdict["flagged"])
-
-    def test_compile_universe_growth_capped(self):
-        fresh = {"hybrid_compile_buckets": 12.0}
-        baseline = {"hybrid_compile_buckets": 4.0}
-        verdict = bench_sentinel.compare(fresh, baseline)
-        assert any(f["kind"] == "growth_cap"
-                   for f in verdict["flagged"])
-        fresh["hybrid_compile_buckets"] = 6.0  # within allowance
-        assert bench_sentinel.compare(
-            fresh, baseline)["verdict"] == "pass"
-
-    def test_median_baseline_robust_to_one_loaded_round(self):
-        runs = [{"knn_b1_qps": 100.0}, {"knn_b1_qps": 110.0},
-                {"knn_b1_qps": 10.0}]  # one loaded-box round
-        base = bench_sentinel.baseline_from_runs(runs)
-        assert base["knn_b1_qps"] == 100.0
-
-    def test_real_trajectory_passes(self):
-        """Acceptance: the sentinel passes the actual BENCH_r0*.json
-        trajectory — the newest artifact vs the median of the rest."""
-        paths = sorted(glob.glob(os.path.join(_REPO, "BENCH_r0?.json")))
-        assert len(paths) >= 2
-        fresh = bench_sentinel.merge_metrics(
-            bench_sentinel.docs_from_file(paths[-1]))
-        runs = [bench_sentinel.merge_metrics(
-            bench_sentinel.docs_from_file(p)) for p in paths[:-1]]
-        runs = [r for r in runs if r]
-        assert runs, "no extractable baseline in the trajectory"
-        baseline = bench_sentinel.baseline_from_runs(runs)
-        verdict = bench_sentinel.compare(fresh, baseline)
-        assert verdict["verdict"] == "pass", verdict["flagged"]
-        assert verdict["checked"] >= 1

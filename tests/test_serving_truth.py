@@ -738,7 +738,7 @@ class TestOverheadGuard:
 
 
 # ---------------------------------------------------------------------------
-# catalog lint extensions + sentinel gates
+# catalog lint extensions
 # ---------------------------------------------------------------------------
 
 
@@ -801,49 +801,3 @@ class TestCatalogLintExtensions:
                      "nornicdb_served_tier_total",
                      "nornicdb_degrade_total"):
             assert REGISTRY.get(name) is not None, name
-
-
-class TestSentinelShadowParity:
-    def _run(self, artifact, extra_args=()):
-        import subprocess
-        import sys
-
-        proc = subprocess.run(
-            [sys.executable,
-             os.path.join(REPO, "scripts", "bench_sentinel.py"),
-             "--baseline", artifact, "--artifact", artifact,
-             *extra_args],
-            capture_output=True, text=True)
-        return proc
-
-    def test_extraction_and_absolute_gates(self, tmp_path):
-        import sys
-
-        sys.path.insert(0, os.path.join(REPO, "scripts"))
-        try:
-            import bench_sentinel as bs
-        finally:
-            sys.path.pop(0)
-        doc = {"load": {"shadow_parity": {"exact": 1.0,
-                                          "statistical": 0.96}}}
-        m = bs.extract_metrics(doc)
-        assert m["shadow_parity_exact"] == 1.0
-        assert m["shadow_parity_statistical"] == 0.96
-        summ = {"summary": True,
-                "load": {"shadow_parity_exact": 0.99,
-                         "shadow_parity_statistical": 0.9}}
-        m2 = bs.extract_metrics(summ)
-        assert m2["shadow_parity_exact"] == 0.99
-        # exact gates ABSOLUTELY at 1.0 even with no baseline metric
-        verdict = bs.compare({"shadow_parity_exact": 0.99}, {})
-        assert verdict["verdict"] == "regression"
-        assert verdict["flagged"][0]["metric"] == "shadow_parity_exact"
-        # statistical floor 0.95
-        verdict = bs.compare({"shadow_parity_statistical": 0.9}, {})
-        assert verdict["verdict"] == "regression"
-        verdict = bs.compare({"shadow_parity_exact": 1.0,
-                              "shadow_parity_statistical": 0.96}, {})
-        assert verdict["verdict"] == "pass"
-        # missing on both sides: skipped, never failed
-        verdict = bs.compare({}, {})
-        assert "shadow_parity_exact" in verdict["skipped"]
