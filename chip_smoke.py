@@ -803,6 +803,7 @@ def phase_surface(run: Run) -> Dict[str, Any]:
         {"pairs": [list(p) for p in sorted(pairs)]})
     check(rows == [[len(pairs)]], f"created {rows} of {len(pairs)} edges")
     top = min(20, n_docs // 2)
+    programs_before = graph_ops._pagerank_impl._cache_size()
     ranked = cypher(
         "CALL apoc.algo.pageRank() YIELD node, score "
         f"RETURN node.idx AS idx, score ORDER BY score DESC LIMIT {top}")
@@ -816,8 +817,9 @@ def phase_surface(run: Run) -> Dict[str, Any]:
         check(abs(score - want) <= 1e-4 * want,
               f"PageRank node {idx}: {score} vs host {want}")
     # the device program runs wherever the backend is an accelerator, and
-    # only there
-    device_arm = graph_ops._pagerank_impl._cache_size() > 0
+    # only there (counted as this call's growth: under the tests another
+    # file of the same process may have compiled the program before)
+    device_arm = graph_ops._pagerank_impl._cache_size() > programs_before
     check(device_arm == (jax.default_backend() != "cpu"),
           f"PageRank device arm ran={device_arm} on "
           f"{jax.default_backend()}")

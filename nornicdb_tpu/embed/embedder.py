@@ -32,6 +32,19 @@ _TOKENS_C = REGISTRY.counter(
     "and padded (rows x width of the array the device is given)",
     labels=("kind",))
 
+# a batch of FULL_BATCH_ROWS rows or more is never narrower than
+# FULL_BATCH_MIN_WIDTH (``JaxEncoderEmbedder._run`` says why); 16 is the
+# embed queue's batch, and the queue seals by the same floor
+FULL_BATCH_ROWS = 16
+FULL_BATCH_MIN_WIDTH = 256
+
+
+def width_bucket(tokens: int) -> int:
+    """The power-of-two width an id list of ``tokens`` ids is padded to
+    (``ops.similarity.pow2_bucket`` floored at 16, without importing JAX:
+    the embed queue seals by it)."""
+    return max(16, 1 << max(tokens - 1, 0).bit_length())
+
 
 class Embedder(Protocol):
     dims: int
@@ -72,9 +85,11 @@ class JaxEncoderEmbedder:
     - pads token widths AND the batch dimension to power-of-two buckets
       (jit cache stays small; pad rows are dropped);
     - batches up to ``max_batch`` texts per device call;
-    - long texts are chunked 512/50 and mean-pooled (whole-doc vector);
-      per-chunk vectors available via embed_chunks (reference
-      ChunkEmbeddings, db.go:224).
+    - ``embed_batch`` runs a whole document at its full width, up to
+      ``cfg.max_len`` tokens (the whole-document vector); ``embed_chunks``
+      gives a long document's 512/50 windows a vector each, a second
+      product (reference ChunkEmbeddings, db.go:224);
+    - a batch of 16 rows or more is at least 256 wide (``_run``).
     """
 
     def __init__(
@@ -117,11 +132,7 @@ class JaxEncoderEmbedder:
         # this embedder has made XLA compile
         self.shapes: Set[Tuple[int, int]] = set()
 
-    @staticmethod
-    def _bucket_width(w: int) -> int:
-        from nornicdb_tpu.ops.similarity import pow2_bucket
-
-        return max(16, pow2_bucket(w))
+    _bucket_width = staticmethod(width_bucket)
 
     def _run(self, id_lists: List[List[int]]) -> np.ndarray:
         import jax.numpy as jnp
@@ -130,8 +141,19 @@ class JaxEncoderEmbedder:
 
         n = len(id_lists)
         width = self._bucket_width(max(len(x) for x in id_lists))
-        width = min(width, self.cfg.max_len)
         rows = pow2_bucket(n)
+        if rows >= FULL_BATCH_ROWS:
+            # a rule on the array about to be dispatched, which is all
+            # this method sees: a full batch (the embed queue's 16 rows)
+            # never takes a program narrower than 256. Under that width a
+            # 16-row pass is bound by reading the float32 weights and
+            # converting the token table, so (16,128) or (16,64) would
+            # save a few ms a batch and each costs a whole-depth compile
+            # the first time a queue sealed by length holds sixteen short
+            # documents. Fewer rows (a query's (1,16), a document's
+            # (r,512) chunks) keep their own width.
+            width = max(width, FULL_BATCH_MIN_WIDTH)
+        width = min(width, self.cfg.max_len)
         arr = np.zeros((rows, width), np.int32)
         real = 0
         for i, ids in enumerate(id_lists):

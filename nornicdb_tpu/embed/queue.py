@@ -10,11 +10,13 @@ Implements the MutationListener hook so the ListenableEngine feeds it
 from __future__ import annotations
 
 import logging
-import queue
 import threading
 import time
-from typing import Callable, List, Optional
+from collections import OrderedDict
+from itertools import islice
+from typing import Callable, Dict, List, Optional, Tuple
 
+from nornicdb_tpu.embed.embedder import FULL_BATCH_MIN_WIDTH, width_bucket
 from nornicdb_tpu.obs import REGISTRY
 from nornicdb_tpu.obs.tracing import Span, span as _span, trace as _trace
 from nornicdb_tpu.storage.types import Engine, MutationListener, Node
@@ -31,6 +33,22 @@ _WORKER_S = REGISTRY.counter(
     "publish, other, starved)", labels=("phase",))
 _BATCHES_C = REGISTRY.counter(
     "nornicdb_embed_batches_total", "Batches the embed worker processed")
+# how often sealing by length engages: `arrival` rows left in arrival
+# order (nothing older stayed behind), `by_length` rows were picked ahead
+# of an older document for their length
+_SEALED_C = REGISTRY.counter(
+    "nornicdb_embed_sealed_rows_total",
+    "Rows the embed worker sealed into batches: in arrival order, or "
+    "picked by length ahead of older documents", labels=("order",))
+_WAIT_H = REGISTRY.histogram(
+    "nornicdb_embed_queue_wait_seconds",
+    "Enqueue of a node to its on_embedded hook returned",
+    buckets=(0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
+             10.0, 20.0, 30.0, 60.0, 120.0))
+
+# a seal looks no further than the oldest LOOK_AHEAD_BATCHES x batch_size
+# pending documents, so a rescan's million ids never make it O(backlog)
+LOOK_AHEAD_BATCHES = 16
 
 
 def _account_batch(root) -> None:
@@ -48,6 +66,20 @@ def _account_batch(root) -> None:
     _BATCHES_C.inc()
 
 CHUNK_THRESHOLD_CHARS = 2000  # texts longer than this get chunk embeddings
+
+
+def text_length(text: str) -> int:
+    """The length a document is sealed by: CLS and one token a
+    whitespace word. Exact for the hash tokenizer on plain words and
+    monotone for any other, and the queue needs no embedder for it."""
+    return len(text.split()) + 1
+
+
+def seal_width(length: int) -> int:
+    """The embedder's power-of-two width bucket for ``length`` tokens.
+    Nothing under ``FULL_BATCH_MIN_WIDTH`` is told apart: a full batch
+    is never narrower than that."""
+    return max(FULL_BATCH_MIN_WIDTH, width_bucket(length))
 
 
 def build_embedding_text(node: Node) -> str:
@@ -90,9 +122,14 @@ class EmbedQueue(MutationListener):
         self.rescan_interval_s = rescan_interval_s
         self.cluster_debounce_s = cluster_debounce_s
         self.on_cluster = on_cluster
-        self._q: "queue.Queue[Optional[str]]" = queue.Queue()
-        self._pending = set()
+        # ids not yet sealed into a batch, in arrival order, each with
+        # the length it is sealed by; and every id enqueued and not yet
+        # done with (waiting or in the worker's batch) with its arrival
+        # time. One lock guards both; the worker sleeps on its condition.
+        self._waiting: "OrderedDict[str, int]" = OrderedDict()
+        self._pending: Dict[str, float] = {}
         self._lock = threading.Lock()
+        self._arrived = threading.Condition(self._lock)
         self._stop = threading.Event()
         self._worker: Optional[threading.Thread] = None
         self._rescanner: Optional[threading.Thread] = None
@@ -105,25 +142,27 @@ class EmbedQueue(MutationListener):
     # -- MutationListener ------------------------------------------------
 
     def on_node_upsert(self, node: Node) -> None:
-        if (
-            node.embedding is None
-            and not embed_exempt(node)
-            and build_embedding_text(node)
-        ):
-            self.enqueue(node.id)
+        if node.embedding is None and not embed_exempt(node):
+            text = build_embedding_text(node)
+            if text:
+                self.enqueue(node.id, text_length(text))
 
     def on_node_delete(self, node_id: str) -> None:
         with self._lock:
-            self._pending.discard(node_id)
+            self._pending.pop(node_id, None)
+            self._waiting.pop(node_id, None)
 
     # -- queue -----------------------------------------------------------
 
-    def enqueue(self, node_id: str) -> None:
-        with self._lock:
+    def enqueue(self, node_id: str, length: int = 0) -> None:
+        """``length`` is ``text_length`` of the node's embedding text;
+        a caller that does not know it is sealed with the short ones."""
+        with self._arrived:
             if node_id in self._pending:
                 return
-            self._pending.add(node_id)
-        self._q.put(node_id)
+            self._pending[node_id] = time.perf_counter()
+            self._waiting[node_id] = length
+            self._arrived.notify()
 
     def start(self) -> None:
         if self._worker is None:
@@ -139,7 +178,8 @@ class EmbedQueue(MutationListener):
 
     def stop(self) -> None:
         self._stop.set()
-        self._q.put(None)
+        with self._arrived:
+            self._arrived.notify_all()
         if self._worker is not None:
             self._worker.join(timeout=10)
         if self._cluster_timer is not None:
@@ -163,42 +203,78 @@ class EmbedQueue(MutationListener):
 
         _adm.lane_scope(_adm.LANE_BACKGROUND).__enter__()
         while not self._stop.is_set():
-            batch: List[str] = []
             t_wait = time.perf_counter()
-            try:
-                item = self._q.get(timeout=0.25)
-            except queue.Empty:
+            with self._arrived:
+                if not self._waiting and not self._stop.is_set():
+                    self._arrived.wait(timeout=0.25)
+                batch, picked, oldest = ([], 0, 0.0) \
+                    if self._stop.is_set() else self._seal()
+            waited = time.perf_counter() - t_wait
+            self._starved_s += waited
+            _WORKER_S.labels("starved").inc(waited)
+            if not batch:
                 continue
-            finally:
-                waited = time.perf_counter() - t_wait
-                self._starved_s += waited
-                _WORKER_S.labels("starved").inc(waited)
-            if item is None:
-                break
-            batch.append(item)
-            while len(batch) < self.batch_size:
-                try:
-                    nxt = self._q.get_nowait()
-                except queue.Empty:
-                    break
-                if nxt is None:
-                    self._stop.set()
-                    break
-                batch.append(nxt)
             try:
-                self._process_batch(batch)
+                self._process_batch(
+                    batch, picked=picked,
+                    oldest_wait_s=time.perf_counter() - oldest)
             except Exception:
                 logger.exception("embed batch failed")
 
-    def _process_batch(self, node_ids: List[str]) -> None:
+    def _seal(self) -> Tuple[List[str], int, float]:
+        """Take the next batch off the waiting set (lock held): its ids
+        in arrival order, how many of them were picked ahead of an older
+        document, and the oldest one's arrival time.
+
+        With ``batch_size`` or fewer waiting the batch is all of them, in
+        arrival order: a write that arrives alone is embedded alone and
+        at once. With more, the oldest is the anchor and decides the
+        width (``seal_width`` of its length); its companions come from
+        the look-ahead: the longest that fit that width, and where those
+        are too few the nearest above it. A batch is as wide as its
+        longest row, so rows of a length share the padding they cause.
+        The oldest document always leaves with the next batch, so none
+        waits for more batches than documents were ahead of it when it
+        arrived, however short the ones behind it are."""
+        n = self.batch_size
+        ahead = list(islice(self._waiting.items(), LOOK_AHEAD_BATCHES * n))
+        if len(ahead) <= n:
+            rows = list(range(len(ahead)))
+        else:
+            width = seal_width(ahead[0][1])
+            rest = range(1, len(ahead))
+            # sorted() is stable: equal lengths stay in arrival order
+            fit = sorted((i for i in rest if ahead[i][1] <= width),
+                         key=lambda i: -ahead[i][1])
+            over = sorted((i for i in rest if ahead[i][1] > width),
+                          key=lambda i: ahead[i][1])
+            rows = sorted([0] + (fit + over)[:n - 1])
+        if not rows:
+            return [], 0, 0.0
+        batch = [ahead[i][0] for i in rows]
+        for nid in batch:
+            del self._waiting[nid]
+        # a row is in arrival order while nothing older stays behind
+        in_order = sum(1 for at, i in enumerate(rows) if at == i)
+        _SEALED_C.labels("arrival").inc(in_order)
+        _SEALED_C.labels("by_length").inc(len(rows) - in_order)
+        return batch, len(rows) - in_order, self._pending.get(
+            batch[0], time.perf_counter())
+
+    def _process_batch(self, node_ids: List[str], picked: int = 0,
+                       oldest_wait_s: float = 0.0) -> None:
         """One batch is one root span ``embed.batch`` (``/admin/traces``,
         and ``nornic:embed.batch`` in a profiler trace) whose children
-        are its phases; the phase counter is fed from the same spans."""
+        are its phases; the phase counter is fed from the same spans.
+        ``picked`` rows were sealed ahead of older documents; the oldest
+        row had waited ``oldest_wait_s`` when the batch was sealed."""
         starved, self._starved_s = self._starved_s, 0.0
         root = None
         try:
             with _trace("embed.batch", rows=len(node_ids),
-                        starved_ms=round(starved * 1e3, 3)) as root:
+                        starved_ms=round(starved * 1e3, 3), picked=picked,
+                        oldest_wait_ms=round(oldest_wait_s * 1e3, 3)
+                        ) as root:
                 self._embed_and_store(node_ids)
         finally:
             _account_batch(root)
@@ -210,12 +286,10 @@ class EmbedQueue(MutationListener):
                 try:
                     node = self.storage.get_node(nid)
                 except KeyError:
-                    with self._lock:
-                        self._pending.discard(nid)
+                    self._done(nid)
                     continue
                 if node.embedding is not None:
-                    with self._lock:
-                        self._pending.discard(nid)
+                    self._done(nid)
                     continue
                 nodes.append(node)
             texts = [build_embedding_text(n) for n in nodes]
@@ -226,12 +300,12 @@ class EmbedQueue(MutationListener):
         if vectors is None:
             self.failed_count += len(nodes)
             for n in nodes:
-                with self._lock:
-                    self._pending.discard(n.id)
+                self._done(n.id)
             return
         for node, text, vec in zip(nodes, texts, vectors):
             # per-node isolation: one failing write must not wedge the rest
             # of the batch in _pending (they'd never re-enqueue)
+            embedded = False
             try:
                 chunk_vectors = None
                 if len(text) > CHUNK_THRESHOLD_CHARS and hasattr(
@@ -262,13 +336,20 @@ class EmbedQueue(MutationListener):
                             self.on_embedded(fresh)
                         except Exception:
                             logger.exception("on_embedded callback failed")
+                embedded = True
             except Exception:
                 logger.exception("embed write failed for %s", node.id)
                 self.failed_count += 1
             finally:
-                with self._lock:
-                    self._pending.discard(node.id)
+                self._done(node.id, embedded)
         self._schedule_clustering()
+
+    def _done(self, node_id: str, embedded: bool = False) -> None:
+        """The worker is done with ``node_id``, embedded or dropped."""
+        with self._lock:
+            arrived = self._pending.pop(node_id, None)
+        if embedded and arrived is not None:
+            _WAIT_H.observe(time.perf_counter() - arrived)
 
     def _embed_with_retry(self, texts: List[str]):
         """Reference: embedBatchWithRetry + llama crash recovery
@@ -312,13 +393,8 @@ class EmbedQueue(MutationListener):
         while not self._stop.wait(self.rescan_interval_s):
             try:
                 for node in self.storage.all_nodes():
-                    if (
-                        node.embedding is None
-                        and not embed_exempt(node)
-                        and build_embedding_text(node)
-                        and not (self.has_vector is not None
-                                 and self.has_vector(node.id))
-                    ):
-                        self.enqueue(node.id)
+                    if not (self.has_vector is not None
+                            and self.has_vector(node.id)):
+                        self.on_node_upsert(node)
             except Exception:
                 logger.exception("rescan failed")
