@@ -591,27 +591,41 @@ class QdrantCompat:
                     )
             else:
                 coerced.append([])
-        # pass 2: apply
+        # pass 2: apply. Every node first, then every vector in ONE index
+        # call under one hold of the index lock, in the request's order:
+        # a search never finds an id whose node is not there yet, and the
+        # request is whole in the index before its 200
         n = 0
-        with self._own_write():
-            for p, vec in zip(points, coerced):
-                nid = _point_node_id(name, p["id"])
-                node = Node(
-                    id=nid,
-                    labels=[self._label(name)],
-                    properties={
-                        "_point_id": p["id"],
-                        "_vector": vec,
-                        "payload": p.get("payload") or {},
-                    },
-                )
-                if self.storage.has_node(nid):
-                    self.storage.update_node(node)
-                else:
-                    self.storage.create_node(node)
-                if vec:
-                    idx.add(nid, vec)
-                n += 1
+        fresh = 0
+        ids: List[str] = []
+        vecs: List[List[float]] = []
+        with _obs_span("qdrant.upsert", points=len(points)) as up, \
+                self._own_write():
+            with _obs_span("upsert.storage"):
+                for p, vec in zip(points, coerced):
+                    nid = _point_node_id(name, p["id"])
+                    node = Node(
+                        id=nid,
+                        labels=[self._label(name)],
+                        properties={
+                            "_point_id": p["id"],
+                            "_vector": vec,
+                            "payload": p.get("payload") or {},
+                        },
+                    )
+                    if self.storage.has_node(nid):
+                        self.storage.update_node(node)
+                    else:
+                        self.storage.create_node(node)
+                        fresh += 1
+                    if vec:
+                        ids.append(nid)
+                        vecs.append(vec)
+                    n += 1
+            if ids:
+                with _obs_span("upsert.index", rows=len(ids)):
+                    idx.add_matrix(ids, np.asarray(vecs, np.float32))
+            up.annotate(new=fresh, overwritten=n - fresh)
         if n:
             self._invalidate_raw(name)
             # write-path pricing (ISSUE 18): bulk upserts were unpriced

@@ -70,6 +70,9 @@ IMPORT_TIME_MODULES = (
     "nornicdb_tpu.embed.embedder",
     "nornicdb_tpu.api.qdrant",
     "nornicdb_tpu.storage.memory",
+    # ISSUE 32: the brute index's refresh and ship-bytes counters, the
+    # id table's `extended`, the `index_update` dispatch kind
+    "nornicdb_tpu.search.vector_index",
 )
 
 _PREFIX = "nornicdb_"

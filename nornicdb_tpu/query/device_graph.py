@@ -875,58 +875,63 @@ class DeviceGraphPlane:
             _ledger(TIER_RANK, "stale_snapshot",
                     {"catalog_version": self.catalog.version})
             return None
-        dv = index.device_view()
-        if dv is None:
-            return None
-        matrix, valid, _ext_ids, mutations, _comp = dv
-        if mutations != snap["mutations"]:
-            _event("degrade_stale")
-            _ledger(TIER_RANK, "stale_snapshot",
-                    {"snapshot_mutations": snap["mutations"],
-                     "index_mutations": mutations})
-            return None
-        import time as _time
+        # the lease holds the index lock until the rank is DISPATCHED:
+        # the next write's refresh donates the device arrays
+        with index.device_lease() as lease:
+            if lease.view is None:
+                return None
+            matrix, valid, _ext_ids, mutations, _comp = lease.view
+            if mutations != snap["mutations"]:
+                _event("degrade_stale")
+                _ledger(TIER_RANK, "stale_snapshot",
+                        {"snapshot_mutations": snap["mutations"],
+                         "index_mutations": mutations})
+                return None
+            import time as _time
 
-        jax = _jx()
-        jnp = jax.numpy
-        f1 = pow2_bucket(max(1, snap["hops"][0][2]))
-        f2 = pow2_bucket(max(1, snap["hops"][1][2])) if len(hops_t) == 2 \
-            else 0
-        frontier = f1 * max(f2, 1)
-        if frontier > 1 << 18:
-            _event("degrade_rank_overflow")
-            _ledger(TIER_RANK, "rank_overflow",
-                    {"snapshot_version": snap["version"]})
-            return None
-        kp = pow2_bucket(min(k, max(frontier, 1)))
-        bsz = pow2_bucket(len(anchors))
-        a = np.full(bsz, -1, dtype=np.int32)
-        a[:len(anchors)] = np.asarray(anchors, dtype=np.int32)
-        q = np.zeros((bsz, queries.shape[1]), np.float32)
-        q[:len(anchors)] = queries
-        ip1, fr1, _d1 = snap["hops"][0]
-        if f2:
-            ip2, fr2, _d2 = snap["hops"][1]
-        else:
-            ip2, fr2 = ip1, fr1  # unused when f2 == 0
-        t0 = _time.perf_counter()
-        try:
-            vals, sel_rows = _traverse_rank_fn(f1, f2, kp)(
-                jnp.asarray(a), jnp.asarray(q), ip1, fr1, ip2, fr2,
-                snap["slot_of_row"], matrix, valid,
-                jnp.int32(snap["n"]))
-            vals = np.asarray(vals)
-            sel_rows = np.asarray(sel_rows)
-        except Exception:  # noqa: BLE001
-            _event("degrade_error")
-            _ledger(TIER_RANK, "error",
-                    {"snapshot_version": snap["version"]})
-            return None
+            jax = _jx()
+            jnp = jax.numpy
+            f1 = pow2_bucket(max(1, snap["hops"][0][2]))
+            f2 = pow2_bucket(max(1, snap["hops"][1][2])) \
+                if len(hops_t) == 2 else 0
+            frontier = f1 * max(f2, 1)
+            if frontier > 1 << 18:
+                _event("degrade_rank_overflow")
+                _ledger(TIER_RANK, "rank_overflow",
+                        {"snapshot_version": snap["version"]})
+                return None
+            kp = pow2_bucket(min(k, max(frontier, 1)))
+            bsz = pow2_bucket(len(anchors))
+            a = np.full(bsz, -1, dtype=np.int32)
+            a[:len(anchors)] = np.asarray(anchors, dtype=np.int32)
+            q = np.zeros((bsz, queries.shape[1]), np.float32)
+            q[:len(anchors)] = queries
+            ip1, fr1, _d1 = snap["hops"][0]
+            if f2:
+                ip2, fr2, _d2 = snap["hops"][1]
+            else:
+                ip2, fr2 = ip1, fr1  # unused when f2 == 0
+            t0 = _time.perf_counter()
+            try:
+                vals, sel_rows = _traverse_rank_fn(f1, f2, kp)(
+                    jnp.asarray(a), jnp.asarray(q), ip1, fr1, ip2, fr2,
+                    snap["slot_of_row"], matrix, valid,
+                    jnp.int32(snap["n"]))
+                dims = int(matrix.shape[1])
+                del matrix, valid
+                lease.release()
+                vals = np.asarray(vals)
+                sel_rows = np.asarray(sel_rows)
+            except Exception:  # noqa: BLE001
+                _event("degrade_error")
+                _ledger(TIER_RANK, "error",
+                        {"snapshot_version": snap["version"]})
+                return None
         dt = _time.perf_counter() - t0
         record_dispatch(KIND_RANK, bsz, f1 * 100_000 + kp, dt)
         if _cost.pricing_enabled():
             flops, byts = _cost.price_traverse_rank(
-                bsz, frontier, int(matrix.shape[1]), kp)
+                bsz, frontier, dims, kp)
             _cost.record_query_cost(
                 KIND_RANK, _cost.cost_name(self), len(anchors), flops,
                 byts)

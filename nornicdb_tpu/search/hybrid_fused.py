@@ -731,52 +731,58 @@ class FusedHybrid:
             t_plan0 = time.time()
         # the exact tier's view capture happens only here — the walk
         # dispatch above never touches the brute matrix, so a served
-        # walk batch skips the post-write device re-ship entirely
-        view = self.brute.device_view()
-        if view is None:
-            return none_rows
-        m, valid, vec_ext, mutations, _compactions = view
-        l2v = self._ensure_map(snap, mutations)
-        if l2v is None:
-            # a write/compaction moved the brute matrix between the
-            # view capture and the map read — retry next batch
-            _HYB_C.labels("host_fallback_vec_race").inc()
-            self._ledger(TIER_BRUTE_F32, "host", "vec_race", snap)
-            return none_rows
-        args = (*lex_base, l2v, jnp.float32(avgdl), qn)
-        t0 = time.time()
-        # from the call into the fused program to its arrays on the host
-        with _span("hybrid.dispatch", b=b, k=kq, entries=entries,
-                   terms=n_terms, u=u_b, tier=TIER_BRUTE_F32):
-            if snap["shards"] == 1:
-                ls, li, vs, vi, fs, fpos = _fused_single(
-                    *args, jnp.asarray(m), jnp.asarray(valid), *tail,
-                    kq=kq, rrf_k=self.rrf_k)
-                lgrow = li
-            elif "mesh" in snap and len(jax.devices()) >= snap["shards"]:
-                mp, vp = self._vec_arrays(m, valid, snap)
-                if mp is None:
-                    _HYB_C.labels("host_fallback_unshardable").inc()
-                    self._ledger(TIER_BRUTE_F32, "host", "unshardable",
-                                 snap)
-                    return none_rows
-                ls, lgrow, vs, vi, fs, fpos = _fused_sharded_impl(
-                    *args, mp, vp, *tail, kq=kq, rrf_k=self.rrf_k,
-                    mesh_holder=_holder(snap["mesh"]))
-            else:
-                ls, lgrow, vs, vi, fs, fpos = self._shard_loop(
-                    snap, args, m, valid, tail, kq)
-            # force to host inside the timed window (async dispatch)
-            ls, lgrow = np.asarray(ls), np.asarray(lgrow)
-            vs, vi = np.asarray(vs), np.asarray(vi)
-            fs, fpos = np.asarray(fs), np.asarray(fpos)
+        # walk batch leaves the pending writes to whoever scans next.
+        # The lease holds the index lock until the fused program is
+        # DISPATCHED: the next write's refresh donates m and valid
+        with self.brute.device_lease() as lease:
+            if lease.view is None:
+                return none_rows
+            m, valid, vec_ext, mutations, _compactions = lease.view
+            l2v = self._ensure_map(snap, mutations)
+            if l2v is None:
+                # a write/compaction moved the brute matrix between the
+                # view capture and the map read — retry next batch
+                _HYB_C.labels("host_fallback_vec_race").inc()
+                self._ledger(TIER_BRUTE_F32, "host", "vec_race", snap)
+                return none_rows
+            args = (*lex_base, l2v, jnp.float32(avgdl), qn)
+            t0 = time.time()
+            # from the call into the fused program to its arrays on the
+            # host
+            with _span("hybrid.dispatch", b=b, k=kq, entries=entries,
+                       terms=n_terms, u=u_b, tier=TIER_BRUTE_F32):
+                if snap["shards"] == 1:
+                    ls, li, vs, vi, fs, fpos = _fused_single(
+                        *args, m, valid, *tail, kq=kq, rrf_k=self.rrf_k)
+                    lgrow = li
+                elif "mesh" in snap \
+                        and len(jax.devices()) >= snap["shards"]:
+                    mp, vp = self._vec_arrays(m, valid, snap)
+                    if mp is None:
+                        _HYB_C.labels("host_fallback_unshardable").inc()
+                        self._ledger(TIER_BRUTE_F32, "host",
+                                     "unshardable", snap)
+                        return none_rows
+                    ls, lgrow, vs, vi, fs, fpos = _fused_sharded_impl(
+                        *args, mp, vp, *tail, kq=kq, rrf_k=self.rrf_k,
+                        mesh_holder=_holder(snap["mesh"]))
+                else:
+                    ls, lgrow, vs, vi, fs, fpos = self._shard_loop(
+                        snap, args, m, valid, tail, kq)
+                vec_price = _cost.price_brute(
+                    pow2_bucket(b), int(m.shape[0]), int(m.shape[1]))
+                # not pinned while this thread waits for the result
+                del m, valid
+                lease.release()
+                # force to host inside the timed window (async dispatch)
+                ls, lgrow = np.asarray(ls), np.asarray(lgrow)
+                vs, vi = np.asarray(vs), np.asarray(vi)
+                fs, fpos = np.asarray(fs), np.asarray(fpos)
         t1 = time.time()
         record_dispatch("hybrid_fused", pow2_bucket(b), kq, t1 - t0)
         _HYB_C.labels("dispatch").inc()
         self._record_cost("hybrid_fused", b, snap,
-                          vec_flops_bytes=_cost.price_brute(
-                              pow2_bucket(b), int(m.shape[0]),
-                              int(m.shape[1])))
+                          vec_flops_bytes=vec_price)
         with _span("hybrid.decode", b=b):
             out = self._decode(snap, vec_ext, delta, token_rows, extras,
                                ls, lgrow, vs, vi, fs, fpos, kq,

@@ -400,8 +400,11 @@ class SearchService:
         search of ``limit`` hits can dispatch: the embedder's
         single-query shape, and one fused batch for each power-of-two
         bucket up to ``max_batch`` riders (default: the most the hybrid
-        batcher seals). The lexical snapshot is built first, inline, and
-        the first batch ships the vector matrix to the device. Returns
+        batcher seals), then the index's update programs
+        (``BruteForceIndex.warm_updates``), so a store after the warm-up
+        compiles nothing either. The lexical snapshot is built first,
+        inline, and the first batch ships the vector matrix to the
+        device. Returns
         the buckets warmed: none while the fused tier is not eligible
         (``_ensure_fused``). A server calls this once after a bulk load;
         nothing else changes (no result is cached, no counter of served
@@ -431,6 +434,8 @@ class SearchService:
                                [extra] * b)
             warmed.append(b)
             b *= 2
+        # and the programs that write later stores into the device copy
+        self.vectors.warm_updates()
         return warmed
 
     def _fused_hybrid_trio(self, query, qv, overfetch, weights):

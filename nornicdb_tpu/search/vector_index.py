@@ -2,22 +2,35 @@
 
 The TPU analog of the reference's GPUEmbeddingIndex
 (pkg/gpu/accelerator.go:290-843 Add/Sync/Search): a host NumPy mirror is
-the source of truth; a capacity-padded [C,D] normalized matrix is synced
-to device HBM lazily (dirty-flag) and queried with one MXU matmul + top-k
-(nornicdb_tpu.ops.similarity). Growth re-pads to the next power-of-two
-capacity so jit never sees a new shape per insert.
+the source of truth, written only under the index lock; a capacity-padded
+[C,D] normalized matrix lives in device HBM and is queried with one MXU
+matmul + top-k (nornicdb_tpu.ops.similarity). Growth re-pads to the next
+power-of-two capacity so jit never sees a new shape per insert.
+
+The device copy is UPDATED, not replaced: a write notes the slots it
+touched, and the next reader applies them to the resident arrays with one
+jitted, donated scatter (``index_update``) before it scans. Only a change
+of capacity (growth, compaction, a load) ships the whole matrix. Because
+an update donates the arrays, nobody may hold them across a release of
+the index lock: a reader captures them, DISPATCHES the program that reads
+them and only then lets the lock go (``_Lease``); the device runs
+programs in the order they were dispatched, so a scan dispatched before
+an update reads the old contents.
 """
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from nornicdb_tpu.obs import cost as _cost
+from nornicdb_tpu.obs.dispatch import declare_kind, record_dispatch
 from nornicdb_tpu.obs.metrics import REGISTRY
 from nornicdb_tpu.obs.tracing import span as _span
 from nornicdb_tpu.ops.similarity import (
@@ -30,13 +43,106 @@ from nornicdb_tpu.ops.similarity import (
 )
 
 
-# how often a reader of the slot-to-id table was handed the generation's
-# shared snapshot (reused) against a fresh copy after a write (copied)
+# how a reader of the slot-to-id table came by it: the table every reader
+# since the last write that moved an id shares (reused), that table with
+# only the touched chunks rebuilt (extended), or a whole new one (copied)
 _IDS_SNAPSHOT_C = REGISTRY.counter(
     "nornicdb_index_ids_snapshot_total",
-    "Slot-to-id snapshots handed to index readers, by whether the "
-    "mutation generation's snapshot was shared or rebuilt",
+    "Slot-to-id tables handed to index readers, by whether the last "
+    "one was shared, had its touched chunks rebuilt, or was rebuilt whole",
     labels=("result",))
+_REFRESH_C = REGISTRY.counter(
+    "nornicdb_index_refresh_total",
+    "Refreshes of the index's device copy, by whether pending rows were "
+    "written into the resident arrays or the whole matrix was shipped",
+    labels=("kind",))
+_SHIP_BYTES_C = REGISTRY.counter(
+    "nornicdb_index_device_ship_bytes_total",
+    "Bytes sent from the host to the index's device copy",
+    labels=("kind",))
+
+# the update program under its own kind in nornicdb_device_dispatch_*:
+# b = the bucket its rows were padded to, k = 1; the seconds are the
+# host's (call to return: the program runs behind the scans dispatched
+# before it, and nobody waits for it)
+KIND_UPDATE = "index_update"
+declare_kind(KIND_UPDATE)
+
+# pending rows are applied in rounds padded to the smallest bucket that
+# holds them, so a (capacity, dims) has three update programs whatever
+# the writers do; more than the largest takes several rounds
+UPDATE_BUCKETS = (16, 128, 1024)
+
+# slots of the id table are grouped so that a write which moves an id
+# rebuilds one group and not the table
+IDS_CHUNK = 4096
+
+
+def _update_bucket(n: int) -> int:
+    return next(b for b in UPDATE_BUCKETS if b >= n)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def index_update(matrix, valid, slots, rows, vals):
+    """``matrix[slots] = rows`` and ``valid[slots] = vals`` in the arrays
+    they are given (both donated: the caller's references are dead when
+    this returns). ``slots`` may repeat a slot, with the same row."""
+    return matrix.at[slots].set(rows), valid.at[slots].set(vals)
+
+
+def _ship(host: np.ndarray):
+    """A device copy of ``host`` that no later write to ``host`` can
+    reach, there before this returns. On the CPU backend ``device_put``
+    aliases a 64-byte-aligned buffer instead of copying it (ROADMAP
+    D10(d)); there the copy is made first."""
+    if jax.default_backend() == "cpu":
+        host = host.copy()
+    return jax.block_until_ready(jax.device_put(host))
+
+
+class IdTable:
+    """Slot -> external id for one generation of an index, read-only: a
+    tuple of tuples of ``IDS_CHUNK`` ids. A reader that captured one
+    keeps resolving slots to the ids they held then, whatever is freed
+    and reused afterwards; successive tables share every chunk no write
+    touched."""
+
+    __slots__ = ("chunks",)
+
+    def __init__(self, chunks: Tuple[Tuple[Optional[str], ...], ...]):
+        self.chunks = chunks
+
+    def __getitem__(self, slot: int) -> Optional[str]:
+        return self.chunks[slot // IDS_CHUNK][slot % IDS_CHUNK]
+
+
+class _Lease:
+    """The index lock, held from the capture of the device arrays to the
+    dispatch of the program that reads them and released by hand there
+    (``release`` is idempotent; leaving the ``with`` releases too)."""
+
+    __slots__ = ("_lock", "_held", "view")
+
+    def __init__(self, lock) -> None:
+        self._lock = lock
+        self._held = False
+        self.view = None
+
+    def acquire(self) -> None:
+        self._lock.acquire()
+        self._held = True
+
+    def release(self) -> None:
+        if self._held:
+            self._held = False
+            self._lock.release()
+
+    def __enter__(self) -> "_Lease":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
 
 # the fewest rows ``add_matrix`` takes as one block: 2.7 us a row against
 # ``add``'s 6.1 at 64 rows of 1,024 floats, 6.7 against 5.8 at 8 (the
@@ -92,13 +198,16 @@ class BruteForceIndex:
         # Length-capped; _changelog_floor marks how far back it reaches.
         self._changelog: List[Tuple[int, str]] = []
         self._changelog_floor = 0
-        # device cache
+        # the device copy, and what the host mirror has that it lacks:
+        # slots written since the last refresh (noted only while a copy
+        # exists; without one the first reader ships everything)
         self._dev_matrix = None
         self._dev_valid = None
-        self._dirty = True
-        # (mutations, tuple(_ext_ids)): the slot-to-id snapshot every
-        # reader of that generation shares (_ids_snapshot_locked)
-        self._view_ids_cache = None
+        self._pending: set = set()
+        # the slot-to-id table readers share (_ids_snapshot_locked) and
+        # the chunks of it in which a write has moved an id since
+        self._ids_table: Optional[IdTable] = None
+        self._ids_moved: set = set()
         # quantized serving plane (search/device_quant.py), created
         # lazily when NORNICDB_VECTOR_QUANT != off and the corpus
         # clears the quant floor — HBM then holds int8/PQ codes while
@@ -154,7 +263,7 @@ class BruteForceIndex:
         self._valid = new_v
         self._ext_ids.extend([None] * (new_cap - len(self._ext_ids)))
         self._capacity = new_cap
-        self._dirty = True
+        self._drop_device_locked()
 
     # -- mutation ---------------------------------------------------------
 
@@ -164,7 +273,7 @@ class BruteForceIndex:
             if ext_id in self._slot_of:
                 slot = self._slot_of[ext_id]
                 self._matrix[slot] = self._normalize(v)
-                self._dirty = True
+                self._wrote_locked(slot)
                 self.mutations += 1
                 self._log_change_locked(ext_id)
                 return
@@ -179,9 +288,27 @@ class BruteForceIndex:
             self._ext_ids[slot] = ext_id
             self._slot_of[ext_id] = slot
             self._n_alive += 1
-            self._dirty = True
+            self._wrote_locked(slot, id_moved=True)
             self.mutations += 1
             self._log_change_locked(ext_id)
+
+    def _wrote_locked(self, slot: int, id_moved: bool = False) -> None:
+        """A write changed ``slot`` of the host mirror (and, with
+        ``id_moved``, whose slot it is)."""
+        if self._dev_matrix is not None:
+            self._pending.add(slot)
+        if id_moved:
+            self._ids_moved.add(slot // IDS_CHUNK)
+
+    def _drop_device_locked(self) -> None:
+        """The slot space changed (growth, compaction): the device copy
+        and the id table are of another shape. The next reader ships the
+        matrix whole."""
+        self._dev_matrix = None
+        self._dev_valid = None
+        self._pending = set()
+        self._ids_table = None
+        self._ids_moved = set()
 
     def _log_change_locked(self, ext_id: str) -> None:
         self._changelog.append((self.mutations, ext_id))
@@ -296,7 +423,10 @@ class BruteForceIndex:
             self._slot_of.update(zip(ext_ids, range(start, start + n)))
             self._count += n
             self._n_alive += n
-            self._dirty = True
+            if self._dev_matrix is not None:
+                self._pending.update(range(start, start + n))
+            self._ids_moved.update(range(start // IDS_CHUNK,
+                                         (start + n - 1) // IDS_CHUNK + 1))
             seq0 = self.mutations
             self.mutations += n
             self._changelog.extend(zip(range(seq0 + 1, seq0 + n + 1),
@@ -312,7 +442,7 @@ class BruteForceIndex:
             self._ext_ids[slot] = None
             self._free.append(slot)
             self._n_alive -= 1
-            self._dirty = True
+            self._wrote_locked(slot, id_moved=True)
             self.mutations += 1
             self._maybe_compact_locked()
             return True
@@ -363,7 +493,7 @@ class BruteForceIndex:
             self._capacity = new_cap
             self._count = len(rows)
             self._free = []
-        self._dirty = True
+        self._drop_device_locked()
         self.mutations += 1
         self.compactions += 1
 
@@ -431,38 +561,102 @@ class BruteForceIndex:
     # -- search -----------------------------------------------------------
 
     def _device_arrays_locked(self):
-        if self._dirty or self._dev_matrix is None:
-            self._dev_matrix = jnp.asarray(self._matrix)
-            self._dev_valid = jnp.asarray(self._valid)
-            self._dirty = False
+        """The device arrays, current with the host mirror; call with the
+        index lock held and keep it until the program that reads them is
+        dispatched (``_Lease``): the next refresh donates them."""
+        if self._dev_matrix is None:
+            with _span("index.refresh", kind="full", rows=self._capacity,
+                       bucket=0):
+                self._dev_matrix = _ship(self._matrix)
+                self._dev_valid = _ship(self._valid)
+            self._pending = set()
+            _REFRESH_C.labels("full").inc()
+            _SHIP_BYTES_C.labels("full").inc(
+                self._matrix.nbytes + self._valid.nbytes)
+        elif self._pending:
+            slots = np.sort(np.fromiter(self._pending, np.int32,
+                                        len(self._pending)))
+            self._pending = set()
+            with _span("index.refresh", kind="rows", rows=len(slots)) as sp:
+                sp.annotate(bucket=self._apply_rows_locked(slots))
+            _REFRESH_C.labels("rows").inc()
         return self._dev_matrix, self._dev_valid
 
+    def _apply_rows_locked(self, slots: np.ndarray) -> int:
+        """Write rows ``slots`` of the host mirror into the resident
+        device arrays, in rounds of the smallest bucket that holds what
+        is left (padded by repeating the round's last row); returns the
+        last round's bucket."""
+        top = UPDATE_BUCKETS[-1]
+        for lo in range(0, len(slots), top):
+            part = slots[lo:lo + top]
+            n = len(part)
+            bucket = _update_bucket(n)
+            padded = np.full(bucket, part[-1], np.int32)
+            padded[:n] = part
+            # fancy indexing copies: what is handed over is no view of
+            # the mirror
+            rows, vals = self._matrix[padded], self._valid[padded]
+            t0 = time.perf_counter()
+            self._dev_matrix, self._dev_valid = index_update(
+                self._dev_matrix, self._dev_valid, padded, rows, vals)
+            record_dispatch(KIND_UPDATE, bucket, 1,
+                            time.perf_counter() - t0)
+            _SHIP_BYTES_C.labels("rows").inc(
+                padded.nbytes + rows.nbytes + vals.nbytes)
+        return bucket
+
+    def warm_updates(self) -> None:
+        """Compile (or load from the cache) every program a write to this
+        index can need at its present capacity, by rewriting slot 0 with
+        its own row once a bucket. A server calls this before it takes
+        traffic, so that the first search after a write is not the one
+        that compiles. Nothing to do while the index is empty or below
+        the host tier's size (no device copy)."""
+        with self._lock:
+            if (self._n_alive == 0 or self._capacity * (self.dims or 1)
+                    <= self._SMALL_HOST):
+                return
+            self._device_arrays_locked()
+            for bucket in UPDATE_BUCKETS:
+                self._apply_rows_locked(np.zeros(bucket, np.int32))
+            jax.block_until_ready(self._dev_matrix)
+
     def _ids_snapshot_locked(self):
-        """(slot-to-id snapshot, ``"reused"`` | ``"copied"``) for the
-        current ``mutations`` generation; call with the index lock held.
-        The snapshot is rebuilt only when a write has moved the
-        generation (every writer of ``_ext_ids`` bumps ``mutations``
-        under the lock), is shared by every reader of that generation
-        and is a tuple because nobody may write it: a reader that
-        captured it keeps resolving slots to the ids they held then,
-        whatever is freed and reused afterwards."""
-        cached = self._view_ids_cache
-        if cached is not None and cached[0] == self.mutations:
-            result = "reused"
-        else:
-            cached = (self.mutations, tuple(self._ext_ids))
-            self._view_ids_cache = cached
+        """(slot-to-id table, ``"reused"`` | ``"extended"`` |
+        ``"copied"``); call with the index lock held. Every writer of
+        ``_ext_ids`` notes the chunk it touched under the lock; an
+        overwrite of a row moves no id and leaves the table shared, a
+        new or removed id rebuilds its chunk of ``IDS_CHUNK`` slots, and
+        only a change of the slot space (growth, compaction, a load)
+        rebuilds the table. Nobody may write a table handed out."""
+        table = self._ids_table
+        n_chunks = -(-self._capacity // IDS_CHUNK)
+        if table is None:
+            table = IdTable(tuple(
+                tuple(self._ext_ids[c * IDS_CHUNK:(c + 1) * IDS_CHUNK])
+                for c in range(n_chunks)))
             result = "copied"
+        elif self._ids_moved:
+            chunks = list(table.chunks)
+            for c in self._ids_moved:
+                chunks[c] = tuple(
+                    self._ext_ids[c * IDS_CHUNK:(c + 1) * IDS_CHUNK])
+            table = IdTable(tuple(chunks))
+            result = "extended"
+        else:
+            result = "reused"
+        self._ids_table = table
+        self._ids_moved = set()
         _IDS_SNAPSHOT_C.labels(result).inc()
-        return cached[1], result
+        return table, result
 
     def view_meta(self):
         """(mutations, compactions) — or None while the index is empty
         — WITHOUT forcing the device arrays current. The walk tier
-        only needs the mutation counter for its freshness gate; after
-        a write burst, :meth:`device_view` would re-ship the whole
-        matrix to device and re-copy the capacity-sized ext-id list,
-        a per-write tax the walk dispatch never uses."""
+        only needs the mutation counter for its freshness gate, and
+        :meth:`device_lease` would apply the pending writes to the
+        device copy, which the walk dispatch never reads."""
         with self._lock:
             if self._n_alive == 0 or self._matrix is None:
                 return None
@@ -471,33 +665,39 @@ class BruteForceIndex:
     def ids_meta(self):
         """(ext_ids, mutations, compactions) — or None while
         empty — WITHOUT forcing the device arrays current. The
-        quantized fused tier joins/decodes against slot ids and must
-        not pay the float32 matrix re-ship that :meth:`device_view`
-        implies after a write burst. ``ext_ids`` is the generation's
-        id snapshot: one per mutation generation, shared with
-        :meth:`device_view` and :meth:`search_batch`, read-only."""
+        quantized fused tier joins/decodes against slot ids and never
+        reads the float32 device copy. ``ext_ids`` is the shared
+        read-only id table (:class:`IdTable`), the one
+        :meth:`device_lease` and :meth:`search_batch` resolve through."""
         with self._lock:
             if self._n_alive == 0 or self._matrix is None:
                 return None
             ext_ids, _ = self._ids_snapshot_locked()
             return ext_ids, self.mutations, self.compactions
 
-    def device_view(self):
+    def device_lease(self) -> _Lease:
         """Consistent device-side view for external batched kernels (the
-        fused hybrid pipeline): (matrix[C,D], valid[C], ext_ids,
-        mutations, compactions) captured atomically, or None while the
-        index is empty. The matrix/valid arrays are the same lazily
-        synced device cache ``search_batch`` dispatches against;
-        ``ext_ids`` is the generation's id snapshot: one per mutation
-        generation, shared with :meth:`ids_meta` and
-        :meth:`search_batch`, read-only, so a steady read stream
-        doesn't re-copy a capacity-sized list per batch."""
-        with self._lock:
-            if self._n_alive == 0 or self._matrix is None:
-                return None
-            m, valid = self._device_arrays_locked()
-            ext_ids, _ = self._ids_snapshot_locked()
-            return m, valid, ext_ids, self.mutations, self.compactions
+        fused hybrid pipeline, the graph plane's rank): a lease whose
+        ``view`` is (matrix[C,D], valid[C], ext_ids, mutations,
+        compactions) captured atomically, or None while the index is
+        empty. The lease HOLDS THE INDEX LOCK: dispatch the program that
+        reads the arrays, then ``release()`` (or leave the ``with``)
+        before waiting for its result. The arrays are the ones
+        ``search_batch`` scans and the next write's refresh donates them,
+        so they must not be kept past the release. ``ext_ids`` is the
+        shared read-only id table (:meth:`ids_meta`) and may be kept."""
+        lease = _Lease(self._lock)
+        lease.acquire()
+        try:
+            if self._n_alive and self._matrix is not None:
+                m, valid = self._device_arrays_locked()
+                ext_ids, _ = self._ids_snapshot_locked()
+                lease.view = (m, valid, ext_ids, self.mutations,
+                              self.compactions)
+        except BaseException:
+            lease.release()
+            raise
+        return lease
 
     def search(
         self, query: Sequence[float], k: int = 10
@@ -670,12 +870,11 @@ class BruteForceIndex:
         With ``NORNICDB_VECTOR_QUANT`` set, large corpora serve through
         the quantized coarse+exact-rerank plane instead (answers remain
         exact-rescored float32; ``exact=True`` bypasses the plane for
-        callers whose contract is exhaustive recall). Slots resolve
-        through the id snapshot of the mutation generation the scan
-        ran against: one per generation, shared with
-        :meth:`device_view` and :meth:`ids_meta`, read-only, rebuilt
-        by the first read after a write under the lock this call
-        already takes."""
+        callers whose contract is exhaustive recall). Every write
+        acknowledged before the call is in the answer: the device copy
+        is refreshed, the id table captured and the scan dispatched
+        under one hold of the index lock, so matrix, validity and ids
+        are of one generation (:meth:`_ids_snapshot_locked`)."""
         from nornicdb_tpu.obs import audit as _audit
 
         if not exact:
@@ -697,9 +896,10 @@ class BruteForceIndex:
         # it, the scan until its result is on the host, the result loop.
         # ``path`` on index.scan is the tier label that tells host NumPy
         # from the chip, which ``vector_brute_f32`` does not.
-        with _span("index.snapshot") as snap:
-            t_ask = time.perf_counter()
-            with self._lock:
+        with _Lease(self._lock) as lease:
+            with _span("index.snapshot") as snap:
+                t_ask = time.perf_counter()
+                lease.acquire()
                 snap.annotate(lock_wait_ms=round(
                     (time.perf_counter() - t_ask) * 1e3, 3))
                 if self._n_alive == 0:
@@ -724,32 +924,43 @@ class BruteForceIndex:
                         return self._search_host(
                             np.asarray(queries, np.float32), self._matrix,
                             self._valid, self._ext_ids, k_eff)
-                # one lock hold: (m, valid, ext_ids) are one generation
+                # one lock hold: (m, valid, ext_ids) are one generation,
+                # with every write acknowledged before it applied
                 m, valid = self._device_arrays_locked()
                 ext_ids, ids = self._ids_snapshot_locked()
                 snap.annotate(ids=ids)
-        pallas = _use_pallas()
-        # from the call into the jitted scan to its result on the host:
-        # the wait behind other callers' scans, the execution, D2H
-        with _span("index.scan", path="pallas" if pallas else "xla",
-                   b=len(queries), k=k_eff):
-            q = l2_normalize(jnp.asarray(queries, dtype=jnp.float32))
-            if pallas:
-                from nornicdb_tpu.ops.pallas_topk import fused_cosine_topk
+            pallas = _use_pallas()
+            # from the call into the jitted scan to its result on the
+            # host: the wait behind other callers' scans, the execution,
+            # D2H. The lock goes once the scan is DISPATCHED, not before:
+            # the next refresh donates m and valid, and the device runs
+            # what it is given in order
+            with _span("index.scan", path="pallas" if pallas else "xla",
+                       b=len(queries), k=k_eff):
+                q = l2_normalize(jnp.asarray(queries, dtype=jnp.float32))
+                if pallas:
+                    from nornicdb_tpu.ops.pallas_topk import (
+                        fused_cosine_topk,
+                    )
 
-                s, i = fused_cosine_topk(q, m, valid, k_eff)
-            else:
-                s, i = cosine_topk_auto(q, m, valid, k_eff)
-            s = np.asarray(s)
-            i = np.asarray(i)
+                    s, i = fused_cosine_topk(q, m, valid, k_eff)
+                else:
+                    s, i = cosine_topk_auto(q, m, valid, k_eff)
+                # not pinned while this thread waits for the result
+                del m, valid
+                lease.release()
+                s = np.asarray(s)
+                i = np.asarray(i)
         out: List[List[Tuple[str, float]]] = []
+        chunks = ext_ids.chunks
         with _span("index.collect"):
             for row in range(s.shape[0]):
                 hits = []
                 for col in range(s.shape[1]):
                     if s[row, col] < -1e29:
                         break
-                    eid = ext_ids[int(i[row, col])]
+                    slot = int(i[row, col])
+                    eid = chunks[slot // IDS_CHUNK][slot % IDS_CHUNK]
                     if eid is not None:
                         hits.append((eid, float(s[row, col])))
                 out.append(hits)
@@ -810,5 +1021,4 @@ class BruteForceIndex:
             idx._slot_of[eid] = i
         idx._count = n
         idx._n_alive = n
-        idx._dirty = True
         return idx

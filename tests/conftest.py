@@ -56,14 +56,22 @@ def _deadlock_watchdog():
 def benchmark_state_put_back():
     """A rehearsed run of the benchmark (``benchmark/run.py --rehearse``,
     in process) sets the deployment's environment and the trace buffer's
-    capacity; the tests that follow on this worker must not inherit
-    them."""
+    capacity, and leaves the admission controller with the waits it
+    observed and with limits read from that environment (``admission.cfg``
+    caches them for the process); neither the run nor the tests that
+    follow on this worker may inherit another's."""
+    from nornicdb_tpu import admission
     from nornicdb_tpu.obs import tracing
 
     env = dict(os.environ)
     capacity = tracing.TRACES.capacity
+    # the waits go now; the limits are dropped, NOT re-read (``reload``
+    # would cache this moment's environment): the run reads its own
+    admission.CONTROLLER.reset()
+    admission._cfg = None
     yield
     tracing.TRACES.capacity = capacity
     for key in set(os.environ) - set(env):
         del os.environ[key]
     os.environ.update(env)
+    admission.reload()

@@ -393,7 +393,9 @@ class TestShardedParity:
         snap = fh.lex._snap
         qs = PARITY_QUERIES[:4]
         embs = rng.standard_normal((len(qs), D)).astype(np.float32)
-        view = brute.device_view()
+        # no writer in this test: the arrays may outlive the lease
+        with brute.device_lease() as lease:
+            view = lease.view
         m, valid = view[0], view[1]
         l2v = fh._ensure_map(snap, view[3])
         fh.lex.refresh_alive(snap)

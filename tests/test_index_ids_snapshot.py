@@ -1,13 +1,14 @@
-"""The index's slot-to-id snapshot: one per mutation generation (ISSUE 27).
+"""The index's slot-to-id table: shared until an id moves (ISSUES 27, 32).
 
-``BruteForceIndex.search_batch``, ``device_view`` and ``ids_meta`` resolve
-slots through one shared, read-only snapshot of ``_ext_ids`` that is
-rebuilt only by the first read after a write. Pinned here, on the jitted
-scan's path (4,200 x 64 is past ``_SMALL_HOST``):
+``BruteForceIndex.search_batch``, ``device_lease`` and ``ids_meta`` resolve
+slots through one shared, read-only ``IdTable`` of ``_ext_ids``. Pinned
+here, on the jitted scan's path (4,200 x 64 is past ``_SMALL_HOST``):
 
 - reads with no write between them share one object and count ``reused``;
-- every kind of write makes the next search serve the written state and
-  count ``copied`` once;
+- every kind of write makes the next search serve the written state; an
+  overwrite of a row moves no id and the table stays shared (``reused``),
+  a new or removed id rebuilds its chunk only (``extended``), a change of
+  the slot space rebuilds the table (``copied``);
 - a snapshot captured before a slot is freed and reused keeps the old id,
   also for a search whose scan is in flight while the write lands;
 - readers beside a writer that churns ids through recycled slots only
@@ -23,7 +24,7 @@ import pytest
 
 from nornicdb_tpu import obs
 from nornicdb_tpu.search import vector_index
-from nornicdb_tpu.search.vector_index import BruteForceIndex
+from nornicdb_tpu.search.vector_index import BruteForceIndex, IdTable
 
 ROWS, DIMS = 4200, 64
 
@@ -46,33 +47,13 @@ def _index(vectors):
 
 def _counts():
     fam = obs.REGISTRY.get("nornicdb_index_ids_snapshot_total")
-    return {r: fam.labels(r).value for r in ("reused", "copied")}
+    return {r: fam.labels(r).value
+            for r in ("reused", "extended", "copied")}
 
 
 def _grown(before):
     after = _counts()
     return {r: after[r] - before[r] for r in after}
-
-
-@pytest.fixture(autouse=True)
-def device_holds_its_own_copy(monkeypatch):
-    """On the CPU backend ``jnp.asarray`` is zero-copy for a 64-byte-aligned
-    buffer, so the "device" matrix may be a live view of the host mirror
-    and a scan in flight may see a later write, which a chip's HBM copy
-    cannot. The mirror is swapped for a copy while it is shipped, so that
-    what the scan reads is what the device was given, as on the chip."""
-    inner = BruteForceIndex._device_arrays_locked
-
-    def shipped(self):
-        mirror = self._matrix, self._valid
-        if self._dirty or self._dev_matrix is None:
-            self._matrix, self._valid = mirror[0].copy(), mirror[1].copy()
-        try:
-            return inner(self)
-        finally:
-            self._matrix, self._valid = mirror
-
-    monkeypatch.setattr(BruteForceIndex, "_device_arrays_locked", shipped)
 
 
 @pytest.fixture
@@ -130,12 +111,13 @@ def _load_then_add(idx, vectors, tmp_path):
     return loaded, fresh, "after-load"
 
 
+# the write, and how the first search after it comes by its id table
 WRITES = {
-    "add_new_id": _add_new,
-    "add_over_existing_id": _add_over_existing,
-    "remove": _remove,
-    "compact": _compact,
-    "load_then_add": _load_then_add,
+    "add_new_id": (_add_new, "extended"),
+    "add_over_existing_id": (_add_over_existing, "reused"),
+    "remove": (_remove, "extended"),
+    "compact": (_compact, "copied"),
+    "load_then_add": (_load_then_add, "extended"),
 }
 
 
@@ -146,7 +128,7 @@ def _case_no_write_reuses(handed, tmp_path, monkeypatch):
     before = _counts()
     for row in (4, 5):
         assert _top(idx, vectors[row])[0][0] == f"n{row}"
-    assert _grown(before) == {"reused": 2, "copied": 0}
+    assert _grown(before) == {"reused": 2, "extended": 0, "copied": 0}
     assert handed[-1][0] is handed[-2][0] is handed[-3][0]
     assert [r for _, r in handed[-3:]] == ["copied", "reused", "reused"]
 
@@ -156,19 +138,23 @@ def _case_write(write):
         vectors = _vectors(2)
         idx = _index(vectors)
         _top(idx, vectors[0])
-        idx, query, expect = WRITES[write](idx, vectors, tmp_path)
+        do, how = WRITES[write]
+        idx, query, expect = do(idx, vectors, tmp_path)
         before = _counts()
         hits = _top(idx, query)
-        assert _grown(before) == {"reused": 0, "copied": 1}
+        want = dict.fromkeys(("reused", "extended", "copied"), 0)
+        want[how] = 1
+        assert _grown(before) == want
         if expect is None:
             assert "n8" not in {h[0] for h in hits}
             assert hits[0][1] < 0.9
         else:
             assert hits[0][0] == expect
             assert hits[0][1] == pytest.approx(1.0, abs=1e-5)
-        # read-your-writes cost one rebuild; the generation is shared again
+        # read-your-writes cost at most one chunk; the table is shared again
         _top(idx, query)
-        assert _grown(before) == {"reused": 1, "copied": 1}
+        want["reused"] += 1
+        assert _grown(before) == want
         assert handed[-1][0] is handed[-2][0]
     return case
 
@@ -177,13 +163,15 @@ def _case_captured_snapshot_keeps_freed_slot(handed, tmp_path, monkeypatch):
     vectors = _vectors(3)
     idx = _index(vectors)
     slot = idx._slot_of["n11"]
-    captured = idx.device_view()[2]
-    assert isinstance(captured, tuple)      # nobody can write it
+    with idx.device_lease() as lease:
+        captured = lease.view[2]
+    assert isinstance(captured, IdTable)
+    assert all(isinstance(c, tuple) for c in captured.chunks)  # read-only
     assert idx.remove("n11")
     idx.add("newcomer", _vectors(94, 1)[0])
     assert idx._slot_of["newcomer"] == slot     # the freed slot, reused
     assert captured[slot] == "n11"
-    assert idx.device_view()[2][slot] == "newcomer"
+    assert idx.ids_meta()[0][slot] == "newcomer"
 
 
 def _case_in_flight_search_keeps_its_generation(handed, tmp_path,
@@ -194,7 +182,8 @@ def _case_in_flight_search_keeps_its_generation(handed, tmp_path,
     scan = vector_index.cosine_topk_auto
 
     def write_lands_mid_scan(q, m, valid, k):
-        # the lock is released: a writer frees n12's slot and reuses it
+        # a writer frees n12's slot and reuses it: the write is pending,
+        # the arrays this scan was handed are not touched
         assert idx.remove("n12")
         idx.add("newcomer", newcomer)
         return scan(q, m, valid, k)
@@ -215,15 +204,24 @@ def _case_three_readers_one_object(handed, tmp_path, monkeypatch):
     vectors = _vectors(5)
     idx = _index(vectors)
     before = _counts()
-    view = idx.device_view()
+    with idx.device_lease() as lease:
+        view = lease.view
     meta = idx.ids_meta()
     _top(idx, vectors[1])
-    assert view[2] is meta[0] is handed[-1][0] is idx._view_ids_cache[1]
+    assert view[2] is meta[0] is handed[-1][0] is idx._ids_table
     assert (view[3], view[4]) == (meta[1], meta[2]) == (idx.mutations, 0)
-    assert _grown(before) == {"reused": 2, "copied": 1}
-    idx.add("n1", vectors[2])
-    assert idx.ids_meta()[0] is not meta[0]
-    assert idx.ids_meta()[0] is idx.device_view()[2]
+    assert _grown(before) == {"reused": 2, "extended": 0, "copied": 1}
+    idx.add("n1", vectors[2])           # a row overwritten: no id moved
+    assert idx.ids_meta()[0] is meta[0]
+    idx.add("one-more", vectors[3])     # one id moved: one chunk rebuilt
+    after = idx.ids_meta()[0]
+    slot = idx._slot_of["one-more"]
+    assert after is not meta[0] and after[slot] == "one-more"
+    assert [a is b for a, b in zip(after.chunks, meta[0].chunks)] \
+        == [c != slot // vector_index.IDS_CHUNK
+            for c in range(len(after.chunks))]
+    with idx.device_lease() as lease:
+        assert lease.view[2] is after
 
 
 CASES = {
@@ -248,8 +246,8 @@ def test_small_host_path_takes_no_snapshot():
     idx.add_batch([(f"n{i}", v) for i, v in enumerate(vectors)])
     before = _counts()
     assert _top(idx, vectors[2])[0][0] == "n2"
-    assert _grown(before) == {"reused": 0, "copied": 0}
-    assert idx._view_ids_cache is None
+    assert _grown(before) == {"reused": 0, "extended": 0, "copied": 0}
+    assert idx._ids_table is None
 
 
 def test_readers_beside_a_writer_recycling_slots():

@@ -135,7 +135,8 @@ class TestResourceAccounting:
         assert s["changelog_cap"] >= 4096
         # device arrays not materialized yet (small host-path corpus)
         assert s["device_bytes"] == 0
-        idx._device_arrays_locked()
+        with idx.device_lease():
+            pass
         assert idx.resource_stats()["device_bytes"] > 0
 
     def test_bm25_stats_postings_and_tombstones(self):

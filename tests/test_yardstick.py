@@ -35,6 +35,8 @@ NEEDS_CHIP = {
                              "the peaks",
     "query_encoder_busy_pct": "the query encoder's device seconds over the "
                               "window",
+    "index_update_roofline": "the update program's device seconds against "
+                             "the peak bytes/s",
 }
 # Listed by a cell and read by nothing: strict, so that the PR that mends
 # the metric takes the mark out.

@@ -1,6 +1,6 @@
 """The yardstick's controls and fault cases (``benchmark/tests/``), brought
 under tier-1's collection by name: a control reads not-correct, and so does
-a whole rehearsed run with the timed path broken underneath. The three
+a whole rehearsed run with the timed path broken underneath. The four
 "unbroken is correct" cases are ``tests/test_yardstick.py``'s per-cell
 case. A file of its own, so that ``--dist loadfile`` can run it beside the
 rehearsals and not after them.
@@ -21,6 +21,12 @@ from benchmark.tests.test_hybrid import (  # noqa: F401
     test_a_broken_fuse_is_not_correct,
     test_the_control_is_not_correct,
     test_the_reference_alone_judges_its_own_answers_correct,
+)
+from benchmark.tests.test_live import (  # noqa: F401
+    test_a_write_that_never_reaches_the_device_is_not_correct,
+    test_an_acknowledgement_before_the_apply_is_not_correct,
+    test_the_live_control_reads_above_the_limit,
+    test_the_reference_judges_answers_as_of_their_request,
 )
 
 pytestmark = pytest.mark.usefixtures("benchmark_state_put_back")
