@@ -20,9 +20,14 @@ closed it for graph ANN:
   applies the vectorized Okapi tf normalization, scatters them into a
   ``[U, C]`` matrix, multiplies into a dense ``[B, C]`` score matrix
   and takes one top-k. Batch and k pad to power-of-two buckets
-  (``microbatch.pow2_bucket``), U follows the batch bucket
-  (``LEX_TERMS_PER_QUERY``) and the number of postings is no shape at
-  all, so the XLA compile universe is (B, k).
+  (``microbatch.pow2_bucket``); U follows the batch's distinct scoring
+  terms in two steps a batch bucket (``lex_rows``: ``half`` = 8 x B,
+  no less than 16, or ``full`` = 16 x B, whichever is the smaller that
+  holds them) and the number of postings is no shape at all, so the XLA
+  compile universe is (B, k, rows in {half, full}), all of it compiled
+  before traffic by ``SearchService.warm_hybrid``. A batch with more
+  terms than ``full`` still takes the next power of two, which compiles
+  on the request that needs it (ROADMAP S8).
 - **Sharding** (``shard_map``): postings, doc vectors and the planned
   entry columns row-shard over the ``data`` mesh axis; each shard
   scores its local rows, then one all-gather + top-k merges shard-local
@@ -63,6 +68,15 @@ _LEX_C = REGISTRY.counter(
     "Device BM25 snapshot lifecycle and per-search freshness decisions",
     labels=("event",))
 
+# which row bucket each planned batch took (lex_rows): `over` is a
+# batch whose program nothing warmed
+_PLAN_ROWS_C = REGISTRY.counter(
+    "nornicdb_device_bm25_plan_rows_total",
+    "Planned lexical batches by the row bucket of their [B, U] program",
+    labels=("bucket",))
+for _bucket in ("half", "full", "over"):
+    _PLAN_ROWS_C.labels(_bucket)  # every series reads from 0
+
 declare_kind("bm25_score")
 
 
@@ -88,19 +102,43 @@ class SnapshotStale(Exception):
 # follows the postings its terms have, and no program's shape does
 LEX_CHUNK = 65536
 
-# unique-term rows a program has for each row of its batch bucket: the
-# [B, U] selection matrix and the [U+1, C] scatter are compiled at
-# U = LEX_TERMS_PER_QUERY x B (or the next power of two that holds the
-# batch's terms), so a batch bucket has ONE program as long as its
-# queries average no more than this many distinct scoring terms.
-# 16 holds every batch of queries of up to 16 terms in that one program;
-# it is not the fastest: what a dispatch costs grows with U (the
-# scatter's target, its layout conversion, the product), and at a
-# million passages U = 8 x B ran a batch of 12.8 six-term riders in 105 ms
-# where 16 x B takes 141 (v5e, PR 28; 76 queries/s against 61). The
-# smaller U needs a second warmed program a bucket for the batches that
-# overflow it: ROADMAP S12
+# unique-term rows of a program, by its batch bucket B: the [B, U]
+# selection matrix and the flat [U x C] scatter target are compiled at
+# one of TWO widths, and plan() takes the smaller that holds the batch's
+# distinct scoring terms (lex_rows, below):
+#   half  LEX_TERMS_HALF x B, no less than LEX_ROWS_MIN
+#   full  LEX_TERMS_PER_QUERY x B
+# What a dispatch costs grows with U whatever the postings are (the
+# scatter's target, its layout conversion for the product, the product
+# and the zero-fill), so the rows follow the terms: at a million passages
+# a batch of 12.8 six-term riders (~77 terms) runs in 105 ms at U = 128
+# where U = 256 takes 141 (v5e, PR 28). `half` holds a batch of queries
+# that average eight terms, `full` every batch of queries of up to
+# sixteen; both are warmed (SearchService.warm_hybrid), so which one a
+# batch takes costs no compile. Past `full` U is the next power of two
+# that holds the terms, a program nothing warmed (ROADMAP S8)
 LEX_TERMS_PER_QUERY = 16
+LEX_TERMS_HALF = 8
+LEX_ROWS_MIN = 16
+
+
+def row_buckets(b_bucket: int) -> Tuple[int, int]:
+    """(half, full): the two widths U the batch bucket has programs
+    for. At B = 1 they are one."""
+    b = max(b_bucket, 1)
+    return max(LEX_ROWS_MIN, LEX_TERMS_HALF * b), LEX_TERMS_PER_QUERY * b
+
+
+def lex_rows(n_terms: int, b_bucket: int) -> Tuple[int, str]:
+    """(U, bucket) for a batch of ``n_terms`` distinct scoring terms in
+    the batch bucket ``b_bucket``: the smaller of ``half`` and ``full``
+    that holds them, else (``over``) the next power of two."""
+    half, full = row_buckets(b_bucket)
+    if n_terms <= half:
+        return half, "half"
+    if n_terms <= full:
+        return full, "full"
+    return pow2_bucket(n_terms), "over"
 
 
 def bm25_dense_scores(
@@ -395,6 +433,7 @@ class DeviceBM25:
             "c_local": c_local,
             "built_compactions": base["compactions"],
             "vocab": base["vocab"],
+            "terms": base["terms"],
             "off_sh": off_sh,
             "post_doc": jnp.asarray(pd_all.reshape(-1)),
             "post_tf": jnp.asarray(pt_all.reshape(-1).astype(tf_dtype)),
@@ -638,10 +677,11 @@ class DeviceBM25:
         Terms are DEDUPED across the whole batch (each unique term's
         postings are walked once, however many coalesced queries share
         it) and idf comes from the incremental live-df counters, so
-        deletes correct df without a rebuild. U is
-        ``LEX_TERMS_PER_QUERY`` x the batch bucket, or the next power of
-        two that holds the terms, and nothing the size of the postings
-        is built here: the device expands the ranges."""
+        deletes correct df without a rebuild. U follows the terms
+        (``lex_rows``: the ``half`` or the ``full`` bucket of
+        ``b_bucket``, or the next power of two that holds them), and
+        nothing the size of the postings is built here: the device
+        expands the ranges."""
         vocab = snap["vocab"]
         off_sh = snap["off_sh"]
         s_n = snap["shards"]
@@ -662,8 +702,7 @@ class DeviceBM25:
                 terms.append(t)
                 idfs.append(np.float32(
                     math.log(1.0 + (n - df + 0.5) / (df + 0.5))))
-        u_b = pow2_bucket(max(len(terms),
-                              LEX_TERMS_PER_QUERY * max(b_bucket, 1)))
+        u_b, rows = lex_rows(len(terms), b_bucket)
         # the device's [U+1, C] tf-norm matrix is addressed in int32
         # (jax's default index width) — refuse to plan a batch whose
         # cell space would wrap
@@ -683,8 +722,21 @@ class DeviceBM25:
             tstart[:, : len(terms)] = off_sh[:, ti]
             tlen[:, : len(terms)] = off_sh[:, ti + 1] - off_sh[:, ti]
         self._plan_cost.shape = (int(tlen.sum()), len(terms), u_b)
+        _PLAN_ROWS_C.labels(rows).inc()
         return (tstart.reshape(-1), tlen.reshape(-1), sel,
                 np.float32(avgdl))
+
+    def rare_terms(self, snap: Dict[str, Any], n: int) -> List[str]:
+        """Up to ``n`` scoring terms of the snapshot with the fewest
+        postings: what a warm-up plans when it needs a batch of many
+        distinct terms and none of their work."""
+        plen = np.diff(snap["off_sh"], axis=1).sum(axis=0)
+        # twice as many as asked for: a term whose documents were all
+        # deleted since the build scores nothing and is planned away
+        order = np.argsort(plen, kind="stable")[: 2 * n]
+        cand = [snap["terms"][int(i)] for i in order]
+        dfs, _, _ = self.bm25.term_stats(cand)
+        return [t for t in cand if dfs.get(t, 0) > 0][:n]
 
     # -- dispatch ---------------------------------------------------------
 

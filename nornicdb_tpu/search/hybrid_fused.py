@@ -9,10 +9,13 @@ entries and emits the RRF-fused top-k, with the per-source candidate
 lists along for the ride (the service's min_score gates and result
 payloads need the raw scores).
 
-Pipeline (single compile per pow2 ``(B, k)`` bucket: the lexical
-half's unique-term rows follow B, ``device_bm25.LEX_TERMS_PER_QUERY``,
-and the number of postings a batch walks is no shape of the program;
-``SearchService.warm_hybrid`` compiles every bucket before traffic):
+Pipeline (two compiles per pow2 ``(B, k)`` bucket: the lexical half's
+unique-term rows follow the batch's distinct terms in two steps,
+``device_bm25.lex_rows``: ``half`` = 8 x B, no less than 16, and
+``full`` = 16 x B; the number of postings a batch walks is no shape of
+the program. ``SearchService.warm_hybrid`` compiles both of every
+bucket before traffic; only a batch with more terms than ``full`` takes
+a program nothing warmed, the next power of two: ROADMAP S8):
 
 1. **lexical** — ``device_bm25.bm25_dense_scores`` over the CSR
    snapshot -> top-k rows;

@@ -398,9 +398,14 @@ class SearchService:
                     max_batch: Optional[int] = None) -> List[int]:
         """Compile, before traffic needs them, every program a hybrid
         search of ``limit`` hits can dispatch: the embedder's
-        single-query shape, and one fused batch for each power-of-two
-        bucket up to ``max_batch`` riders (default: the most the hybrid
-        batcher seals), then the index's update programs
+        single-query shape, and for each power-of-two bucket up to
+        ``max_batch`` riders (default: the most the hybrid batcher
+        seals) BOTH of the bucket's fused programs (``device_bm25.
+        lex_rows``): the ``half`` one with every rider the text "warm
+        up", the ``full`` one with riders that ask, between them, for
+        more distinct terms than ``half`` holds, taken from the
+        snapshot's rarest so that the batch walks next to no postings.
+        Then the index's update programs
         (``BruteForceIndex.warm_updates``), so a store after the warm-up
         compiles nothing either. The lexical snapshot is built first,
         inline, and the first batch ships the vector matrix to the
@@ -410,6 +415,7 @@ class SearchService:
         nothing else changes (no result is cached, no counter of served
         searches moves)."""
         from nornicdb_tpu.config import env_bool, env_int
+        from nornicdb_tpu.search.device_bm25 import row_buckets
         from nornicdb_tpu.search.microbatch import pow2_bucket
 
         with self._lock:
@@ -423,20 +429,37 @@ class SearchService:
         if qv is None:
             qv = np.ones((dims,), np.float32)
         overfetch = max(limit * 3, 30)
-        extra = {"tokens": tuple(tokenize("warm up")),
-                 "n_cand": overfetch, "w": (1.0, 1.0)}
+        kq = pow2_bucket(overfetch)
+
+        def rider(tokens):
+            return {"tokens": tuple(tokens), "n_cand": overfetch,
+                    "w": (1.0, 1.0)}
+
+        few = rider(tokenize("warm up"))
         top = self._hybrid_batch.max_batch if max_batch is None \
             else max_batch
-        warmed: List[int] = []
+        buckets: List[int] = []
         b = 1
         while b <= pow2_bucket(max(top, 1)):
-            fused.search_batch(np.tile(qv, (b, 1)), pow2_bucket(overfetch),
-                               [extra] * b)
-            warmed.append(b)
+            buckets.append(b)
             b *= 2
+        # the `full` program of a bucket is asked for by one term more
+        # than its `half` holds, dealt round the riders; a snapshot with
+        # no more terms than that has, until it is rebuilt, no batch
+        # that could ask for it
+        rare = fused.lex.rare_terms(
+            fused.lex.ensure_snapshot(), row_buckets(buckets[-1])[0] + 1)
+        for b in buckets:
+            qs = np.tile(qv, (b, 1))
+            fused.search_batch(qs, kq, [few] * b)
+            half, full = row_buckets(b)
+            many = rare[: half + 1]
+            if half < len(many) <= full:
+                fused.search_batch(
+                    qs, kq, [rider(many[i::b]) for i in range(b)])
         # and the programs that write later stores into the device copy
         self.vectors.warm_updates()
-        return warmed
+        return buckets
 
     def _fused_hybrid_trio(self, query, qv, overfetch, weights):
         """One coalesced fused-hybrid ride: (lex, vec, fused) candidate

@@ -12,6 +12,7 @@ import io
 import json
 import os
 import sys
+import time
 from contextlib import redirect_stdout
 
 import numpy as np
@@ -233,6 +234,13 @@ def test_store_batch_equals_store_once_a_node(two_stores):
     bulk.flush()
     assert bulk._embed_queue.has_vector(ids[11])
     assert a.vectors.get(ids[11]) is not None
+    # the fused tier builds in the background and the host serves until
+    # it is there; the two tiers' scores differ in their last bits, so
+    # both stores answer from the same one: wait for both builds
+    deadline = time.time() + 60
+    while (a._ensure_fused() is None or b._ensure_fused() is None) \
+            and time.time() < deadline:
+        time.sleep(0.01)
     rng = np.random.default_rng(22)
     for _ in range(100):
         row = int(rng.integers(0, len(ids)))
