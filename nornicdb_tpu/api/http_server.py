@@ -1338,6 +1338,17 @@ class HttpServer:
             if len(segments) == 3 and segments[2] == "aliases" \
                     and method == "GET":
                 return ok({"aliases": q.list_aliases(name)})
+            if len(segments) >= 3 and segments[2] == "index":
+                # payload index: the fields a search's filter is
+                # evaluated on by the scan itself (docs/qdrant_compat.md)
+                if method == "PUT" and len(segments) == 3:
+                    return ok({"operation_id": int(q.create_payload_index(
+                        name, payload.get("field_name", ""),
+                        payload.get("field_schema"))),
+                        "status": "completed"})
+                if method == "DELETE" and len(segments) == 4:
+                    return ok({"operation_id": int(q.delete_payload_index(
+                        name, segments[3])), "status": "completed"})
             if len(segments) >= 3 and segments[2] == "snapshots":
                 snap_dir = self._qdrant_snapshot_dir()
                 if method == "POST" and len(segments) == 3:

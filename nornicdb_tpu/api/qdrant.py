@@ -32,8 +32,16 @@ from nornicdb_tpu.obs import declare_kind as _obs_declare_kind
 from nornicdb_tpu.obs import record_dispatch as _obs_record_dispatch
 from nornicdb_tpu.obs import span as _obs_span
 from nornicdb_tpu.obs import tenant as _tenant
+from nornicdb_tpu.obs.metrics import REGISTRY as _REGISTRY
 from nornicdb_tpu.ops.similarity import pow2_bucket
-from nornicdb_tpu.search.vector_index import BruteForceIndex
+from nornicdb_tpu.search.vector_index import (
+    COLUMN_SCHEMAS,
+    KIND_FILTERED,
+    MAX_COLUMNS,
+    BruteForceIndex,
+    StaleFilterPlan,
+    open_bounds,
+)
 from nornicdb_tpu.storage.types import Node, now_ms
 
 _META_PREFIX = "qdrant-meta/"
@@ -63,6 +71,28 @@ def _point_node_id(collection: str, point_id: Any) -> str:
 _CONVOY_SEQ = itertools.count(1)
 # a search's own widening dispatch (b=1, outside the collection's batcher)
 _obs_declare_kind("vector_widen")
+# where a search that carried a filter had it evaluated: by the scan on the
+# device (a conjunction of match.value / range conditions on indexed
+# fields), on the host after an unfiltered scan (everything else), or
+# nowhere (`empty`: the plan shows that no point can pass)
+_FILTERED_C = _REGISTRY.counter(
+    "nornicdb_qdrant_filtered_search_total",
+    "Qdrant searches that carried a filter, by where it was evaluated",
+    labels=("tier",))
+# a payload index is filled from storage in blocks of this many points
+_INDEX_FILL_BLOCK = 16384
+_RANGE_OPS = ("gt", "gte", "lt", "lte")
+
+
+def _batch_kind(extras):
+    """(dispatch kind, span attrs) of a sealed batch, from its riders'
+    plans (`MicroBatcher`'s ``batch_kind``): one rider with bounds makes
+    it a batch of the filtered program."""
+    plans = [e for e in extras if e is not None]
+    if not plans:
+        return "microbatch", {}
+    return KIND_FILTERED, {"filtered": len(plans),
+                           "fields": int(plans[0][1].shape[0])}
 
 
 class QdrantCompat:
@@ -121,6 +151,8 @@ class QdrantCompat:
         register_resource("queue", self._convoy_resource_name,
                           self._upsert_coalescer)
         self._lock = threading.Lock()
+        # one payload index is created or dropped at a time
+        self._index_ddl = threading.Lock()
         # depth of in-progress writes by THIS layer (thread-local): its
         # own storage writes already maintain the indexes incrementally,
         # so the external-mutation listener must ignore them
@@ -261,7 +293,76 @@ class QdrantCompat:
             "config": {
                 "params": {"vectors": meta.properties.get("config", {})},
             },
+            "payload_schema": {
+                field: {"data_type": schema, "points": len(self._index(name))}
+                for field, schema in
+                (meta.properties.get("payload_schema") or {}).items()},
         }
+
+    # -- payload index ---------------------------------------------------
+
+    def create_payload_index(self, name: str, field: str,
+                             schema: Any) -> bool:
+        """PUT /collections/{name}/index: a payload field the scan can
+        filter on (``integer`` or ``keyword``). The field's values become
+        an int32 column beside the vectors (`BruteForceIndex`), filled
+        here from what is stored and kept by every later write; the
+        declaration lives in the collection's meta node, so a restart
+        rebuilds the column with the index."""
+        name = self.resolve(name)
+        if isinstance(schema, dict):    # {"type": "keyword", "is_tenant": ..}
+            schema = schema.get("type")
+        if not field or not isinstance(field, str):
+            raise QdrantError("field_name is required")
+        if schema not in COLUMN_SCHEMAS:
+            raise QdrantError(
+                f"field_schema {schema!r}: the payload index takes "
+                f"{list(COLUMN_SCHEMAS)}")
+        with self._index_ddl:
+            meta = self._meta(name)
+            declared = dict(meta.properties.get("payload_schema") or {})
+            if field not in declared and len(declared) >= MAX_COLUMNS:
+                raise QdrantError(f"at most {MAX_COLUMNS} indexed payload "
+                                  f"fields a collection")
+            idx = self._index(name)
+            try:
+                idx.declare_column(field, schema)
+            except ValueError as exc:
+                raise QdrantError(str(exc))
+            if field not in idx.columns():
+                # the rows that were there first: their payloads once
+                ids = idx.ids()
+                for lo in range(0, len(ids), _INDEX_FILL_BLOCK):
+                    idx.fill_column(field, ids[lo:lo + _INDEX_FILL_BLOCK],
+                                    self._payloads_of)
+                idx.publish_column(field)
+            if declared.get(field) != schema:
+                declared[field] = schema
+                meta.properties["payload_schema"] = declared
+                with self._own_write():
+                    self.storage.update_node(meta)
+        self._clear_search_cache()
+        return True
+
+    def delete_payload_index(self, name: str, field: str) -> bool:
+        """DELETE /collections/{name}/index/{field}. Filters on the field
+        are evaluated on the host again."""
+        name = self.resolve(name)
+        with self._index_ddl:
+            meta = self._meta(name)
+            declared = dict(meta.properties.get("payload_schema") or {})
+            self._index(name).drop_column(field)
+            if field in declared:
+                del declared[field]
+                meta.properties["payload_schema"] = declared
+                with self._own_write():
+                    self.storage.update_node(meta)
+        self._clear_search_cache()
+        return True
+
+    def _payloads_of(self, node_ids: Sequence[str]) -> List[Optional[dict]]:
+        return [None if n is None else n.properties.get("payload")
+                for n in self.storage.batch_get_nodes(node_ids)]
 
     def _meta(self, name: str) -> Node:
         try:
@@ -408,6 +509,7 @@ class QdrantCompat:
             "version": "nornicdb-tpu-qdrant-1",
             "collection": name,
             "config": meta.properties.get("config", {}),
+            "payload_schema": meta.properties.get("payload_schema") or {},
             "points": points,
         }
 
@@ -517,6 +619,8 @@ class QdrantCompat:
         if self.storage.has_node(_META_PREFIX + name):
             self.delete_collection(name)
         self.create_collection(name, payload.get("config") or None)
+        for field, schema in (payload.get("payload_schema") or {}).items():
+            self.create_payload_index(name, field, schema)
         if preserved:
             with self._lock:
                 aliases = self._alias_map()
@@ -536,12 +640,17 @@ class QdrantCompat:
             if space is not None and space.index is not None:
                 return space.index
         # lazy rebuild from storage (post-restart)
-        self._meta(name)  # raises if collection doesn't exist
+        meta = self._meta(name)  # raises if collection doesn't exist
         idx = BruteForceIndex()
+        # the declared payload index with it: nobody reads this index
+        # before it is whole, so its columns are ready from the start
+        for field, schema in (
+                meta.properties.get("payload_schema") or {}).items():
+            idx.declare_column(field, schema, ready=True)
         for node in self.storage.get_nodes_by_label(self._label(name)):
             vec = node.properties.get("_vector")
             if vec:
-                idx.add(node.id, vec)
+                idx.add(node.id, vec, node.properties.get("payload"))
         with self._lock:
             space = self._space(name)
             if space.index is None:
@@ -599,6 +708,9 @@ class QdrantCompat:
         fresh = 0
         ids: List[str] = []
         vecs: List[List[float]] = []
+        # an indexed field's code goes in with the row, in the same index
+        # call and lock hold
+        payloads: Optional[List[dict]] = [] if idx.has_columns else None
         with _obs_span("qdrant.upsert", points=len(points)) as up, \
                 self._own_write():
             with _obs_span("upsert.storage"):
@@ -621,10 +733,15 @@ class QdrantCompat:
                     if vec:
                         ids.append(nid)
                         vecs.append(vec)
+                        if payloads is not None:
+                            payloads.append(p.get("payload") or {})
+                    elif payloads is not None:
+                        idx.set_payload(nid, p.get("payload") or {})
                     n += 1
             if ids:
                 with _obs_span("upsert.index", rows=len(ids)):
-                    idx.add_matrix(ids, np.asarray(vecs, np.float32))
+                    idx.add_matrix(ids, np.asarray(vecs, np.float32),
+                                   payloads)
             up.annotate(new=fresh, overwritten=n - fresh)
         if n:
             self._invalidate_raw(name)
@@ -803,8 +920,27 @@ class QdrantCompat:
                 f"search vector size {len(vector)} != collection "
                 f"size {want}")
         distance = meta.properties.get("config", {}).get("distance", "Cosine")
+        plan = None
+        if query_filter is not None:
+            with _obs_span("qdrant.filter_plan") as sp:
+                conds = _device_conditions(query_filter)
+                if conds and distance == "Cosine":
+                    idx = self._index(name)
+                    # the graph ANN rung takes no bounds (nor do the
+                    # quantised and tiered ones: the index says)
+                    if self._ann_search_index(name) is idx:
+                        plan = idx.filter_bounds(conds)
+                tier = "host" if plan is None else \
+                    "empty" if plan == "empty" else "device"
+                sp.annotate(tier=tier, conds=len(conds or ()),
+                            fields=len({c[0] for c in conds or ()}))
+            _FILTERED_C.labels(tier).inc()
+            if tier == "empty":
+                # no point can pass: no scan
+                return self._search_cache.put_guarded(cache_key, [],
+                                                      gen_at_miss)
         if distance == "Cosine":
-            ranked = self._ranked_cosine(name, vector, limit)
+            ranked = self._ranked_cosine(name, vector, limit, plan)
         else:
             ranked = self._ranked_raw(name, vector, distance)
         # the rank generator runs lazily inside the loop below, so this
@@ -866,8 +1002,12 @@ class QdrantCompat:
             mb = self._microbatchers.get(name)
             if mb is None:
                 mb = MicroBatcher(
-                    lambda queries, k, _n=name:
-                        self._ann_search_index(_n).search_batch(queries, k),
+                    lambda queries, k, plans, _n=name:
+                        self._search_batch(_n, queries, k, plans),
+                    # a rider's extra is its filter plan (or None):
+                    # riders with different filters and with none seal
+                    # into one batch
+                    pass_extras=True, batch_kind=_batch_kind,
                     # one bounded stage label for ALL collections — the
                     # per-collection split lives in the resource gauges,
                     # not in histogram label cardinality
@@ -881,6 +1021,26 @@ class QdrantCompat:
 
                 register_resource("queue", f"qdrant:{name}", mb)
             return mb
+
+    def _search_batch(self, name: str, queries, k: int, plans):
+        """One sealed batch of the collection's coalescer. No rider with
+        a filter plan: the plain program with the plain arguments, through
+        whichever rung serves the collection. Otherwise the brute index's
+        filtered scan, the riders' bounds stacked beside their vectors
+        and the riders without a plan under open bounds."""
+        planned = [p for p in plans if p is not None]
+        if not planned:
+            return self._ann_search_index(name).search_batch(queries, k)
+        gen, first = planned[0]
+        if any(p[0] != gen for p in planned):
+            raise StaleFilterPlan("riders planned against different "
+                                  "payload columns")
+        bounds = open_bounds(len(plans), first.shape[0])
+        for row, p in enumerate(plans):
+            if p is not None:
+                bounds[row] = p[1]
+        return self._index(name).search_batch(queries, k, bounds=bounds,
+                                              bounds_gen=gen)
 
     def _ann_search_index(self, name: str):
         """The index the coalesced batches dispatch to: the collection's
@@ -950,7 +1110,7 @@ class QdrantCompat:
             pass
 
     def _ranked_cosine(self, name: str, vector: Sequence[float],
-                       limit: int):
+                       limit: int, plan=None):
         """Yield (node_id, cosine) best-first, progressively widening the
         kNN so selective filters still fill `limit` (a fixed 4x
         oversample starves on rare payloads).
@@ -969,7 +1129,13 @@ class QdrantCompat:
         Widening rounds (a selective filter, a hit whose node is gone,
         an ANN first round that under-filled, a `limit` over the bound)
         go direct, `k *= 4` — their k varies too much to bucket
-        usefully."""
+        usefully.
+
+        With a filter ``plan`` (`BruteForceIndex.filter_bounds`) every
+        round carries the plan's bounds and the scan itself ranks among
+        the points that pass: a round that comes back short has seen every
+        one of them, so a planned filter is answered by the one coalesced
+        scan and never widens to fill `limit`."""
         idx = self._index(name)
         total = len(idx)
         k = min(max(_FIRST_K_MIN, limit), _FIRST_K_MAX)
@@ -984,15 +1150,25 @@ class QdrantCompat:
         while True:
             k_req = min(k, total) if total else k
             if first:
-                hits = self._collection_microbatch(name).search(q, k_req)
-                self._maybe_shadow_vector(idx, q, k_req, hits)
+                try:
+                    hits = self._collection_microbatch(name).search(
+                        q, k_req, plan)
+                except StaleFilterPlan:
+                    # an index on a field came or went under the plan:
+                    # the unplanned path, filtered on the host
+                    plan = None
+                    hits = self._collection_microbatch(name).search(
+                        q, k_req)
                 first = False
                 # a short FIRST round is not exhaustion: the ANN wrapper
                 # (cagra) live-filters rows deleted since its build, so
                 # it can return < k while thousands of live rows remain.
                 # Widening rounds query the brute index directly and ARE
-                # authoritative.
-                ann_round = True
+                # authoritative, and so is a round with a plan (the
+                # filtered scan is the brute index's own).
+                ann_round = plan is None
+                if ann_round:
+                    self._maybe_shadow_vector(idx, q, k_req, hits)
             else:
                 # the request's own b=1 dispatch, outside the coalescer:
                 # its own span and dispatch kind (never `device.dispatch`
@@ -1001,7 +1177,12 @@ class QdrantCompat:
                 widen_round += 1
                 t_widen = time.perf_counter()
                 with _obs_span("qdrant.widen", k=k_req, round=widen_round):
-                    hits = idx.search(q, k=k_req)
+                    if plan is None:
+                        hits = idx.search(q, k=k_req)
+                    else:
+                        hits = idx.search_batch(
+                            q[None, :], k_req, bounds=plan[1][None],
+                            bounds_gen=plan[0])[0]
                 _obs_record_dispatch("vector_widen", 1, pow2_bucket(k_req),
                                      time.perf_counter() - t_widen)
                 ann_round = False
@@ -1090,6 +1271,37 @@ class QdrantCompat:
         if with_vector:
             d["vector"] = node.properties.get("_vector") or []
         return d
+
+
+def _device_conditions(flt: Any):
+    """The filter as a conjunction of ``(key, op, value)`` conditions the
+    scan can evaluate on payload columns (``op``: ``eq`` for
+    ``match.value``, else one of ``gt``/``gte``/``lt``/``lte``), or
+    ``None`` when it is anything else: a `should`, a `must_not`, a nested
+    filter, `match.any`/`text`, `has_id`, `is_null`, `is_empty`, a
+    condition without a key. Whether the keys are indexed and the values
+    of the columns' types is the index's to say
+    (`BruteForceIndex.filter_bounds`); what it cannot take goes down the
+    host path as it always has."""
+    if not isinstance(flt, dict) or set(flt) != {"must"} \
+            or not isinstance(flt["must"], list) or not flt["must"]:
+        return None
+    conds = []
+    for cond in flt["must"]:
+        if not isinstance(cond, dict) or not isinstance(
+                cond.get("key"), str):
+            return None
+        match, rng = cond.get("match"), cond.get("range")
+        if set(cond) == {"key", "match"} and isinstance(match, dict) \
+                and set(match) == {"value"}:
+            conds.append((cond["key"], "eq", match["value"]))
+        elif set(cond) == {"key", "range"} and isinstance(rng, dict) \
+                and rng and set(rng) <= set(_RANGE_OPS):
+            conds.extend((cond["key"], op, rng[op]) for op in _RANGE_OPS
+                         if op in rng)
+        else:
+            return None
+    return conds
 
 
 def _match_filter(payload: Dict[str, Any], flt: Dict[str, Any],

@@ -71,7 +71,9 @@ IMPORT_TIME_MODULES = (
     "nornicdb_tpu.api.qdrant",
     "nornicdb_tpu.storage.memory",
     # ISSUE 32: the brute index's refresh and ship-bytes counters, the
-    # id table's `extended`, the `index_update` dispatch kind
+    # id table's `extended`, the `index_update` dispatch kind; ISSUE 34:
+    # the `vector_filtered` dispatch kind, and in api.qdrant above the
+    # filtered-search counter
     "nornicdb_tpu.search.vector_index",
 )
 

@@ -60,6 +60,7 @@ _INDEX_GAUGES: Tuple[Tuple[str, str], ...] = (
     ("nornicdb_index_resident_partitions", "resident_partitions"),
     ("nornicdb_index_tiered_device_bytes", "tiered_device_bytes"),
     ("nornicdb_index_disk_bytes", "disk_bytes"),
+    ("nornicdb_index_payload_bytes", "payload_index_bytes"),
 )
 
 _HELP = {
@@ -93,6 +94,9 @@ _HELP = {
         "Device bytes of the tiered plane's resident PQ slabs",
     "nornicdb_index_disk_bytes":
         "On-disk bytes of the cold partition spill store",
+    "nornicdb_index_payload_bytes":
+        "Device bytes of the index's payload columns (counted in "
+        "device_bytes too)",
 }
 
 _lock = threading.Lock()

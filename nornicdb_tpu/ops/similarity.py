@@ -126,6 +126,96 @@ def _cosine_topk_chunked_impl(
     return best_s, best_i
 
 
+# codes of a payload column (``BruteForceIndex``): a bounded condition never
+# matches the first two, open bounds match every code
+COL_MISSING = -(1 << 31)       # the point has no such field
+COL_UNCODED = COL_MISSING + 1  # it has a value the column cannot hold
+COL_LO = COL_MISSING + 2
+COL_HI = (1 << 31) - 1
+
+
+def _bounds_mask(cols: jnp.ndarray, bounds: jnp.ndarray) -> jnp.ndarray:
+    """``[B, n]`` bool: row of ``cols [F, n]`` by rider of ``bounds
+    [B, F, 2]``, true where every field's code lies in the rider's
+    inclusive ``[lo, hi]``."""
+    lo = bounds[:, :, 0, None]
+    hi = bounds[:, :, 1, None]
+    return jnp.all((cols[None] >= lo) & (cols[None] <= hi), axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "chunk"))
+def _cosine_topk_filtered_impl(
+    queries: jnp.ndarray,  # [B, D] (normalized)
+    matrix: jnp.ndarray,  # [C, D]
+    valid: jnp.ndarray,  # [C] bool
+    columns: jnp.ndarray,  # [F, C] int32 payload codes, slot-aligned
+    bounds: jnp.ndarray,  # [B, F, 2] int32, inclusive, per rider
+    k: int,
+    chunk: int,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``_cosine_topk_chunked_impl`` with a mask a rider: rider ``b`` sees
+    row ``c`` when ``valid[c]`` and ``bounds[b, f, 0] <= columns[f, c] <=
+    bounds[b, f, 1]`` for every field ``f``. Every row is scored; a row a
+    rider may not see scores ``NEG_INF`` for that rider. ``chunk`` equal to
+    the capacity is the dense scan."""
+    b = queries.shape[0]
+    c = matrix.shape[0]
+    f = columns.shape[0]
+    if chunk >= c:
+        s = jnp.matmul(queries, matrix.T, precision=EXACT)
+        s = jnp.where(valid[None, :] & _bounds_mask(columns, bounds), s,
+                      NEG_INF)
+        return jax.lax.top_k(s, k)
+
+    def step(carry, i):
+        best_s, best_i = carry
+        rows = jax.lax.dynamic_slice_in_dim(matrix, i * chunk, chunk, axis=0)
+        vmask = jax.lax.dynamic_slice_in_dim(valid, i * chunk, chunk, axis=0)
+        cols = jax.lax.dynamic_slice(columns, (0, i * chunk), (f, chunk))
+        s = jnp.matmul(queries, rows.T, precision=EXACT)  # [B, chunk]
+        s = jnp.where(vmask[None, :] & _bounds_mask(cols, bounds), s,
+                      NEG_INF)
+        idx = i * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        cat_s = jnp.concatenate([best_s, s], axis=1)
+        cat_i = jnp.concatenate([best_i, jnp.broadcast_to(idx, (b, chunk))], axis=1)
+        top_s, pos = jax.lax.top_k(cat_s, k)
+        top_i = jnp.take_along_axis(cat_i, pos, axis=1)
+        return (top_s, top_i), None
+
+    init = (
+        jnp.full((b, k), NEG_INF, dtype=queries.dtype),
+        jnp.zeros((b, k), dtype=jnp.int32),
+    )
+    (best_s, best_i), _ = jax.lax.scan(
+        step, init, jnp.arange(c // chunk, dtype=jnp.int32)
+    )
+    return best_s, best_i
+
+
+def cosine_topk_filtered(
+    queries: jnp.ndarray,
+    matrix: jnp.ndarray,
+    valid: jnp.ndarray,
+    columns: jnp.ndarray,
+    bounds: jnp.ndarray,
+    k: int,
+    chunk: int = 16384,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Exact cosine top-k among the rows each rider's bounds let through,
+    routed as ``cosine_topk_auto`` routes the plain scan: dense up to
+    ``CHUNKED_THRESHOLD`` rows (and for a capacity no power-of-two chunk
+    divides), chunked above."""
+    c = matrix.shape[0]
+    k = min(k, c)
+    if c > CHUNKED_THRESHOLD:
+        while c % chunk != 0 and chunk >= 512:
+            chunk //= 2
+    if c <= CHUNKED_THRESHOLD or c % chunk != 0:
+        chunk = c
+    return _cosine_topk_filtered_impl(queries, matrix, valid, columns,
+                                      bounds, k, chunk)
+
+
 # above this row count, route to the chunked kernel to bound HBM
 CHUNKED_THRESHOLD = 262_144
 

@@ -269,7 +269,8 @@ class _Item:
 class _Req:
     __slots__ = ("vec", "k", "extra", "done", "result", "error",
                  "dispatch_t0", "dispatch_t1", "batch_size", "tier",
-                 "lane", "deadline", "t_enq", "early", "tenant")
+                 "lane", "deadline", "t_enq", "early", "tenant",
+                 "batch_attrs")
 
     def __init__(self, vec: np.ndarray, k: int, extra: Any = None):
         self.vec = vec
@@ -298,6 +299,13 @@ class _Req:
         # tenant captured at enqueue (ISSUE 18): the batch leader binds
         # the riders' mix so the padded-dispatch cost splits per tenant
         self.tenant: "str | None" = None
+        # what the leader's ``batch_kind`` said of the batch's extras,
+        # for this rider's ``device.dispatch`` span
+        self.batch_attrs: dict = {}
+
+
+def _plain_kind(extras: Sequence[Any]) -> Tuple[str, dict]:
+    return "microbatch", {}
 
 
 class MicroBatcher:
@@ -316,8 +324,18 @@ class MicroBatcher:
         truncate: bool = True,
         surface: str = "search",
         tier_surface: "str | None" = None,
+        batch_kind: "Callable[[Sequence[Any]], Tuple[str, dict]] | None"
+        = None,
     ):
         self._search_batch = search_batch
+        # batch_kind(extras of the riders) -> (dispatch kind, span attrs):
+        # a batcher whose riders' extras decide which program a batch
+        # runs (the Qdrant surface: riders with and without filter bounds
+        # seal together, and a batch with bounds runs the filtered scan)
+        # records each batch under the kind of the program it ran, so
+        # nornicdb_device_dispatch_* and admission's predict_ms keep the
+        # programs apart. None: every batch is ``microbatch``.
+        self._batch_kind = batch_kind or _plain_kind
         self._max_batch = max_batch
         # bounded stage-attribution label (code-chosen per batcher role:
         # "service:vector", "service:hybrid", "qdrant", ...) for the
@@ -386,7 +404,7 @@ class MicroBatcher:
         # queue-wait-only.
         if dl is not None:
             _adm.CONTROLLER.cost_check(
-                self._surface, "microbatch",
+                self._surface, self._batch_kind([extra])[0],
                 pow2_bucket(max(min(self._last_batch, self._max_batch),
                                 1)),
                 lane, now=t_enq)
@@ -517,7 +535,8 @@ class MicroBatcher:
         wait_attrs: dict = {"surface": self._surface,
                             "batch": req.batch_size, "lane": req.lane}
         disp_attrs: dict = {"surface": self._surface,
-                            "batch": req.batch_size, "k": req.k}
+                            "batch": req.batch_size, "k": req.k,
+                            **req.batch_attrs}
         if req.deadline is not None:
             # the budget at the dispatch decision (ISSUE 15 acceptance:
             # a trace shows the deadline at ingress, ring crossing and
@@ -567,6 +586,7 @@ class MicroBatcher:
                 pad = np.broadcast_to(
                     queries[0], (bucket - b,) + queries.shape[1:])
                 queries = np.concatenate([queries, pad], axis=0)
+            kind, attrs = self._batch_kind([r.extra for r in batch])
             t0 = time.time()
             _audit.consume_batch_tier()  # clear any stale leader note
             # bind the riders' tenant mix around the dispatch (18): the
@@ -576,7 +596,7 @@ class MicroBatcher:
             # device completion — the measured wall seconds then split
             # across the same rider mix
             with _tenant.batch_scope([r.tenant for r in batch]):
-                with _device.dispatch_scope("microbatch"):
+                with _device.dispatch_scope(kind):
                     # the inner plane prices the PADDED array; the
                     # padding-efficiency join needs the rider count
                     _device.note_real_rows(float(b))
@@ -592,11 +612,12 @@ class MicroBatcher:
                     _device.maybe_sync(results)
                     t1 = time.time()
                 tier = _audit.consume_batch_tier()
-                record_dispatch("microbatch", bucket, k_max, t1 - t0)
+                record_dispatch(kind, bucket, k_max, t1 - t0)
             for r, res in zip(batch, results):
                 r.dispatch_t0, r.dispatch_t1 = t0, t1
                 r.batch_size = b
                 r.tier = tier
+                r.batch_attrs = attrs
                 if self._truncate:
                     r.result = res[: r.k] if r.k < k_max else res
                 else:
@@ -617,11 +638,12 @@ class MicroBatcher:
                     continue
                 try:
                     kb = pow2_bucket(max(r.k, 1))
+                    kind, r.batch_attrs = self._batch_kind([r.extra])
                     r.dispatch_t0 = time.time()
                     q1 = np.asarray(r.vec, np.float32)[None, :]
                     _audit.consume_batch_tier()
                     with _tenant.batch_scope([r.tenant]):
-                        with _device.dispatch_scope("microbatch"):
+                        with _device.dispatch_scope(kind):
                             if self._pass_extras:
                                 res = self._search_batch(q1, kb,
                                                          [r.extra])[0]
@@ -631,7 +653,7 @@ class MicroBatcher:
                             r.dispatch_t1 = time.time()
                         r.tier = _audit.consume_batch_tier()
                         r.batch_size = 1
-                        record_dispatch("microbatch", 1, kb,
+                        record_dispatch(kind, 1, kb,
                                         r.dispatch_t1 - r.dispatch_t0)
                     if self._truncate:
                         r.result = res[: r.k] if r.k < kb else res

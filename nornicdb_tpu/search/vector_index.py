@@ -21,6 +21,8 @@ an update reads the old contents.
 from __future__ import annotations
 
 import functools
+import json
+import math
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -35,11 +37,17 @@ from nornicdb_tpu.obs.metrics import REGISTRY
 from nornicdb_tpu.obs.tracing import span as _span
 from nornicdb_tpu.ops.similarity import (
     CHUNKED_THRESHOLD,
+    COL_HI,
+    COL_LO,
+    COL_MISSING,
+    COL_UNCODED,
     cosine_topk,
     cosine_topk_auto,
     cosine_topk_chunked,
+    cosine_topk_filtered,
     l2_normalize,
     pad_dim,
+    pow2_bucket,
 )
 
 
@@ -68,6 +76,17 @@ _SHIP_BYTES_C = REGISTRY.counter(
 KIND_UPDATE = "index_update"
 declare_kind(KIND_UPDATE)
 
+# the filtered scan (a batch with at least one rider that carries bounds)
+# under its own kind: admission's predict_ms does not mix it with
+# ``microbatch``, whose batches run the plain program
+KIND_FILTERED = "vector_filtered"
+declare_kind(KIND_FILTERED)
+
+# a collection indexes at most this many payload fields: the column stack
+# is [F, capacity] with F the power-of-two bucket of their number (1, 2, 4)
+MAX_COLUMNS = 4
+COLUMN_SCHEMAS = ("integer", "keyword")
+
 # pending rows are applied in rounds padded to the smallest bucket that
 # holds them, so a (capacity, dims) has three update programs whatever
 # the writers do; more than the largest takes several rounds
@@ -88,6 +107,39 @@ def index_update(matrix, valid, slots, rows, vals):
     they are given (both donated: the caller's references are dead when
     this returns). ``slots`` may repeat a slot, with the same row."""
     return matrix.at[slots].set(rows), valid.at[slots].set(vals)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+def index_update_columns(matrix, valid, columns, slots, rows, vals, codes):
+    """``index_update`` for an index with payload columns: the rows'
+    codes ``[F, n]`` go into ``columns[:, slots]`` in the same program, so
+    a reader never sees a row of one write beside the codes of another."""
+    return (matrix.at[slots].set(rows), valid.at[slots].set(vals),
+            columns.at[:, slots].set(codes))
+
+
+class StaleFilterPlan(RuntimeError):
+    """Bounds were planned against a set of payload columns the index no
+    longer has (an index on a field was created or dropped in between)."""
+
+
+def open_bounds(*shape: int) -> np.ndarray:
+    """Bounds ``[*shape, 2]`` that let every code through."""
+    bounds = np.empty(shape + (2,), np.int32)
+    bounds[..., 0], bounds[..., 1] = COL_MISSING, COL_HI
+    return bounds
+
+
+def payload_value(payload, key: str):
+    """``(found, value)`` of a dotted ``key`` in a payload, walked as the
+    host filter walks it (``api/qdrant._match_condition``)."""
+    value = payload
+    for part in str(key).split("."):
+        if isinstance(value, dict) and part in value:
+            value = value[part]
+        else:
+            return False, None
+    return True, value
 
 
 def _ship(host: np.ndarray):
@@ -204,6 +256,21 @@ class BruteForceIndex:
         self._dev_matrix = None
         self._dev_valid = None
         self._pending: set = set()
+        # payload columns (``declare_column``): for each indexed field one
+        # int32 code a slot, slot-aligned with the matrix, written under
+        # the index lock with the row and carried to the device by the
+        # same refresh. Row f of the stack is ``_col_fields[f]``; the stack
+        # has pow2_bucket(len(fields)) rows, the spare ones all MISSING.
+        # A field serves filters once it is ready (filled) and while no
+        # live row holds a value its column cannot code (``_col_uncoded``).
+        self._col_fields: List[str] = []
+        self._col_schema: Dict[str, str] = {}
+        self._col_dict: Dict[str, Dict[str, int]] = {}
+        self._col_uncoded: Dict[str, int] = {}
+        self._col_ready: set = set()
+        self._col_gen = 0  # bumped when the set of fields changes
+        self._columns: Optional[np.ndarray] = None  # [F, cap] int32
+        self._dev_columns = None
         # the slot-to-id table readers share (_ids_snapshot_locked) and
         # the chunks of it in which a write has moved an id since
         self._ids_table: Optional[IdTable] = None
@@ -259,6 +326,11 @@ class BruteForceIndex:
         if self._matrix is not None:
             new_m[: self._capacity] = self._matrix
             new_v[: self._capacity] = self._valid
+        if self._columns is not None:
+            new_c = np.full((self._columns.shape[0], new_cap), COL_MISSING,
+                            np.int32)
+            new_c[:, : self._capacity] = self._columns
+            self._columns = new_c
         self._matrix = new_m
         self._valid = new_v
         self._ext_ids.extend([None] * (new_cap - len(self._ext_ids)))
@@ -267,12 +339,17 @@ class BruteForceIndex:
 
     # -- mutation ---------------------------------------------------------
 
-    def add(self, ext_id: str, vector: Sequence[float]) -> None:
+    def add(self, ext_id: str, vector: Sequence[float],
+            payload: Optional[Dict] = None) -> None:
+        """Insert or overwrite one row. ``payload`` is what the indexed
+        fields' codes are taken from (an index with payload columns; a
+        row written without one has none of the fields)."""
         v = np.asarray(vector, dtype=np.float32)
         with self._lock:
             if ext_id in self._slot_of:
                 slot = self._slot_of[ext_id]
                 self._matrix[slot] = self._normalize(v)
+                self._code_slot_locked(slot, payload)
                 self._wrote_locked(slot)
                 self.mutations += 1
                 self._log_change_locked(ext_id)
@@ -288,6 +365,7 @@ class BruteForceIndex:
             self._ext_ids[slot] = ext_id
             self._slot_of[ext_id] = slot
             self._n_alive += 1
+            self._code_slot_locked(slot, payload)
             self._wrote_locked(slot, id_moved=True)
             self.mutations += 1
             self._log_change_locked(ext_id)
@@ -306,6 +384,7 @@ class BruteForceIndex:
         matrix whole."""
         self._dev_matrix = None
         self._dev_valid = None
+        self._dev_columns = None
         self._pending = set()
         self._ids_table = None
         self._ids_moved = set()
@@ -343,6 +422,10 @@ class BruteForceIndex:
             if dev is not None:
                 dev_b = int(getattr(dev, "nbytes", 0)) + int(
                     getattr(self._dev_valid, "nbytes", 0) or 0)
+            # the payload columns' device copy, counted in device_bytes
+            # too so that the memory ledger reconciles
+            col_b = int(getattr(self._dev_columns, "nbytes", 0) or 0)
+            dev_b += col_b
             used = max(self._count, 1)
             quant = self._quant
             tiered = self._tiered
@@ -350,9 +433,11 @@ class BruteForceIndex:
                 "rows": self._n_alive,
                 "capacity": self._capacity,
                 "device_bytes": dev_b,
+                "payload_index_bytes": col_b,
                 # host mirror + the ext-id slot table (pointer-sized
                 # slots; string payloads are shared with callers)
-                "host_bytes": matrix_b + valid_b + 8 * len(self._ext_ids),
+                "host_bytes": matrix_b + valid_b + 8 * len(self._ext_ids)
+                + (self._columns.nbytes if self._columns is not None else 0),
                 "dead_fraction": round(
                     (self._count - self._n_alive) / used, 6),
                 "changelog_depth": len(self._changelog),
@@ -387,10 +472,12 @@ class BruteForceIndex:
             for ext_id, vec in items:
                 self.add(ext_id, vec)
 
-    def add_matrix(self, ext_ids: Sequence[str], matrix: np.ndarray) -> None:
+    def add_matrix(self, ext_ids: Sequence[str], matrix: np.ndarray,
+                   payloads: Optional[Sequence[Optional[Dict]]] = None
+                   ) -> None:
         """``add`` for row i of a float32 ``[n, dims]`` matrix under
-        ``ext_ids[i]``, for every i in order: the same rows, slots, ids
-        and mutation count afterwards. ``BULK_MIN_ROWS`` or more fresh
+        ``ext_ids[i]`` (with ``payloads[i]``), for every i in order: the
+        same rows, slots, ids, codes and mutation count afterwards. ``BULK_MIN_ROWS`` or more fresh
         ids into an index without free slots (a bulk load) are
         normalised and copied block by block, with no call a row;
         anything else takes the loop. The changelog is trimmed once, at
@@ -405,8 +492,9 @@ class BruteForceIndex:
         with self._lock:
             if (n < BULK_MIN_ROWS or self._free or len(set(ext_ids)) != n
                     or not self._slot_of.keys().isdisjoint(ext_ids)):
-                for ext_id, vec in zip(ext_ids, matrix):
-                    self.add(ext_id, vec)
+                for i, (ext_id, vec) in enumerate(zip(ext_ids, matrix)):
+                    self.add(ext_id, vec,
+                             None if payloads is None else payloads[i])
                 return
             start = self._count
             self._ensure_capacity_locked(start + n, matrix.shape[1])
@@ -420,6 +508,9 @@ class BruteForceIndex:
                           out=self._matrix[start + lo:start + lo + len(block)])
             self._valid[start:start + n] = True
             self._ext_ids[start:start + n] = ext_ids
+            if self._col_fields and payloads is not None:
+                for i in range(n):
+                    self._code_slot_locked(start + i, payloads[i])
             self._slot_of.update(zip(ext_ids, range(start, start + n)))
             self._count += n
             self._n_alive += n
@@ -442,6 +533,7 @@ class BruteForceIndex:
             self._ext_ids[slot] = None
             self._free.append(slot)
             self._n_alive -= 1
+            self._code_slot_locked(slot, None)
             self._wrote_locked(slot, id_moved=True)
             self.mutations += 1
             self._maybe_compact_locked()
@@ -476,6 +568,9 @@ class BruteForceIndex:
             self._ext_ids = []
             self._slot_of = {}
             self._free = []
+            if self._columns is not None:
+                self._columns = np.full((self._columns.shape[0], 0),
+                                        COL_MISSING, np.int32)
         else:
             rows = [i for i, e in enumerate(self._ext_ids)
                     if e is not None and self._valid[i]]
@@ -484,6 +579,11 @@ class BruteForceIndex:
             new_m[: len(rows)] = self._matrix[rows]
             new_v = np.zeros((new_cap,), dtype=bool)
             new_v[: len(rows)] = True
+            if self._columns is not None:
+                new_c = np.full((self._columns.shape[0], new_cap),
+                                COL_MISSING, np.int32)
+                new_c[:, : len(rows)] = self._columns[:, rows]
+                self._columns = new_c
             self._ext_ids = ([self._ext_ids[i] for i in rows]
                              + [None] * (new_cap - len(rows)))
             self._slot_of = {e: s for s, e in enumerate(self._ext_ids)
@@ -580,6 +680,11 @@ class BruteForceIndex:
             with _span("index.refresh", kind="rows", rows=len(slots)) as sp:
                 sp.annotate(bucket=self._apply_rows_locked(slots))
             _REFRESH_C.labels("rows").inc()
+        if self._columns is not None and self._dev_columns is None:
+            # a thousandth of the matrix: shipped whole beside a full
+            # ship, after a fill and when the set of fields changed
+            self._dev_columns = _ship(self._columns)
+            _SHIP_BYTES_C.labels("columns").inc(self._columns.nbytes)
         return self._dev_matrix, self._dev_valid
 
     def _apply_rows_locked(self, slots: np.ndarray) -> int:
@@ -598,12 +703,24 @@ class BruteForceIndex:
             # the mirror
             rows, vals = self._matrix[padded], self._valid[padded]
             t0 = time.perf_counter()
-            self._dev_matrix, self._dev_valid = index_update(
-                self._dev_matrix, self._dev_valid, padded, rows, vals)
+            if self._dev_columns is None:
+                # no columns, or a stack the next lines of the caller
+                # ship whole: the plain program, as an index without a
+                # payload index runs it
+                self._dev_matrix, self._dev_valid = index_update(
+                    self._dev_matrix, self._dev_valid, padded, rows, vals)
+                shipped = 0
+            else:
+                codes = self._columns[:, padded]
+                self._dev_matrix, self._dev_valid, self._dev_columns = \
+                    index_update_columns(
+                        self._dev_matrix, self._dev_valid,
+                        self._dev_columns, padded, rows, vals, codes)
+                shipped = codes.nbytes
             record_dispatch(KIND_UPDATE, bucket, 1,
                             time.perf_counter() - t0)
             _SHIP_BYTES_C.labels("rows").inc(
-                padded.nbytes + rows.nbytes + vals.nbytes)
+                padded.nbytes + rows.nbytes + vals.nbytes + shipped)
         return bucket
 
     def warm_updates(self) -> None:
@@ -617,10 +734,261 @@ class BruteForceIndex:
             if (self._n_alive == 0 or self._capacity * (self.dims or 1)
                     <= self._SMALL_HOST):
                 return
+            # with payload columns the program that also writes the codes
             self._device_arrays_locked()
             for bucket in UPDATE_BUCKETS:
                 self._apply_rows_locked(np.zeros(bucket, np.int32))
             jax.block_until_ready(self._dev_matrix)
+
+    # -- payload columns --------------------------------------------------
+
+    def _code_locked(self, field: str, payload: Optional[Dict]) -> int:
+        """The int32 code of ``field`` in ``payload``. ``integer``: the
+        value itself when it is an ``int`` the column holds; ``keyword``:
+        the string's number in the field's dictionary (grown here). A
+        point without the field, or with a value no ``match.value`` or
+        ``range`` of the host filter can match (a list, a dict, ``None``;
+        for a keyword anything but a string), is MISSING. A scalar the
+        host filter could match and the column cannot hold (a float, a
+        bool, a numeric string, an integer past int32) is UNCODED, and
+        the field answers no filter while a live row holds one."""
+        found, value = payload_value(payload, field) \
+            if payload else (False, None)
+        if not found or value is None or isinstance(value, (list, dict)):
+            return COL_MISSING
+        if self._col_schema[field] == "keyword":
+            if type(value) is not str:
+                return COL_MISSING
+            codes = self._col_dict[field]
+            code = codes.get(value)
+            if code is None:
+                code = codes[value] = len(codes)
+            return code
+        if type(value) is int and COL_LO <= value <= COL_HI:
+            return value
+        return COL_UNCODED
+
+    def _set_code_locked(self, f: int, slot: int,
+                         payload: Optional[Dict]) -> None:
+        """Write field ``f``'s code of ``slot`` from ``payload``, keeping
+        the field's count of uncoded live rows."""
+        field = self._col_fields[f]
+        new = self._code_locked(field, payload)
+        old = int(self._columns[f, slot])
+        if old == new:
+            return
+        if old == COL_UNCODED:
+            self._col_uncoded[field] -= 1
+        if new == COL_UNCODED:
+            self._col_uncoded[field] += 1
+        self._columns[f, slot] = new
+
+    def _code_slot_locked(self, slot: int, payload: Optional[Dict]) -> None:
+        """Write ``slot``'s codes from ``payload`` (``None``: the row has
+        none of the fields)."""
+        for f in range(len(self._col_fields)):
+            self._set_code_locked(f, slot, payload)
+
+    def columns(self) -> Dict[str, str]:
+        """``{field: schema}`` of the payload columns that serve filters."""
+        with self._lock:
+            return {f: self._col_schema[f] for f in self._col_fields
+                    if f in self._col_ready}
+
+    def declare_column(self, field: str, schema: str,
+                       ready: bool = False) -> bool:
+        """A payload column for ``field`` (``integer`` | ``keyword``), every
+        slot MISSING. It takes the codes of rows written from now on and
+        serves filters once ``publish_column`` says it is filled
+        (``ready=True``: there is nothing to fill). False when the index
+        already has the column under that schema."""
+        if schema not in COLUMN_SCHEMAS:
+            raise ValueError(f"field_schema {schema!r}: the payload index "
+                             f"takes {COLUMN_SCHEMAS}")
+        with self._lock:
+            if field in self._col_schema:
+                if self._col_schema[field] != schema:
+                    raise ValueError(
+                        f"field {field!r} is indexed as "
+                        f"{self._col_schema[field]!r}; drop it first")
+                return False
+            if len(self._col_fields) >= MAX_COLUMNS:
+                raise ValueError(f"at most {MAX_COLUMNS} indexed payload "
+                                 f"fields a collection")
+            self._col_fields.append(field)
+            self._col_schema[field] = schema
+            self._col_dict[field] = {}
+            self._col_uncoded[field] = 0
+            if ready:
+                self._col_ready.add(field)
+            self._restack_locked({f: i for i, f in
+                                  enumerate(self._col_fields[:-1])})
+            return True
+
+    def drop_column(self, field: str) -> bool:
+        with self._lock:
+            if field not in self._col_schema:
+                return False
+            was = {f: i for i, f in enumerate(self._col_fields)}
+            self._col_fields.remove(field)
+            for table in (self._col_schema, self._col_dict,
+                          self._col_uncoded):
+                del table[field]
+            self._col_ready.discard(field)
+            self._restack_locked(was)
+            return True
+
+    def _restack_locked(self, was: Dict[str, int]) -> None:
+        """The column stack for the present fields, each field's row taken
+        from row ``was[field]`` of the old stack (a new field: MISSING).
+        Plans made against the old stack are stale from here."""
+        old = self._columns
+        if not self._col_fields:
+            self._columns = None
+        else:
+            self._columns = np.full(
+                (pow2_bucket(len(self._col_fields)), self._capacity),
+                COL_MISSING, np.int32)
+            for f, field in enumerate(self._col_fields):
+                if field in was:
+                    self._columns[f] = old[was[field]]
+        self._dev_columns = None
+        self._col_gen += 1
+
+    def fill_column(self, field: str, ext_ids: Sequence[str],
+                    read_payloads) -> None:
+        """Set ``field``'s code for rows that were there before the column
+        was. ``read_payloads(ext_ids)`` reads their stored payloads; it
+        is called outside the index lock and its reading thrown away when
+        a write landed meanwhile (the payloads may then be older than the
+        rows), and after three such readings with the writers held."""
+        for _ in range(3):
+            seen = self.mutations
+            payloads = read_payloads(ext_ids)
+            with self._lock:
+                if self.mutations == seen:
+                    self._fill_locked(field, ext_ids, payloads)
+                    return
+        with self._lock:
+            self._fill_locked(field, ext_ids, read_payloads(ext_ids))
+
+    def _fill_locked(self, field, ext_ids, payloads) -> None:
+        f = self._col_fields.index(field)
+        slot_of, code = self._slot_of.get, self._code_locked
+        pairs = [(slot_of(e), code(field, p))
+                 for e, p in zip(ext_ids, payloads)]
+        slots = np.fromiter((s for s, _ in pairs if s is not None),
+                            np.int64)
+        codes = np.fromiter((c for s, c in pairs if s is not None),
+                            np.int32, len(slots))
+        self._col_uncoded[field] += int(
+            np.count_nonzero(codes == COL_UNCODED)) - int(
+            np.count_nonzero(self._columns[f, slots] == COL_UNCODED))
+        self._columns[f, slots] = codes
+        # shipped whole by the next reader (a thousandth of the matrix)
+        self._dev_columns = None
+
+    def set_payload(self, ext_id: str, payload: Optional[Dict]) -> None:
+        """The codes of a row whose payload changed and whose vector did
+        not."""
+        with self._lock:
+            slot = self._slot_of.get(ext_id)
+            if slot is not None and self._col_fields:
+                self._code_slot_locked(slot, payload)
+                self._wrote_locked(slot)
+                self.mutations += 1
+
+    @property
+    def has_columns(self) -> bool:
+        return bool(self._col_fields)
+
+    def publish_column(self, field: str) -> None:
+        with self._lock:
+            if field in self._col_schema:
+                self._col_ready.add(field)
+
+    def filter_bounds(self, conds: Sequence[Tuple[str, str, object]]):
+        """Per-field inclusive bounds for a conjunction of conditions
+        ``(field, op, value)``, ``op`` one of ``eq``, ``gt``, ``gte``,
+        ``lt``, ``lte``: ``(generation, bounds [F, 2] int32)`` for
+        :meth:`search_batch`; ``"empty"`` when no row can pass (a keyword
+        the dictionary has never seen, bounds that exclude each other);
+        ``None`` when this index cannot answer exactly and the caller
+        filters on the host (a field without a ready column, a row whose
+        value the column cannot hold, a value of another type than the
+        schema's, the quantised or tiered rung serving)."""
+        if self.tiered_plane() is not None or self.quant_plane() is not None:
+            return None
+        with self._lock:
+            if self._columns is None:
+                return None
+            bounds = open_bounds(self._columns.shape[0])
+            lo = {}
+            hi = {}
+            for field, op, value in conds:
+                if field not in self._col_ready \
+                        or self._col_uncoded[field]:
+                    return None
+                keyword = self._col_schema[field] == "keyword"
+                a, b = COL_LO, COL_HI
+                if op == "eq":
+                    if keyword:
+                        if type(value) is not str:
+                            return None
+                        code = self._col_dict[field].get(value)
+                        if code is None:
+                            return "empty"
+                        a = b = code
+                    elif type(value) is int:
+                        a = b = value
+                    else:
+                        return None
+                elif keyword or type(value) not in (int, float) \
+                        or not math.isfinite(value):
+                    return None
+                elif op == "gt":
+                    a = math.floor(value) + 1
+                elif op == "gte":
+                    a = math.ceil(value)
+                elif op == "lt":
+                    b = math.ceil(value) - 1
+                elif op == "lte":
+                    b = math.floor(value)
+                else:
+                    return None
+                lo[field] = max(lo.get(field, COL_LO), a)
+                hi[field] = min(hi.get(field, COL_HI), b)
+            for field in lo:
+                if lo[field] > hi[field]:
+                    return "empty"
+                bounds[self._col_fields.index(field)] = (lo[field],
+                                                         hi[field])
+            return self._col_gen, bounds
+
+    def warm_filtered(self, max_batch: int = 32,
+                      ks: Sequence[int] = (64, 128, 256)) -> None:
+        """Compile (or load from the cache) the filtered scan for every
+        batch bucket up to ``max_batch`` at the power-of-two bucket of
+        each k in ``ks`` (what a coalesced batch asks for), with open
+        bounds, so that the first filtered search is not the one that
+        compiles. The defaults are what a Qdrant search's first round can
+        ask for. A server calls this before it takes traffic. Nothing to
+        do for an index without payload columns or below the host tier's
+        size."""
+        with self._lock:
+            if (self._columns is None or self._n_alive == 0
+                    or self._capacity * (self.dims or 1)
+                    <= self._SMALL_HOST):
+                return
+            gen, rows = self._col_gen, self._columns.shape[0]
+        b = 1
+        while b <= pow2_bucket(max_batch):
+            bounds = open_bounds(b, rows)
+            for k in ks:
+                self.search_batch(np.ones((b, self.dims), np.float32),
+                                  pow2_bucket(k), bounds=bounds,
+                                  bounds_gen=gen)
+            b *= 2
 
     def _ids_snapshot_locked(self):
         """(slot-to-id table, ``"reused"`` | ``"extended"`` |
@@ -705,11 +1073,17 @@ class BruteForceIndex:
         return self.search_batch(np.asarray([query], dtype=np.float32), k)[0]
 
     @staticmethod
-    def _search_host(queries, m, valid, ext_ids, k_eff):
+    def _search_host(queries, m, valid, ext_ids, k_eff, columns=None,
+                     bounds=None):
         qn = queries / np.maximum(
             np.linalg.norm(queries, axis=1, keepdims=True), 1e-12)
         scores = qn @ m.T
         scores[:, ~valid] = -np.inf
+        if bounds is not None:
+            # the device scan's per-rider mask (ops/similarity._bounds_mask)
+            seen = np.all((columns[None] >= bounds[:, :, 0, None])
+                          & (columns[None] <= bounds[:, :, 1, None]), axis=1)
+            scores[~seen] = -np.inf
         out: List[List[Tuple[str, float]]] = []
         for row in range(scores.shape[0]):
             top = np.argpartition(-scores[row], k_eff - 1)[:k_eff]
@@ -864,9 +1238,18 @@ class BruteForceIndex:
             return None
 
     def search_batch(
-        self, queries: np.ndarray, k: int = 10, exact: bool = False
+        self, queries: np.ndarray, k: int = 10, exact: bool = False,
+        bounds: Optional[np.ndarray] = None,
+        bounds_gen: Optional[int] = None,
     ) -> List[List[Tuple[str, float]]]:
         """Batched exact search; returns per-query [(ext_id, cosine)].
+        ``bounds [B, F, 2] int32`` (:meth:`filter_bounds`, stacked a
+        rider; ``bounds_gen`` the generation they were planned against)
+        restricts rider b to the rows whose payload codes lie inside
+        ``bounds[b]``: the filtered program scores every row and ranks
+        exactly among those a rider may see; matrix, validity, ids AND
+        columns are of one generation. A call without bounds runs the
+        plain program with the plain arguments.
         With ``NORNICDB_VECTOR_QUANT`` set, large corpora serve through
         the quantized coarse+exact-rerank plane instead (answers remain
         exact-rescored float32; ``exact=True`` bypasses the plane for
@@ -877,6 +1260,15 @@ class BruteForceIndex:
         are of one generation (:meth:`_ids_snapshot_locked`)."""
         from nornicdb_tpu.obs import audit as _audit
 
+        if bounds is not None:
+            exact = True    # the other rungs take no bounds
+            bounds = np.asarray(bounds, np.int32)
+            filtered = int(np.count_nonzero(np.any(
+                bounds[:, :, 0] > COL_MISSING, axis=1)
+                | np.any(bounds[:, :, 1] < COL_HI, axis=1)))
+            scan_attrs = {"filtered": filtered, "fields": bounds.shape[1]}
+        else:
+            scan_attrs = {}
         if not exact:
             # capacity rung first (beyond-HBM corpora), then the
             # device-resident quant rung
@@ -904,6 +1296,13 @@ class BruteForceIndex:
                     (time.perf_counter() - t_ask) * 1e3, 3))
                 if self._n_alive == 0:
                     return [[] for _ in range(len(queries))]
+                if bounds is not None and (
+                        self._columns is None
+                        or bounds_gen != self._col_gen
+                        or bounds.shape[1] != self._columns.shape[0]):
+                    raise StaleFilterPlan(
+                        "the payload columns changed after the filter "
+                        "was planned")
                 k_eff = min(k, self._n_alive)
                 # per-query cost accounting: the brute scan's price is
                 # its known shapes — B queries against the
@@ -920,25 +1319,31 @@ class BruteForceIndex:
                     # under the lock and only reads the matrix/valid/
                     # ext_ids, so its scan nests inside the snapshot
                     with _span("index.scan", path="host",
-                               b=len(queries), k=k_eff):
+                               b=len(queries), k=k_eff, **scan_attrs):
                         return self._search_host(
                             np.asarray(queries, np.float32), self._matrix,
-                            self._valid, self._ext_ids, k_eff)
+                            self._valid, self._ext_ids, k_eff,
+                            self._columns, bounds)
                 # one lock hold: (m, valid, ext_ids) are one generation,
                 # with every write acknowledged before it applied
                 m, valid = self._device_arrays_locked()
+                cols = self._dev_columns if bounds is not None else None
                 ext_ids, ids = self._ids_snapshot_locked()
                 snap.annotate(ids=ids)
-            pallas = _use_pallas()
+            pallas = _use_pallas() and bounds is None
             # from the call into the jitted scan to its result on the
             # host: the wait behind other callers' scans, the execution,
             # D2H. The lock goes once the scan is DISPATCHED, not before:
             # the next refresh donates m and valid, and the device runs
             # what it is given in order
             with _span("index.scan", path="pallas" if pallas else "xla",
-                       b=len(queries), k=k_eff):
+                       b=len(queries), k=k_eff, **scan_attrs):
                 q = l2_normalize(jnp.asarray(queries, dtype=jnp.float32))
-                if pallas:
+                if bounds is not None:
+                    s, i = cosine_topk_filtered(q, m, valid, cols, bounds,
+                                                k_eff)
+                    del cols
+                elif pallas:
                     from nornicdb_tpu.ops.pallas_topk import (
                         fused_cosine_topk,
                     )
@@ -987,6 +1392,7 @@ class BruteForceIndex:
     def save(self, path: str) -> None:
         """Snapshot live rows to an .npz (compacted: dead slots dropped)."""
         with self._lock:
+            extra = {}
             if self._matrix is None or self._n_alive == 0:
                 ids = np.asarray([], dtype="U1")
                 matrix = np.zeros((0, 0), np.float32)
@@ -995,10 +1401,21 @@ class BruteForceIndex:
                         if e is not None and self._valid[i]]
                 ids = np.asarray([self._ext_ids[i] for i in rows])
                 matrix = self._matrix[rows]
+                if self._col_fields:
+                    # the payload columns of the live rows, the fields
+                    # that serve filters and the keyword dictionaries
+                    fields = [f for f in self._col_fields
+                              if f in self._col_ready]
+                    extra["columns"] = self._columns[
+                        [self._col_fields.index(f) for f in fields]][:, rows]
+                    extra["columns_meta"] = np.asarray(json.dumps({
+                        "fields": fields,
+                        "schema": {f: self._col_schema[f] for f in fields},
+                        "dict": {f: self._col_dict[f] for f in fields}}))
         # write through a file object — np.savez would append ".npz" to a
         # bare path, breaking the caller's atomic tmp-then-rename publish
         with open(path, "wb") as f:
-            np.savez_compressed(f, ids=ids, matrix=matrix)
+            np.savez_compressed(f, ids=ids, matrix=matrix, **extra)
 
     @classmethod
     def load(cls, path: str, use_device: bool = True) -> "BruteForceIndex":
@@ -1021,4 +1438,15 @@ class BruteForceIndex:
             idx._slot_of[eid] = i
         idx._count = n
         idx._n_alive = n
+        if "columns_meta" in data.files:
+            meta = json.loads(str(data["columns_meta"]))
+            for field in meta["fields"]:
+                idx.declare_column(field, meta["schema"][field], ready=True)
+                idx._col_dict[field] = dict(meta["dict"][field])
+            if meta["fields"]:
+                codes = np.asarray(data["columns"], np.int32)
+                idx._columns[: len(meta["fields"]), :n] = codes
+                for f, field in enumerate(meta["fields"]):
+                    idx._col_uncoded[field] = int(
+                        np.count_nonzero(codes[f] == COL_UNCODED))
         return idx

@@ -37,6 +37,8 @@ NEEDS_CHIP = {
                               "window",
     "index_update_roofline": "the update program's device seconds against "
                              "the peak bytes/s",
+    "filtered_scan_roofline": "the filtered scan's device seconds against "
+                              "the peak bytes/s",
 }
 # Listed by a cell and read by nothing: strict, so that the PR that mends
 # the metric takes the mark out.

@@ -1,6 +1,6 @@
 """The yardstick's controls and fault cases (``benchmark/tests/``), brought
 under tier-1's collection by name: a control reads not-correct, and so does
-a whole rehearsed run with the timed path broken underneath. The four
+a whole rehearsed run with the timed path broken underneath. The
 "unbroken is correct" cases are ``tests/test_yardstick.py``'s per-cell
 case. A file of its own, so that ``--dist loadfile`` can run it beside the
 rehearsals and not after them.
@@ -16,6 +16,12 @@ from benchmark.tests.test_controls import (  # noqa: F401
 from benchmark.tests.test_faults import (  # noqa: F401
     test_ingest_vector_altered_is_not_correct,
     test_search_answer_altered_is_not_correct,
+)
+from benchmark.tests.test_filter import (  # noqa: F401
+    test_a_scan_that_drops_the_mask_is_not_correct,
+    test_answers_not_exact_inside_the_filter_are_not_correct,
+    test_the_filtered_control_reads_above_the_limit,
+    test_the_filtered_reference_judges_its_own_answers_correct,
 )
 from benchmark.tests.test_hybrid import (  # noqa: F401
     test_a_broken_fuse_is_not_correct,
