@@ -31,6 +31,12 @@ _TOKENS_C = REGISTRY.counter(
     "Tokens handed to the encoder forward: real (the id lists' lengths) "
     "and padded (rows x width of the array the device is given)",
     labels=("kind",))
+_PARAM_BYTES_G = REGISTRY.gauge(
+    "nornicdb_embed_param_bytes",
+    "Device bytes of the encoder's parameters: held (the tree the caller "
+    "handed) and forward (the tree the jitted forward is given; smaller "
+    "where the encoder computes in a narrower dtype than it stores)",
+    labels=("tree",))
 
 # a batch of FULL_BATCH_ROWS rows or more is never narrower than
 # FULL_BATCH_MIN_WIDTH (``JaxEncoderEmbedder._run`` says why); 16 is the
@@ -44,6 +50,24 @@ def width_bucket(tokens: int) -> int:
     (``ops.similarity.pow2_bucket`` floored at 16, without importing JAX:
     the embed queue seals by it)."""
     return max(16, 1 << max(tokens - 1, 0).bit_length())
+
+
+def _working_copy(params, dtype):
+    """The tree the forward is handed: every leaf of two or more
+    dimensions (the tables, the kernels, the attention's [h, hd] biases)
+    in ``dtype``, the rounding flax's ``promote_dtype`` would otherwise
+    repeat inside every call; 1-D leaves (LayerNorm, the Dense biases)
+    as they are."""
+    import jax
+
+    return jax.tree_util.tree_map(
+        lambda x: x.astype(dtype) if x.ndim >= 2 else x, params)
+
+
+def _tree_bytes(tree) -> int:
+    import jax
+
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
 
 
 class Embedder(Protocol):
@@ -81,7 +105,13 @@ class HashEmbedder:
 class JaxEncoderEmbedder:
     """Local TPU embedder over the flax encoder.
 
-    - parameters are placed on the device once, at construction;
+    - ``params`` is what the caller handed, placed on the device once,
+      at construction, in the dtype it came in (float32 from every
+      loader); the jitted forward is given ``_forward_params``: the same
+      tree with its matrices cast ONCE to ``cfg.dtype``, the dtype the
+      modules compute in, so no call converts a table or reads a
+      float32 kernel again. Where ``cfg.dtype`` is the leaves' own (a
+      float32 configuration) the two are one tree;
     - pads token widths AND the batch dimension to power-of-two buckets
       (jit cache stays small; pad rows are dropped);
     - batches up to ``max_batch`` texts per device call;
@@ -121,6 +151,16 @@ class JaxEncoderEmbedder:
         # checkpoint loaders hand back NumPy trees; left on the host,
         # every call would ship every weight to the device again
         self.params = jax.device_put(params)
+        dtype = np.dtype(cfg.dtype)
+        self._forward_params = self.params
+        if any(x.ndim >= 2 and x.dtype != dtype
+               for x in jax.tree_util.tree_leaves(self.params)):
+            # one named program, run here: set-up pays the cast once
+            self._forward_params = jax.jit(
+                _working_copy, static_argnums=1)(self.params, dtype)
+        _PARAM_BYTES_G.labels("held").set(_tree_bytes(self.params))
+        _PARAM_BYTES_G.labels("forward").set(
+            _tree_bytes(self._forward_params))
         self.dims = cfg.hidden_size
         self.max_batch = max_batch
         self.tokenizer = HashTokenizer(cfg.vocab_size)
@@ -169,7 +209,7 @@ class JaxEncoderEmbedder:
             with _span("encoder.forward", rows=rows, width=width):
                 with self._lock:
                     self.shapes.add(arr.shape)
-                    out = self._jit(self.params, jnp.asarray(arr))
+                    out = self._jit(self._forward_params, jnp.asarray(arr))
                 out = np.asarray(out, dtype=np.float32)
             record_dispatch("encoder", rows, width,
                             time.perf_counter() - t0)

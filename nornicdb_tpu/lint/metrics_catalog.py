@@ -65,7 +65,8 @@ IMPORT_TIME_MODULES = (
     "nornicdb_tpu.obs.device",
     # ISSUE 26: the hot-path spans' counters — embed worker phases and
     # token fill, the encoder / vector_widen dispatch kinds, the
-    # in-memory engine's lock wait
+    # in-memory engine's lock wait; ISSUE 36: the embedder's
+    # parameter-bytes gauge
     "nornicdb_tpu.embed.queue",
     "nornicdb_tpu.embed.embedder",
     "nornicdb_tpu.api.qdrant",

@@ -4,7 +4,13 @@ The reference embeds with bge-m3 (an XLM-RoBERTa-large derivative) through
 llama.cpp (pkg/embed/local_gguf.go:57 LocalGGUFEmbedder). Here the encoder
 is a native JAX/flax module designed for TPU:
 
-- bfloat16 activations, f32 params/normalization — MXU-friendly;
+- bfloat16 activations, f32 normalization; parameters are held in
+  float32 (what ``init`` and every loader give) and each module rounds
+  its own to ``cfg.dtype`` when called, so the inference stack hands
+  ``apply`` a tree whose matrices it rounded ONCE
+  (``embed/embedder.py`` ``JaxEncoderEmbedder``: same operands, no
+  conversion of the token table a call); training applies the float32
+  tree itself;
 - every activation carries a logical sharding annotation so the same
   module runs single-chip or pjit-sharded over a (dp, tp, sp) mesh with
   XLA inserting the collectives (scaling-book recipe);
